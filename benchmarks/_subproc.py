@@ -21,6 +21,7 @@ def run_hlo_subprocess(snippet: str, n_devices: int, *,
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"    # a CPU fake fabric: never the chip
     env["PYTHONPATH"] = os.path.join(repo, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(snippet)],
                          env=env, capture_output=True, text=True,

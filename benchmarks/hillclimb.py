@@ -17,9 +17,7 @@ import argparse
 import dataclasses
 import json
 
-
-
-HBM_BW = 819e9
+from repro.core.perfmodel import HBM_BW  # one v5e chip
 
 
 def _save(name: str, rec: dict, out="results/hillclimb"):
@@ -148,9 +146,10 @@ def run() -> list[str]:
     import subprocess
     import sys
 
+    # the child is a CPU fake fabric: it must never compete for a chip
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=512",
-               PYTHONPATH="src")
+               JAX_PLATFORMS="cpu", PYTHONPATH="src")
     subprocess.run(
         [sys.executable, "-m", "benchmarks.hillclimb", "--cell", "stencil"],
         check=True, env=env, capture_output=True, text=True)

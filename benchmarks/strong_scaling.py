@@ -34,6 +34,7 @@ print((time.time() - t0) / {iters})
 """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"    # a CPU fake fabric: never the chip
     env["PYTHONPATH"] = "src"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)

@@ -36,7 +36,6 @@ from repro.apps.cfd.grid import (
 )
 from repro.apps.cfd.momentum import AP_FLOOR, form_u_system, form_v_system, window
 from repro.apps.cfd.pressure import divergence, form_pressure_system
-from repro.compat import shard_map
 from repro.core.halo import FabricAxes, gather_halo
 from repro.core.operator import BACKENDS, make_operator
 from repro.core.precond import PrecondConfig, build_precond
@@ -270,7 +269,7 @@ def make_step_fn(cfg: CFDConfig, opts: SolverOptions = SolverOptions(),
     spec = P(fabric.x, fabric.y)
     scalar = P()
     out_specs = scalar if form_only else (spec, spec, spec, scalar, scalar)
-    mapped = shard_map(local, mesh=mesh, in_specs=(spec,) * 5,
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 5,
                        out_specs=out_specs, check_vma=False)
     return jax.jit(mapped)
 

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.comm import SCHEDULES, get_schedule  # noqa: F401
 from repro.core.halo import FabricAxes
 from repro.core.operator import BACKENDS, make_operator  # noqa: F401
@@ -99,7 +98,7 @@ def solve_ref_fused(
     same vector kernels via ``backend="pallas"``.  Python loop (not
     lax.while) because pallas_call is re-traced per call in interpret mode.
     """
-    from repro.compat import resolve_interpret
+    from repro.kernels import resolve_interpret
     from repro.kernels.fused_iter import update_p, update_xr_dots
     from repro.kernels.stencil_nd.fused import stencil7_dot, stencil7_two_dots
 
@@ -217,7 +216,7 @@ def solve_distributed(
     )
     if x0 is None:
         x0 = jnp.zeros_like(b)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         solve_fn, mesh=mesh,
         in_specs=(cf_spec, spec, spec),
         out_specs=out_specs,
@@ -301,7 +300,7 @@ def make_iteration_fn(
 
     spec = fabric.spec(3)
     scalar = P()
-    return shard_map(
+    return jax.shard_map(
         iteration, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec, scalar),
         out_specs=(spec, spec, spec, scalar, scalar),

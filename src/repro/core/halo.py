@@ -287,6 +287,5 @@ def global_apply(mesh, coeffs: StencilCoeffs, v: jax.Array, *, policy: Policy = 
         return local_apply(cf, vv, fabric, policy=policy, overlap=overlap,
                            schedule=schedule)
 
-    from repro.compat import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=(cf_spec, v_spec),
+    return jax.shard_map(fn, mesh=mesh, in_specs=(cf_spec, v_spec),
                      out_specs=v_spec, check_vma=False)(coeffs, v)

@@ -199,7 +199,7 @@ def pallas_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
     overrides the cache's boundary-ring epilogue choice for the overlap
     schedule (None = let the cache decide).
     """
-    from repro.compat import resolve_interpret
+    from repro.kernels import resolve_interpret
     from repro.kernels.fused_iter import (
         dot_mixed, update_p, update_q_dots, update_xr_dots,
     )

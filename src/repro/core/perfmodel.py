@@ -32,8 +32,30 @@ from __future__ import annotations
 import dataclasses
 import math
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeak:
+    """Published per-chip peaks of one accelerator, with their source."""
+
+    flops_per_s: float           # bf16 dense FLOP/s
+    hbm_bytes_per_s: float       # HBM bandwidth
+    source: str
+
+
+#: The one table of device peaks, keyed by ``jax.Device.device_kind``.  A
+#: device that is not here (the CPU among them) has no peak: its roofline
+#: share is "not measured", never a share of some other chip's peak.
+PEAKS = {
+    "TPU v5 lite": DevicePeak(
+        flops_per_s=197e12, hbm_bytes_per_s=819e9,
+        source='Google Cloud documentation, "TPU v5e" (per chip: 197 '
+               'TFLOP/s bf16, 16 GB HBM at 819 GB/s)'),
+}
+
+#: the chip the analytic model below predicts for (one v5e).
+MODEL_CHIP = PEAKS["TPU v5 lite"]
+PEAK_FLOPS = MODEL_CHIP.flops_per_s
+HBM_BW = MODEL_CHIP.hbm_bytes_per_s
 LINK_BW = 50e9
 HOP_LATENCY_S = 1e-6          # per-hop ICI latency (~us class)
 FLOPS_PER_PT = 44.0

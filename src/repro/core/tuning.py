@@ -9,18 +9,18 @@ for the wide star operators.  This module is the production answer, the
 same shape as an inference stack's kernel autotuner:
 
 * :class:`KernelConfig` — one point of the kernel's tuning space: the
-  ``(bx, by)`` x/y tile, the Z-split chunk ``zc``, the VMEM-residency
-  choice (whole padded block resident vs element-indexed streaming
-  windows), and whether the boundary-ring patch of the overlap schedule is
-  *fused* into the interior kernel's pass (one launch) or kept as separate
-  patch launches.
+  ``(bx, by)`` x/y tile, the Z-split chunk ``zc``, and whether the
+  boundary-ring patch of the overlap schedule is *fused* into the interior
+  kernel's pass (one launch) or kept as separate patch launches.
 * :class:`TuningCache` — a JSON-persisted map from a registry-style key
-  ``"{spec}/{dtype}/{XxYxZ}"`` to the winning config plus the sweep record
-  that chose it.  Default path ``results/tuning_cache.json``; overridden
-  (or disabled) by the ``REPRO_TUNING_CACHE`` env var.
+  ``"{device}/{spec}/{dtype}/{XxYxZ}"`` to the winning config plus the
+  sweep record that chose it.  The device part keeps a config swept in
+  one place (the CPU's interpreter, a TPU generation) from serving
+  another.  Default path ``results/tuning_cache.json``; overridden (or
+  disabled) by the ``REPRO_TUNING_CACHE`` env var.
 * :func:`lookup_config` — the one call sites use: returns the cached
   winner when a valid entry exists, else the deterministic pre-tuning
-  default (full-block tile + ``pick_zc`` chunking), so an empty or absent
+  default (``kernels/stencil_nd.ops.default_tile``), so an empty or absent
   cache reproduces the untuned behaviour bit-for-bit.
 * :func:`autotune_cell` / :func:`measure_config` — the hypothesis->measure
   sweep primitives ``benchmarks/kernel_autotune.py`` drives (extending the
@@ -34,6 +34,7 @@ configs up here, so a module-level import would cycle.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import os
 import time
@@ -42,6 +43,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 
+from repro.core.perfmodel import MODEL_CHIP
 from repro.core.stencil import StencilSpec
 
 #: default persistence path, relative to the working directory (the repo
@@ -51,10 +53,9 @@ DEFAULT_CACHE_PATH = os.path.join("results", "tuning_cache.json")
 #: ``REPRO_TUNING_CACHE`` values that disable cache lookup entirely.
 _DISABLED = ("", "0", "off", "none", "false", "no")
 
-#: modeled peak memory bandwidth (bytes/s) the roofline fractions are
-#: quoted against — the same per-chip HBM figure benchmarks/hillclimb.py
-#: uses, so before/after tables are comparable across the two harnesses.
-PEAK_BYTES_PER_S = 819e9
+#: peak memory bandwidth (bytes/s) the sweep's roofline fractions are
+#: quoted against: the modeled chip of ``core/perfmodel.PEAKS``.
+PEAK_BYTES_PER_S = MODEL_CHIP.hbm_bytes_per_s
 
 
 def _dtype_name(dtype) -> str:
@@ -65,100 +66,92 @@ def _dtype_name(dtype) -> str:
 class KernelConfig:
     """One point of the stencil kernel's tuning space.
 
-    ``block`` is the (bx, by) x/y tile of the grid (``None`` entries are
-    resolved to the full local extent before reaching the kernel); ``zc``
-    the Z-split chunk; ``resident`` keeps the whole padded iterate VMEM-
-    resident and cuts each grid step's window with ``dynamic_slice``
-    (required where Pallas lacks ``pl.Element``); ``fuse_ring`` folds the
-    overlap schedule's boundary-ring patch into the interior kernel's pass.
+    ``block`` is the (bx, by) x/y tile of the grid; ``zc`` the Z-split
+    chunk; ``fuse_ring`` folds the overlap schedule's boundary-ring patch
+    into the interior kernel's pass.
     """
 
     block: tuple[int, int]
     zc: int
-    resident: bool = True
     fuse_ring: bool = False
 
     def to_json(self) -> dict:
         return {"block": list(self.block), "zc": self.zc,
-                "resident": self.resident, "fuse_ring": self.fuse_ring}
+                "fuse_ring": self.fuse_ring}
 
     @classmethod
     def from_json(cls, d: dict) -> "KernelConfig":
         return cls(block=tuple(d["block"]), zc=int(d["zc"]),
-                   resident=bool(d.get("resident", True)),
                    fuse_ring=bool(d.get("fuse_ring", False)))
 
-    def divides(self, shape: tuple[int, int, int]) -> bool:
-        bx, by = self.block
-        X, Y, Z = shape
-        return X % bx == 0 and Y % by == 0 and Z % self.zc == 0
+    @property
+    def tile(self) -> tuple[int, int, int]:
+        return tuple(self.block) + (self.zc,)
+
+    def valid_for(self, shape: tuple[int, int, int]) -> bool:
+        """Whether the kernel compiles this tile on ``shape``
+        (``kernels/stencil_nd.kernel.clamp_tile`` leaves it unchanged)."""
+        from repro.kernels.stencil_nd.kernel import clamp_tile
+
+        return clamp_tile(self.tile, tuple(shape)) == self.tile
 
 
-def cache_key(spec: StencilSpec, dtype, shape: tuple[int, ...]) -> str:
-    """Registry-style cache key: ``star7/float32/48x48x32``.
+def device_key(device_kind: str | None = None) -> str:
+    """The device part of a cache key: ``jax.devices()[0].device_kind``
+    lower-cased with spaces as underscores (``tpu_v5_lite``, ``cpu``)."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    return device_kind.strip().lower().replace(" ", "_")
 
-    Stable across processes and jax versions — it names the *problem cell*
-    (shape contract x dtype x local block), never the machine or the code
-    revision; re-sweep (``kernel_autotune --force``) when either changes.
+
+def cache_key(spec: StencilSpec, dtype, shape: tuple[int, ...],
+              device_kind: str | None = None) -> str:
+    """Registry-style cache key: ``tpu_v5_lite/star7/bfloat16/608x608x608``.
+
+    It names the device the sweep ran on and the *problem cell* (shape
+    contract x dtype x local block), never the code revision; re-sweep
+    (``kernel_autotune --force``) after a kernel change.  ``device_kind``
+    defaults to the first visible device's.
     """
     dims = "x".join(str(int(s)) for s in shape)
-    return f"{spec.name}/{_dtype_name(dtype)}/{dims}"
-
-
-def nearest_divisor(n: int, want: int) -> int:
-    """The largest divisor of ``n`` that is <= ``want`` (>= 1).
-
-    The fallback rule for block shapes that do not evenly divide the local
-    block — e.g. the paper's unpadded 600 x 595 tiles, where a requested
-    64 x 64 tile degrades to 60 x 35 instead of a cryptic Pallas shape
-    error deep inside ``pallas_call``.
-    """
-    want = max(1, min(int(want), n))
-    for d in range(want, 0, -1):
-        if n % d == 0:
-            return d
-    return 1
+    return f"{device_key(device_kind)}/{spec.name}/{_dtype_name(dtype)}/{dims}"
 
 
 def validate_config(config: KernelConfig, shape: tuple[int, int, int], *,
                     warn: bool = True, context: str = "") -> KernelConfig:
-    """Clamp ``config`` to tile sizes that evenly divide ``shape``.
+    """Clamp ``config`` to a tile the kernel compiles for ``shape``.
 
-    Returns the config unchanged when it already divides; otherwise the
-    nearest valid shape (largest divisors <= the requested tile) with a
-    warning that names both — the trace-time guard the raw kernel assert
-    used to leave to Pallas.
+    Returns the config unchanged when it is valid; otherwise the nearest
+    valid tile (``kernels/stencil_nd.kernel.clamp_tile``), with a warning
+    that names both: the trace-time guard against a tile Mosaic would
+    refuse — e.g. the paper's unpadded 600 x 595 tiles, where a requested
+    64-plane x slab degrades to 60.
     """
-    if config.divides(shape):
+    from repro.kernels.stencil_nd.kernel import clamp_tile
+
+    tile = clamp_tile(config.tile, tuple(shape))
+    if tile == config.tile:
         return config
-    X, Y, Z = shape
-    fixed = dataclasses.replace(
-        config,
-        block=(nearest_divisor(X, config.block[0]),
-               nearest_divisor(Y, config.block[1])),
-        zc=nearest_divisor(Z, config.zc))
+    fixed = dataclasses.replace(config, block=tile[:2], zc=tile[2])
     if warn:
         warnings.warn(
-            f"stencil kernel tile {config.block + (config.zc,)} does not "
-            f"evenly divide the local block {shape}{context}; falling back "
-            f"to the nearest valid tile {fixed.block + (fixed.zc,)}",
+            f"stencil kernel tile {config.tile} is not a valid tile of the "
+            f"local block {shape}{context}; falling back to the nearest "
+            f"valid tile {fixed.tile}",
             stacklevel=3)
     return fixed
 
 
 def default_config(spec: StencilSpec, dtype,
                    shape: tuple[int, int, int]) -> KernelConfig:
-    """The deterministic pre-tuning default: full-block (bx, by) tile and
-    the ``pick_zc`` VMEM-budgeted Z chunk — exactly what the kernel used
-    before the tuning cache existed, so a missing cache changes nothing."""
-    from repro.compat import HAS_PL_ELEMENT
-    from repro.kernels.stencil_nd.ops import pick_zc
+    """The deterministic pre-tuning default: the VMEM-budgeted
+    ``default_tile`` (whole Y and Z, the deepest x slab that fits), split
+    ring epilogue — so a missing cache changes nothing."""
+    from repro.kernels.stencil_nd.ops import default_tile
 
-    X, Y, Z = shape
-    zc = pick_zc(X, Y, Z, jnp.dtype(dtype).itemsize,
-                 radius=spec.radius, n_coeffs=spec.n_offsets)
-    return KernelConfig(block=(X, Y), zc=zc, resident=not HAS_PL_ELEMENT,
-                        fuse_ring=False)
+    bx, by, zc = default_tile(tuple(shape), jnp.dtype(dtype).itemsize,
+                              radius=spec.radius, n_coeffs=spec.n_offsets)
+    return KernelConfig(block=(bx, by), zc=zc, fuse_ring=False)
 
 
 # ---------------------------------------------------------------------------
@@ -268,7 +261,7 @@ def lookup_config(spec: StencilSpec, dtype, shape: tuple[int, int, int], *,
 
     ``source`` is ``"cache"`` for a valid tuned entry, ``"default"`` when
     the cache is disabled/missing/has no entry, and ``"stale"`` when an
-    entry exists but names a tile that no longer divides ``shape`` (the
+    entry exists but names a tile that is not valid for ``shape`` (the
     deterministic default is used, with a warning) — so tests and CI can
     assert lookups do not silently regress to defaults.
     """
@@ -280,12 +273,12 @@ def lookup_config(spec: StencilSpec, dtype, shape: tuple[int, int, int], *,
     if cache is not None:
         tuned = cache.get(key)
         if tuned is not None:
-            if tuned.divides(shape):
+            if tuned.valid_for(shape):
                 obs_metrics.counter("tuning.lookup.cache").inc()
                 return tuned, "cache"
             warnings.warn(
                 f"tuning-cache entry {key!r} names tile "
-                f"{tuned.block + (tuned.zc,)} which does not divide the "
+                f"{tuned.tile} which is not valid for the "
                 f"local block {shape} (stale entry?); using the default "
                 f"config — re-sweep with benchmarks/kernel_autotune.py",
                 stacklevel=2)
@@ -304,32 +297,31 @@ def candidate_configs(spec: StencilSpec, dtype,
                       smoke: bool = False) -> list[KernelConfig]:
     """The sweep's hypothesis set for one cell, deduplicated and valid.
 
-    Axes: (bx, by) x/y tiles (full block plus halves/quarters), Z-split
-    factors around the VMEM-budgeted default, VMEM-residency (streaming
-    windows only where ``pl.Element`` exists), and ring fusion.  The
+    Axes: x/y tiles and Z-split chunks (the default's, the whole axis,
+    and halves/quarters, each clamped to a valid extent) and ring fusion;
+    tiles whose working set exceeds the VMEM budget are left out.  The
     deterministic default is always candidate 0 so the sweep's "before"
     column is measured under the same harness as every hypothesis.
     """
-    from repro.compat import HAS_PL_ELEMENT
+    from repro.kernels.stencil_nd.kernel import clamp_tile, tile_bytes
+    from repro.kernels.stencil_nd.ops import VMEM_BUDGET_BYTES
 
-    X, Y, Z = shape
+    shape = tuple(shape)
     base = default_config(spec, dtype, shape)
     divs = (1, 2) if smoke else (1, 2, 4)
-    blocks = {(nearest_divisor(X, X // d), nearest_divisor(Y, Y // e))
-              for d in divs for e in divs}
-    zcs = {base.zc, nearest_divisor(Z, Z), nearest_divisor(Z, max(1, Z // 2))}
-    if not smoke:
-        zcs.add(nearest_divisor(Z, max(1, Z // 4)))
-    residents = (True, False) if HAS_PL_ELEMENT else (True,)
+    tiles = [clamp_tile(tuple(n // d for n in shape), shape) for d in divs]
+    axes = [sorted({base.tile[i]} | {t[i] for t in tiles}, reverse=True)
+            for i in range(3)]
+    itemsize = jnp.dtype(dtype).itemsize
     cands = [base]
-    for blk in sorted(blocks, reverse=True):
-        for zc in sorted(zcs, reverse=True):
-            for res in residents:
-                for fuse in (False, True):
-                    c = KernelConfig(block=blk, zc=zc, resident=res,
-                                     fuse_ring=fuse)
-                    if c != base and c.divides(shape):
-                        cands.append(c)
+    for tile in itertools.product(*axes):
+        if tile_bytes(tile, shape, itemsize, radius=spec.radius,
+                      n_tiled=spec.n_offsets + 1) > VMEM_BUDGET_BYTES:
+            continue
+        for fuse in (False, True):
+            c = KernelConfig(block=tile[:2], zc=tile[2], fuse_ring=fuse)
+            if c != base:
+                cands.append(c)
     return cands
 
 
@@ -450,7 +442,7 @@ def autotune_cell(spec: StencilSpec, dtype, shape: tuple[int, int, int], *,
         cache = TuningCache(resolve_cache_path() or DEFAULT_CACHE_PATH)
     key = cache_key(spec, dtype, shape)
     cached = cache.get(key)
-    if cached is not None and not force and cached.divides(shape):
+    if cached is not None and not force and cached.valid_for(shape):
         obs_metrics.counter("tuning.sweep.cache_hit").inc()
         rec = dict(cache.entries[key])
         rec.update(key=key, cache_hit=True)

@@ -8,18 +8,14 @@ tensor instructions but all operands already lived in SRAM; on TPU the state
 lives in HBM and fusion is where the paper's SRAM-residency advantage must be
 re-earned — DESIGN.md §2).
 
-All kernels run on a (rows, 128)-tiled flattening of the mesh block with
-f32 scalar accumulators carried across sequential grid steps (TPU grid
-iterations execute in order, so += into a (1,1) output block is sound; same
-semantics in interpret mode).
-
-Batched (many-RHS) form: every wrapper takes ``batched=True`` and then works
-on a ``(B, rows, 128)`` tiling with grid ``(B, rows // bm)`` — the row-sweep
-axis moves to grid position 1 (``seq_axis``), the per-RHS scalars ride in
-``(B, 1)``/``(B, 2)`` blocks indexed by the batch coordinate, and each RHS
-accumulates its own f32 partial into its own ``(1, 1)`` output block.  Per
-RHS the arithmetic (tile shapes, sweep order, accumulation order) is
-identical to the unbatched form, so B=1 is bitwise equal.
+All kernels run on a ``(B, rows, 128)`` tiling — one (rows, 128)
+flattening of the mesh block per right-hand side (B = 1 for a single-RHS
+solve) — with grid ``(B, rows // bm)``.  The per-RHS scalar coefficients
+ride in SMEM (a ``(B, k)`` f32 table read at the batch coordinate), and
+each RHS accumulates its own f32 dot partials into its own ``(1, 1)``
+output block across the sequential row sweep (TPU grid iterations execute
+in order, so += into the block is sound; same semantics in interpret
+mode).  A single-RHS solve is the B = 1 case of the same kernels.
 
 Precision: products in the storage dtype (bf16), accumulation in f32 — the
 paper's FMAC discipline (Table I mixed column).
@@ -27,91 +23,81 @@ paper's FMAC discipline (Table I mixed column).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _row_spec(bm):
-    return pl.BlockSpec((bm, 128), lambda i: (i, 0))
+    return pl.BlockSpec((None, bm, 128), lambda b, i: (b, i, 0))
 
 
-def _scalar_spec():
-    return pl.BlockSpec((1, 1), lambda i: (0, 0))
+def _scalars_spec():
+    # the whole (B, k) coefficient table, read as scalars at row b
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _row_spec_b(bm):
-    return pl.BlockSpec((1, bm, 128), lambda b, i: (b, i, 0))
+def _partial_spec():
+    return pl.BlockSpec((None, 1, 1), lambda b, i: (b, 0, 0))
 
 
-def _scalar_spec_b(width: int = 1):
-    return pl.BlockSpec((1, width), lambda b, i: (b, 0))
+def _partial_shape(B):
+    return jax.ShapeDtypeStruct((B, 1, 1), jnp.float32)
 
 
-def _acc_init(i, *refs):
-    @pl.when(i == 0)
+def _coef(tab_ref, col, like_ref):
+    """Row b, column ``col`` of the SMEM scalar table, in ``like_ref``'s
+    storage dtype (the product then runs in storage precision)."""
+    c = tab_ref[pl.program_id(0), col]
+    return jnp.full((1, 1), c, jnp.float32).astype(like_ref.dtype)
+
+
+def _acc_init(*refs):
+    @pl.when(pl.program_id(1) == 0)
     def _():
         for r in refs:
             r[...] = jnp.zeros_like(r)
 
 
+def _call(kernel, B, M, bm, in_specs, out_specs, out_shape, interpret):
+    return pl.pallas_call(kernel, grid=(B, M // bm), in_specs=in_specs,
+                          out_specs=out_specs, out_shape=out_shape,
+                          interpret=interpret)
+
+
 # --- q = r - alpha*s ; partials <q,y>, <y,y> ------------------------------
 
-def _update_q_kernel(alpha_ref, r_ref, s_ref, y_ref, q_ref, qy_ref, yy_ref,
-                     *, seq_axis=0):
-    i = pl.program_id(seq_axis)
-    _acc_init(i, qy_ref, yy_ref)
-    alpha = alpha_ref[0, 0]
-    q = r_ref[...] - (alpha.astype(r_ref.dtype) * s_ref[...])
+def _update_q_kernel(alpha_ref, r_ref, s_ref, y_ref, q_ref, qy_ref, yy_ref):
+    _acc_init(qy_ref, yy_ref)
+    q = r_ref[...] - _coef(alpha_ref, 0, r_ref) * s_ref[...]
     q_ref[...] = q
     yf = y_ref[...].astype(jnp.float32)
     qy_ref[...] += jnp.sum(q.astype(jnp.float32) * yf).reshape(1, 1)
     yy_ref[...] += jnp.sum(yf * yf).reshape(1, 1)
 
 
-def update_q_dots_pallas(alpha, r, s, y, *, bm: int, interpret: bool = True,
-                         batched: bool = False):
-    if batched:
-        B, M = r.shape[0], r.shape[1]
-        row, sca = _row_spec_b(bm), _scalar_spec_b()
-        return pl.pallas_call(
-            functools.partial(_update_q_kernel, seq_axis=1),
-            grid=(B, M // bm),
-            in_specs=[sca, row, row, row],
-            out_specs=[row, sca, sca],
-            out_shape=[
-                jax.ShapeDtypeStruct(r.shape, r.dtype),
-                jax.ShapeDtypeStruct((B, 1), jnp.float32),
-                jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            ],
-            interpret=interpret,
-        )(alpha.reshape(B, 1).astype(jnp.float32), r, s, y)
-    M = r.shape[0]
-    grid = (M // bm,)
-    return pl.pallas_call(
-        _update_q_kernel,
-        grid=grid,
-        in_specs=[_scalar_spec(), _row_spec(bm), _row_spec(bm), _row_spec(bm)],
-        out_specs=[_row_spec(bm), _scalar_spec(), _scalar_spec()],
-        out_shape=[
-            jax.ShapeDtypeStruct(r.shape, r.dtype),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(alpha.reshape(1, 1).astype(jnp.float32), r, s, y)
+def update_q_dots_pallas(alpha, r, s, y, *, bm: int, interpret: bool = True):
+    """``alpha``: (B,) f32; vectors: (B, M, 128)."""
+    B, M, _ = r.shape
+    row = _row_spec(bm)
+    return _call(
+        _update_q_kernel, B, M, bm,
+        [_scalars_spec(), row, row, row],
+        [row, _partial_spec(), _partial_spec()],
+        [jax.ShapeDtypeStruct(r.shape, r.dtype), _partial_shape(B),
+         _partial_shape(B)],
+        interpret,
+    )(alpha.reshape(B, 1).astype(jnp.float32), r, s, y)
 
 
 # --- x += alpha*p + omega*q ; r = q - omega*y ; <r0,r>, <r,r> --------------
 
 def _update_xr_kernel(ab_ref, x_ref, p_ref, q_ref, y_ref, r0_ref,
-                      xo_ref, ro_ref, r0r_ref, rr_ref, *, seq_axis=0):
-    i = pl.program_id(seq_axis)
-    _acc_init(i, r0r_ref, rr_ref)
-    alpha = ab_ref[0, 0].astype(x_ref.dtype)
-    omega = ab_ref[0, 1].astype(x_ref.dtype)
+                      xo_ref, ro_ref, r0r_ref, rr_ref):
+    _acc_init(r0r_ref, rr_ref)
+    alpha = _coef(ab_ref, 0, x_ref)
+    omega = _coef(ab_ref, 1, x_ref)
     q = q_ref[...]
     xo_ref[...] = x_ref[...] + alpha * p_ref[...] + omega * q
     r = q - omega * y_ref[...]
@@ -122,103 +108,56 @@ def _update_xr_kernel(ab_ref, x_ref, p_ref, q_ref, y_ref, r0_ref,
 
 
 def update_xr_dots_pallas(alpha, omega, x, p, q, y, r0, *, bm: int,
-                          interpret: bool = True, batched: bool = False):
-    if batched:
-        B, M = x.shape[0], x.shape[1]
-        ab = jnp.stack([alpha, omega], axis=-1).astype(jnp.float32)  # (B, 2)
-        row = _row_spec_b(bm)
-        return pl.pallas_call(
-            functools.partial(_update_xr_kernel, seq_axis=1),
-            grid=(B, M // bm),
-            in_specs=[_scalar_spec_b(2)] + [row] * 5,
-            out_specs=[row, row, _scalar_spec_b(), _scalar_spec_b()],
-            out_shape=[
-                jax.ShapeDtypeStruct(x.shape, x.dtype),
-                jax.ShapeDtypeStruct(x.shape, x.dtype),
-                jax.ShapeDtypeStruct((B, 1), jnp.float32),
-                jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            ],
-            interpret=interpret,
-        )(ab, x, p, q, y, r0)
-    M = x.shape[0]
-    ab = jnp.stack([alpha, omega]).reshape(1, 2).astype(jnp.float32)
-    return pl.pallas_call(
-        _update_xr_kernel,
-        grid=(M // bm,),
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0))] + [_row_spec(bm)] * 5,
-        out_specs=[_row_spec(bm), _row_spec(bm), _scalar_spec(), _scalar_spec()],
-        out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
+                          interpret: bool = True):
+    """``alpha``/``omega``: (B,) f32; vectors: (B, M, 128)."""
+    B, M, _ = x.shape
+    ab = jnp.stack([alpha.reshape(B), omega.reshape(B)],
+                   axis=-1).astype(jnp.float32)              # (B, 2)
+    row = _row_spec(bm)
+    return _call(
+        _update_xr_kernel, B, M, bm,
+        [_scalars_spec()] + [row] * 5,
+        [row, row, _partial_spec(), _partial_spec()],
+        [jax.ShapeDtypeStruct(x.shape, x.dtype),
+         jax.ShapeDtypeStruct(x.shape, x.dtype),
+         _partial_shape(B), _partial_shape(B)],
+        interpret,
     )(ab, x, p, q, y, r0)
 
 
 # --- p = r + beta*(p - omega*s) -------------------------------------------
 
 def _update_p_kernel(bo_ref, r_ref, p_ref, s_ref, po_ref):
-    beta = bo_ref[0, 0].astype(p_ref.dtype)
-    omega = bo_ref[0, 1].astype(p_ref.dtype)
+    beta = _coef(bo_ref, 0, p_ref)
+    omega = _coef(bo_ref, 1, p_ref)
     po_ref[...] = r_ref[...] + beta * (p_ref[...] - omega * s_ref[...])
 
 
-def update_p_pallas(beta, omega, r, p, s, *, bm: int, interpret: bool = True,
-                    batched: bool = False):
-    if batched:
-        B, M = r.shape[0], r.shape[1]
-        bo = jnp.stack([beta, omega], axis=-1).astype(jnp.float32)   # (B, 2)
-        row = _row_spec_b(bm)
-        return pl.pallas_call(
-            _update_p_kernel,
-            grid=(B, M // bm),
-            in_specs=[_scalar_spec_b(2)] + [row] * 3,
-            out_specs=row,
-            out_shape=jax.ShapeDtypeStruct(r.shape, r.dtype),
-            interpret=interpret,
-        )(bo, r, p, s)
-    M = r.shape[0]
-    bo = jnp.stack([beta, omega]).reshape(1, 2).astype(jnp.float32)
-    return pl.pallas_call(
-        _update_p_kernel,
-        grid=(M // bm,),
-        in_specs=[pl.BlockSpec((1, 2), lambda i: (0, 0))] + [_row_spec(bm)] * 3,
-        out_specs=_row_spec(bm),
-        out_shape=jax.ShapeDtypeStruct(r.shape, r.dtype),
-        interpret=interpret,
+def update_p_pallas(beta, omega, r, p, s, *, bm: int, interpret: bool = True):
+    """``beta``/``omega``: (B,) f32; vectors: (B, M, 128)."""
+    B, M, _ = r.shape
+    bo = jnp.stack([beta.reshape(B), omega.reshape(B)],
+                   axis=-1).astype(jnp.float32)              # (B, 2)
+    row = _row_spec(bm)
+    return _call(
+        _update_p_kernel, B, M, bm,
+        [_scalars_spec()] + [row] * 3, row,
+        jax.ShapeDtypeStruct(r.shape, r.dtype),
+        interpret,
     )(bo, r, p, s)
 
 
 # --- plain mixed-precision dot --------------------------------------------
 
-def _dot_kernel(a_ref, b_ref, o_ref, *, seq_axis=0):
-    i = pl.program_id(seq_axis)
-    _acc_init(i, o_ref)
+def _dot_kernel(a_ref, b_ref, o_ref):
+    _acc_init(o_ref)
     prod = (a_ref[...] * b_ref[...]).astype(jnp.float32)   # bf16 multiply, f32 add
     o_ref[...] += jnp.sum(prod).reshape(1, 1)
 
 
-def dot_mixed_pallas(a, b, *, bm: int, interpret: bool = True,
-                     batched: bool = False):
-    if batched:
-        B, M = a.shape[0], a.shape[1]
-        row = _row_spec_b(bm)
-        return pl.pallas_call(
-            functools.partial(_dot_kernel, seq_axis=1),
-            grid=(B, M // bm),
-            in_specs=[row, row],
-            out_specs=_scalar_spec_b(),
-            out_shape=jax.ShapeDtypeStruct((B, 1), jnp.float32),
-            interpret=interpret,
-        )(a, b)
-    M = a.shape[0]
-    return pl.pallas_call(
-        _dot_kernel,
-        grid=(M // bm,),
-        in_specs=[_row_spec(bm), _row_spec(bm)],
-        out_specs=_scalar_spec(),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        interpret=interpret,
-    )(a, b)
+def dot_mixed_pallas(a, b, *, bm: int, interpret: bool = True):
+    """Per-RHS partials ``(B, 1, 1)`` of <a, b> for (B, M, 128) operands."""
+    B, M, _ = a.shape
+    row = _row_spec(bm)
+    return _call(_dot_kernel, B, M, bm, [row, row], _partial_spec(),
+                 _partial_shape(B), interpret)(a, b)

@@ -31,7 +31,7 @@ from repro.kernels.stencil_nd.fused import (  # noqa: F401  (re-exported API)
 from repro.kernels.stencil_nd.kernel import stencil_nd_pallas
 from repro.kernels.stencil_nd.ops import (  # noqa: F401  (re-exported API)
     VMEM_BUDGET_BYTES,
-    pick_zc,
+    default_tile,
 )
 from repro.kernels.stencil_nd.ref import stencil_nd_ref
 

@@ -1,6 +1,5 @@
 from repro.kernels.stencil_nd.ops import (  # noqa: F401
     pallas_local_apply,
-    pick_zc,
     ring_patch_apply,
     stencil_apply,
     tile_apply,

@@ -21,8 +21,8 @@ form wins.  The sweep decides.
 Fusing the dot into the SpMV's write-out pass removes a full re-read of the
 freshly written vector (and of the second operand), cutting the iteration's
 per-point traffic from 42 to 31 words (see kernels/fused_iter for the AXPY
-fusions).  Dots accumulate in f32 across sequential grid steps (paper FMAC
-discipline).
+fusions).  They share the SpMV kernel's tiling and row sweep; the dots
+accumulate in f32 across the sequential grid steps (paper FMAC discipline).
 
 The dot epilogues are the one radius-1-star specialization left in the
 package (the ``kernels/stencil7`` shim re-exports them under their
@@ -37,9 +37,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import HAS_PL_ELEMENT, resolve_interpret
 from repro.core.stencil import STAR7, StencilCoeffs
-from repro.kernels.stencil_nd.ops import pick_zc
+from repro.kernels import resolve_interpret
+from repro.kernels.stencil_nd.kernel import (
+    accumulate, chunk_rows, sweep, window_call,
+)
+from repro.kernels.stencil_nd.ops import default_tile
 
 # kernel argument order (== STAR7.names: xp, xm, yp, ym, zp, zm)
 ORDER = STAR7.names
@@ -70,64 +73,48 @@ def fused_ring_apply(exchange, cf_list: list[jax.Array], spec, config, *,
                       accum_dtype=accum_dtype, interpret=interpret)
 
 
-def _kernel(vp_ref, w_ref, xp_ref, xm_ref, yp_ref, ym_ref, zp_ref, zm_ref,
-            u_ref, d1_ref, d2_ref, *, accum_dtype, two_dots, block, zc):
-    i = pl.program_id(0)
+def _dot_kernel(vp_ref, w_ref, *refs, tile, rows, accum_dtype, two_dots):
+    cf_refs, (u_ref, d1_ref, d2_ref) = refs[:-3], refs[-3:]
 
-    @pl.when(i == 0)
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+             & (pl.program_id(2) == 0))
     def _():
         d1_ref[...] = jnp.zeros_like(d1_ref)
         d2_ref[...] = jnp.zeros_like(d2_ref)
 
-    vp = vp_ref[...]
-    if not HAS_PL_ELEMENT:
-        # padded iterate fully resident: cut this step's z-window by hand
-        bx, by = block
-        vp = jax.lax.dynamic_slice(vp, (0, 0, i * zc), (bx + 2, by + 2, zc + 2))
-    c = lambda a: a.astype(accum_dtype)
-    u = c(vp[1:-1, 1:-1, 1:-1])
-    u += c(xp_ref[...]) * c(vp[2:, 1:-1, 1:-1])
-    u += c(xm_ref[...]) * c(vp[:-2, 1:-1, 1:-1])
-    u += c(yp_ref[...]) * c(vp[1:-1, 2:, 1:-1])
-    u += c(ym_ref[...]) * c(vp[1:-1, :-2, 1:-1])
-    u += c(zp_ref[...]) * c(vp[1:-1, 1:-1, 2:])
-    u += c(zm_ref[...]) * c(vp[1:-1, 1:-1, :-2])
-    u_ref[...] = u.astype(u_ref.dtype)
-    # epilogue: dots against w (= r0 or q) and optionally u itself, in f32
-    uf = u.astype(jnp.float32)
-    wf = w_ref[...].astype(jnp.float32)
-    d1_ref[...] += jnp.sum(wf * uf).reshape(1, 1)
-    if two_dots:
-        d2_ref[...] += jnp.sum(uf * uf).reshape(1, 1)
+    def body(p, q):
+        u = accumulate(vp_ref, cf_refs, STAR7.offsets, radius=1, p=p, q=q,
+                       rows=rows, zc=tile[2], accum_dtype=accum_dtype)
+        u_ref[pl.ds(p, 1), pl.ds(q, rows), :] = u.astype(u_ref.dtype)
+        # epilogue: dots against w (= r0 or q) and optionally u itself, in f32
+        uf = u.astype(jnp.float32)[0]
+        wf = w_ref[p, pl.ds(q, rows), :].astype(jnp.float32)
+        d1_ref[...] += jnp.sum(wf * uf).reshape(1, 1)
+        if two_dots:
+            d2_ref[...] += jnp.sum(uf * uf).reshape(1, 1)
+
+    sweep(tile, rows, body)
 
 
 def _call(coeffs: StencilCoeffs, v: jax.Array, w: jax.Array, *, two_dots: bool,
           accum_dtype=jnp.float32, interpret: bool | None = None):
-    interpret = resolve_interpret(interpret)
-    bx, by, Z = v.shape
-    zc = pick_zc(bx, by, Z, jnp.dtype(v.dtype).itemsize)
-    vp = jnp.pad(v, ((1, 1), (1, 1), (1, 1)))
-    if HAS_PL_ELEMENT:
-        vspec = pl.BlockSpec(
-            (pl.Element(bx + 2), pl.Element(by + 2), pl.Element(zc + 2)),
-            lambda i: (0, 0, i * zc))
-    else:
-        vspec = pl.BlockSpec(vp.shape, lambda i: (0, 0, 0))
-    cspec = pl.BlockSpec((bx, by, zc), lambda i: (0, 0, i))
-    sspec = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    u, d1, d2 = pl.pallas_call(
-        functools.partial(_kernel, accum_dtype=accum_dtype, two_dots=two_dots,
-                          block=(bx, by), zc=zc),
-        grid=(Z // zc,),
-        in_specs=[vspec, cspec] + [cspec] * 6,
-        out_specs=[cspec, sspec, sspec],
+    shape = v.shape
+    # tile and row chunk as for the plain SpMV, with w as one more operand
+    tile = default_tile(shape, jnp.dtype(v.dtype).itemsize, n_coeffs=7)
+    rows = chunk_rows(tile[1], tile[2])
+    sspec = pl.BlockSpec((1, 1), lambda i, j, k: (0, 0))
+    u, d1, d2 = window_call(
+        functools.partial(_dot_kernel, tile=tile, rows=rows,
+                          accum_dtype=accum_dtype, two_dots=two_dots),
+        jnp.pad(v, 1), [w] + [coeffs.diags[n] for n in ORDER],
+        radius=1, tile=tile,
         out_shape=[
-            jax.ShapeDtypeStruct((bx, by, Z), v.dtype),
+            jax.ShapeDtypeStruct(shape, v.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(vp, w, *[coeffs.diags[n] for n in ORDER])
+        out_specs_for=lambda nb, ospec: [ospec, sspec, sspec],
+        interpret=resolve_interpret(interpret))
     return u, d1[0, 0], d2[0, 0]
 
 

@@ -4,7 +4,7 @@ solve_distributed) that pairs the kernel with the depth-r halo exchange.
 
 Every wrapper resolves its tile shapes through the persistent tuning cache
 (``core/tuning``): a swept cell transparently gets its winning
-``KernelConfig`` (x/y tile, Z split, VMEM residency, ring fusion); an
+``KernelConfig`` (x/y tile, Z split, ring fusion); an
 unswept cell falls back to the deterministic pre-tuning default, so an
 empty cache reproduces the fixed-shape behaviour bit-for-bit.
 """
@@ -16,25 +16,34 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import resolve_interpret
 from repro.core.stencil import StencilCoeffs, StencilSpec
+from repro.kernels import resolve_interpret
+from repro.kernels.stencil_nd.kernel import (
+    LANES, SUBLANES, tile_bytes, tile_extents,
+)
 
-VMEM_BUDGET_BYTES = 64 * 2 ** 20     # half of a v5e core's ~128MB VMEM
+#: double-buffered working set a default tile may take: half of a v5e
+#: core's 128 MiB of VMEM (the kernels raise their scoped limit to
+#: ``kernel.VMEM_LIMIT_BYTES``).
+VMEM_BUDGET_BYTES = 64 * 2 ** 20
 
 
-def pick_zc(bx: int, by: int, Z: int, itemsize: int, *,
-            radius: int = 1, n_coeffs: int = 6,
-            budget: int = VMEM_BUDGET_BYTES) -> int:
-    """Largest Z chunk whose working set fits the VMEM budget."""
-    r = radius
-    zc = Z
-    while zc > 1:
-        vmem = ((bx + 2 * r) * (by + 2 * r) * (zc + 2 * r)
-                + (n_coeffs + 1) * bx * by * zc) * itemsize
-        if vmem <= budget and Z % zc == 0:
-            return zc
-        zc //= 2
-    return 1
+def default_tile(shape: tuple[int, int, int], itemsize: int, *,
+                 radius: int = 1, n_coeffs: int = 6,
+                 budget: int = VMEM_BUDGET_BYTES) -> tuple[int, int, int]:
+    """The deterministic tile of an untuned cell: Z whole, then Y whole,
+    then the deepest x slab whose double-buffered working set (window +
+    coefficients + output) fits ``budget``; Y and then Z are split only
+    when a single x plane does not fit.  Falls back to the smallest valid
+    tile."""
+    X, Y, Z = shape
+    for zc in tile_extents(Z, LANES):
+        for byc in tile_extents(Y, SUBLANES):
+            for bxc in tile_extents(X, 1):
+                if tile_bytes((bxc, byc, zc), shape, itemsize, radius=radius,
+                              n_tiled=n_coeffs + 1) <= budget:
+                    return bxc, byc, zc
+    return 1, tile_extents(Y, SUBLANES)[-1], tile_extents(Z, LANES)[-1]
 
 
 def _spec_order(coeffs: StencilCoeffs, spec: StencilSpec):
@@ -58,8 +67,8 @@ def tile_apply(vp: jax.Array, cf_list: list[jax.Array], spec: StencilSpec,
 
     return stencil_nd_pallas(
         vp, cf_list, spec.offsets, radius=spec.radius, zc=config.zc,
-        block=config.block, resident=config.resident,
-        accum_dtype=accum_dtype, interpret=resolve_interpret(interpret))
+        block=config.block, accum_dtype=accum_dtype,
+        interpret=resolve_interpret(interpret))
 
 
 def ring_patch_apply(exchange, cf_list: list[jax.Array], spec: StencilSpec,
@@ -73,8 +82,8 @@ def ring_patch_apply(exchange, cf_list: list[jax.Array], spec: StencilSpec,
     The patch re-runs the same Pallas kernel (not a jnp re-derivation,
     whose fusion can differ by an ulp), so overlap stays bit-identical to
     blocking.  Slab tiles are sized per-slab (a tuned full-block tile does
-    not fit a depth-r slab); the slab kernels reuse the default VMEM
-    chunking for their own shapes.  A batched exchange patches every RHS's
+    not fit a depth-r slab): each slab takes the default tile of its own
+    shape.  A batched exchange patches every RHS's
     ring in the same per-region launches (the slab kernel grids over the
     batch axis).
     """
@@ -82,7 +91,6 @@ def ring_patch_apply(exchange, cf_list: list[jax.Array], spec: StencilSpec,
 
     r = spec.radius
     pre = (slice(None),) * exchange.n_batch
-    itemsize = jnp.dtype(exchange.padded.dtype).itemsize
     for reg in comm.boundary_regions(exchange.shape, fabric, r):
         lo_hi = [(sl.start or 0,
                   exchange.shape[i] if sl.stop is None else sl.stop)
@@ -90,13 +98,14 @@ def ring_patch_apply(exchange, cf_list: list[jax.Array], spec: StencilSpec,
         sub_shape = tuple(hi - lo for lo, hi in lo_hi)
         sub_vp = exchange.padded[pre + tuple(slice(lo, hi + 2 * r)
                                              for lo, hi in lo_hi)]
-        sub_cfg = tuning.KernelConfig(
-            block=sub_shape[:2],
-            zc=pick_zc(*sub_shape, itemsize, radius=r,
-                       n_coeffs=spec.n_offsets),
-            resident=config.resident)
+        sub_cfg = tuning.default_config(spec, exchange.padded.dtype,
+                                        sub_shape)
         patch = tile_apply(sub_vp, [c[reg] for c in cf_list], spec, sub_cfg,
                            accum_dtype=accum_dtype, interpret=interpret)
+        # the TPU compiler aborts (HloReachabilityMap out of range) when it
+        # weighs fusing a kernel's output into this update on a split
+        # fabric; the barrier keeps the kernels and the update apart
+        u, patch = jax.lax.optimization_barrier((u, patch))
         u = u.at[pre + reg].set(patch)
     return u
 
@@ -113,8 +122,8 @@ def stencil_apply(coeffs: StencilCoeffs, v: jax.Array, *,
     shape alone (a tuned cell's config applies to every batch size).
 
     Tile shapes come from the tuning cache (trace-time lookup keyed by
-    {spec x dtype x shape}); without an entry the deterministic default
-    (full-block tile, VMEM-budgeted Z chunk) reproduces the untuned kernel.
+    {device x spec x dtype x shape}); without an entry the deterministic
+    default (:func:`default_tile`) applies.
     """
     from repro.core import tuning
 

@@ -34,6 +34,7 @@ from repro.core import precision
 from repro.core.comm import SCHEDULES
 from repro.core.precond import PRECONDS
 from repro.core.solvers import SOLVERS
+from repro.launch import enable_compile_cache
 from repro.launch.mesh import make_mesh_for_devices
 
 
@@ -97,6 +98,7 @@ def main() -> None:
     ap.add_argument("--run-dir", default=None,
                     help="bundle directory override (implies --obs)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     args.obs = args.obs or args.profile or args.run_dir is not None
     run_ctx = None

@@ -34,14 +34,11 @@ from repro.configs import ARCH_IDS, get_config
 from repro.configs.stencil_cs1 import STENCIL_CELLS
 from repro.core import bicgstab, precision
 from repro.core.halo import FabricAxes
+from repro.core.perfmodel import HBM_BW, PEAK_FLOPS  # one v5e chip
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.models.transformer import ArchConfig
 
-
-# TPU v5e hardware constants (assignment sheet)
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
 LINK_BW = 50e9               # bytes/s per ICI link
 
 LM_SHAPES = ["train_4k", "prefill_32k", "decode_32k", "long_500k"]
@@ -176,8 +173,7 @@ def _compile_step(cfg: ArchConfig, shape, mesh):
     """Lower+compile the cell's step under the ambient mesh."""
     params = M.abstract_params(cfg, mesh)
     batch = M.input_specs(cfg, shape, mesh)
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             opt = M.abstract_opt_state(cfg, mesh)
             step = M.make_train_step(cfg)

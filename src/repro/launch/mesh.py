@@ -20,28 +20,33 @@ from __future__ import annotations
 import jax
 
 
-def _make_mesh(shape, axis_names):
-    """jax.make_mesh, version-gated (see repro.compat.make_mesh)."""
-    from repro.compat import make_mesh
-    return make_mesh(shape, axis_names)
+def make_mesh(shape, axis_names, *, devices=None):
+    """``jax.make_mesh`` with explicit Auto axis types (the solver's
+    shard_maps name every axis; nothing relies on explicit sharding)."""
+    return jax.make_mesh(
+        shape, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The target mesh: one pod = 16 x 16 = 256 chips; two pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_for_devices(n_devices: int | None = None, *, pods: int = 1):
-    """Largest near-square 2D (or 3D with pods) mesh for the available devices.
+    """Largest near-square 2D (or 3D with pods) mesh over the first
+    ``n_devices`` devices (default: all of them).
 
-    Used by tests and CPU-scale examples; on a 1-device CPU this degenerates
-    to a 1x1 mesh and all collectives become no-ops (boundary semantics are
-    preserved because ppermute fills non-received shards with zeros).
+    On one device this degenerates to a 1x1 mesh and all collectives become
+    no-ops (boundary semantics are preserved because ppermute fills
+    non-received shards with zeros).
     """
+    devices = jax.devices()
     if n_devices is None:
-        n_devices = len(jax.devices())
+        n_devices = len(devices)
+    devices = devices[:n_devices]
     per_pod = n_devices // pods
     x = 1
     for cand in range(int(per_pod ** 0.5), 0, -1):
@@ -50,8 +55,9 @@ def make_mesh_for_devices(n_devices: int | None = None, *, pods: int = 1):
             break
     y = per_pod // x
     if pods > 1:
-        return _make_mesh((pods, x, y), ("pod", "data", "model"))
-    return _make_mesh((x, y), ("data", "model"))
+        return make_mesh((pods, x, y), ("pod", "data", "model"),
+                         devices=devices)
+    return make_mesh((x, y), ("data", "model"), devices=devices)
 
 
 def fabric_shape(mesh) -> tuple[int, int, int]:

@@ -1,21 +1,25 @@
-"""Stencil-solver driver: the paper's experiment at CPU scale, for the
-whole stencil family and the full solver x backend x preconditioner matrix.
+"""Stencil-solver driver: the paper's experiment through one entry point,
+for the whole stencil family and the full solver x backend x
+preconditioner matrix.
 
     PYTHONPATH=src python -m repro.launch.solve --mesh 48 48 32 --policy bf16_mixed
+    PYTHONPATH=src python -m repro.launch.solve --mesh 608 608 608 --backend pallas
     PYTHONPATH=src python -m repro.launch.solve --stencil star25 --mesh 24 24 16
     PYTHONPATH=src python -m repro.launch.solve --solver cg --problem poisson
     PYTHONPATH=src python -m repro.launch.solve --precond chebyshev --problem poisson
-    PYTHONPATH=src python -m repro.launch.solve --backend pallas --mesh 16 16 8
     PYTHONPATH=src python -m repro.launch.solve --solver pipelined_bicgstab --schedule overlap
     PYTHONPATH=src python -m repro.launch.solve --backend pallas --autotune --mesh 16 16 8
 
 Builds a diagonally-dominant system with the requested stencil shape
 (``star7`` is the paper's 7-point MFIX class; ``star25`` the high-order
-seismic shape of Jacquelin et al.; ``box27`` the full-neighborhood cube),
-solves it with the selected Krylov solver on the available device fabric —
-through the SPMD halo path or the Pallas fused-kernel backend, optionally
-right-preconditioned — and reports iterations / residuals / timings, with
-the iterative-refinement option for f32-grade accuracy from a 16-bit solve.
+seismic shape of Jacquelin et al.; ``box27`` the full-neighborhood cube)
+directly in the solve's sharding, solves it with the selected Krylov
+solver on a mesh of the first ``--devices`` devices — through the SPMD
+halo path or the Pallas fused-kernel backend, optionally
+right-preconditioned — checks the true residual on the device, and reports
+iterations, residuals, compile and warm-call seconds and peak device
+memory.  :func:`main` returns the same numbers (and, under ``"x"``, the
+solution array) as a dict, so a caller can drive the solve in-process.
 """
 
 from __future__ import annotations
@@ -26,51 +30,107 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
-from repro.core import bicgstab, precision, stencil
+from repro.core import bicgstab, perfmodel, precision, stencil
 from repro.core.comm import SCHEDULES
+from repro.core.halo import FabricAxes, global_apply
 from repro.core.operator import BACKENDS
 from repro.core.precond import PRECONDS, PrecondConfig
 from repro.core.solvers import SOLVERS
+from repro.launch import enable_compile_cache
 from repro.launch.mesh import make_mesh_for_devices
 
+PROBLEMS = ("convdiff", "random", "poisson", "heterogeneous", "seismic")
 
-def build_problem(args, spec: stencil.StencilSpec):
-    """Coefficients for the requested (problem, spec) pair."""
-    shape = tuple(args.mesh)
+
+def default_problem(solver: str, spec: stencil.StencilSpec) -> str:
+    """The shape-appropriate problem when ``--problem`` is not given."""
+    if solver in ("cg", "pipelined_cg"):
+        return "poisson"          # CG wants a symmetric operator
+    if spec == stencil.STAR7:
+        return "convdiff"
+    return "seismic" if spec.pattern == "star" else "random"
+
+
+def make_coeffs(problem: str, spec: stencil.StencilSpec, shape) -> stencil.StencilCoeffs:
+    """The f32 coefficients of the requested (problem, spec) pair."""
     key = jax.random.PRNGKey(0)
-    problem = args.problem
-    if problem is None:  # shape-appropriate default
-        if args.solver in ("cg", "pipelined_cg"):
-            problem = "poisson"      # CG wants a symmetric operator
-        elif spec == stencil.STAR7:
-            problem = "convdiff"
-        elif spec.pattern == "star":
-            problem = "seismic"
-        else:
-            problem = "random"
     if problem == "random":
-        return problem, stencil.random_nonsymmetric(key, shape, spec=spec)
+        return stencil.random_nonsymmetric(key, shape, spec=spec)
     if problem == "poisson":
-        return problem, stencil.poisson(shape, spec=spec)
+        return stencil.poisson(shape, spec=spec)
     if problem == "heterogeneous":
-        return problem, stencil.heterogeneous_poisson(key, shape, spec=spec)
+        return stencil.heterogeneous_poisson(key, shape, spec=spec)
     if problem == "seismic":
         if spec.pattern != "star":
             raise SystemExit("--problem seismic needs a star stencil")
-        return problem, stencil.high_order_star(shape, spec.radius)
+        return stencil.high_order_star(shape, spec.radius)
     if problem == "convdiff":
         if spec != stencil.STAR7:
             raise SystemExit("--problem convdiff is the 7-point MFIX class; "
                              "use seismic/random/poisson for other stencils")
-        return problem, stencil.convection_diffusion(shape)
+        return stencil.convection_diffusion(shape)
     raise SystemExit(f"unknown problem {problem!r}")
 
 
-def main() -> None:
+def build_problem(problem: str, spec: stencil.StencilSpec, shape, *,
+                  dtype, nrhs: int = 1, mesh=None):
+    """``(coeffs, x_true, b)`` of the manufactured system, in ``dtype``.
+
+    ``b = A x_true`` is formed from the f32 coefficients and the stored
+    ``x_true``, then rounded to ``dtype``.  With a ``mesh`` every array is
+    built directly in the solve's ``NamedSharding`` — each device computes
+    its own block, and no global array ever lands on one device.  The
+    values equal the unsharded build's.
+    """
+    nb = 1 if nrhs > 1 else 0      # nrhs == 1 stays unbatched (bitwise)
+    xshape = (nrhs,) * nb + tuple(shape)
+
+    def make():
+        cf = make_coeffs(problem, spec, shape)
+        x_true = jax.random.normal(jax.random.PRNGKey(1), xshape,
+                                   jnp.float32).astype(dtype)
+        b = stencil.rhs_for_solution(cf, x_true).astype(dtype)
+        return cf.astype(dtype), x_true, b
+
+    if mesh is None:
+        return jax.jit(make)()
+    fabric = FabricAxes.from_mesh(mesh)
+    csh = NamedSharding(mesh, fabric.spec(len(shape)))
+    vsh = NamedSharding(mesh, fabric.spec(len(shape), n_batch=nb))
+    # csh is a prefix: it shards every diagonal of the coefficient pytree
+    return jax.jit(make, out_shardings=(csh, vsh, vsh))()
+
+
+def true_residual(mesh, coeffs, x, b) -> jax.Array:
+    """``||b - A x|| / ||b||`` (per RHS) in f32, on the device, in the
+    solve's sharding: the stored operator applied through the halo path
+    with f32 products, then fabric-wide norms."""
+    def rel(cf, xx, bb):
+        r = bb.astype(jnp.float32) - global_apply(
+            mesh, cf, xx, policy=precision.F32)
+        axes = tuple(range(r.ndim - cf.ndim, r.ndim))
+        bf = bb.astype(jnp.float32)
+        return (jnp.sqrt(jnp.sum(r * r, axes))
+                / jnp.sqrt(jnp.sum(bf * bf, axes)))
+
+    return jax.jit(rel)(coeffs, x, b)
+
+
+def peak_bytes(devices) -> list[int]:
+    """Peak bytes in use on each device since the process started; empty
+    where the backend keeps no statistics, as the CPU does."""
+    stats = [d.memory_stats() for d in devices]
+    return [int(s["peak_bytes_in_use"]) for s in stats if s]
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", type=int, nargs=3, default=[48, 48, 32],
                     metavar=("X", "Y", "Z"))
+    ap.add_argument("--devices", type=int, default=None,
+                    help="solve on the first N devices (default: all)")
     ap.add_argument("--stencil", default="star7", choices=sorted(stencil.SPECS),
                     help="stencil shape: star7 (paper), star13, star25 "
                          "(seismic RTM), box27")
@@ -95,9 +155,7 @@ def main() -> None:
                     choices=sorted(precision.POLICIES))
     ap.add_argument("--tol", type=float, default=1e-6)
     ap.add_argument("--maxiter", type=int, default=200)
-    ap.add_argument("--problem", default=None,
-                    choices=["convdiff", "random", "poisson", "heterogeneous",
-                             "seismic"],
+    ap.add_argument("--problem", default=None, choices=list(PROBLEMS),
                     help="default: convdiff for star7, seismic for deeper "
                          "stars, random for box, poisson for --solver cg; "
                          "heterogeneous is the raw variable-diagonal case "
@@ -126,9 +184,17 @@ def main() -> None:
     ap.add_argument("--run-dir", default=None,
                     help="bundle directory override (implies --obs; "
                          "default results/runs/<run_id>)")
-    args = ap.parse_args()
-
+    args = ap.parse_args(argv)
+    if args.nrhs < 1:
+        ap.error("--nrhs must be >= 1")
     args.obs = args.obs or args.profile or args.run_dir is not None
+    return args
+
+
+def main(argv=None) -> dict:
+    """Run one solve as the command line describes; return its summary."""
+    args = parse_args(argv)
+    enable_compile_cache()
     run_ctx = None
     if args.obs:
         from repro.obs import manifest as obs_manifest
@@ -139,7 +205,7 @@ def main() -> None:
             "solve", config=vars(args), run_dir=args.run_dir,
             profile=args.profile)
     try:
-        _solve(args)
+        return _solve(args)
     finally:
         if run_ctx is not None:
             from repro.obs import manifest as obs_manifest
@@ -148,7 +214,13 @@ def main() -> None:
             print(f"run bundle: {run_ctx.run_dir}")
 
 
-def _solve(args) -> None:
+def _fmt(v) -> str:
+    a = np.asarray(v)
+    return (f"{float(a):.3e}" if a.ndim == 0
+            else "[" + ", ".join(f"{x:.3e}" for x in a.reshape(-1)) + "]")
+
+
+def _solve(args) -> dict:
     if args.policy == "f64":
         # get_policy("f64") refuses to hand out a policy that would silently
         # degrade; the CLI owns process startup, so it can just enable x64.
@@ -156,18 +228,21 @@ def _solve(args) -> None:
     shape = tuple(args.mesh)
     spec = stencil.get_spec(args.stencil)
     pol = precision.get_policy(args.policy)
-    mesh = make_mesh_for_devices()
-    problem, cf = build_problem(args, spec)
+    mesh = make_mesh_for_devices(args.devices)
+    devices = list(mesh.devices.flat)
+    dev0 = devices[0]
+    kind = dev0.device_kind
+    problem = args.problem or default_problem(args.solver, spec)
     print(f"problem {problem}/{spec.name} (radius {spec.radius}, "
           f"{spec.n_points} points) {shape} on fabric {dict(mesh.shape)} "
-          f"solver={args.solver} backend={args.backend} "
-          f"schedule={args.schedule} precond={args.precond} policy={pol.name}")
+          f"of {len(devices)} x {kind} solver={args.solver} "
+          f"backend={args.backend} schedule={args.schedule} "
+          f"precond={args.precond} policy={pol.name}")
 
     if args.autotune:
         # tune the per-shard kernel cell the pallas backend will look up:
         # the local block shape under this fabric, in the storage dtype
         from repro.core import tuning
-        from repro.core.halo import FabricAxes
 
         fabric = FabricAxes.from_mesh(mesh)
         local = (shape[0] // fabric.nx, shape[1] // fabric.ny,
@@ -178,12 +253,23 @@ def _solve(args) -> None:
               + ("" if rec["cache_hit"] else
                  f", speedup vs default {rec['speedup_vs_default']:.2f}x"))
 
-    if args.nrhs < 1:
-        raise SystemExit("--nrhs must be >= 1")
-    # nrhs == 1 stays on the unbatched path (bitwise-identical output)
-    xshape = (args.nrhs,) + shape if args.nrhs > 1 else shape
-    x_true = jax.random.normal(jax.random.PRNGKey(1), xshape, jnp.float32)
-    b = stencil.rhs_for_solution(cf, x_true)
+    t0 = time.perf_counter()
+    # refinement is the f32-accuracy path: its outer residuals need the f32
+    # system; a plain solve gets its data in the storage dtype
+    dtype = jnp.float32 if args.refine else pol.storage
+    cf, x_true, b = build_problem(problem, spec, shape, dtype=dtype,
+                                  nrhs=args.nrhs, mesh=mesh)
+    jax.block_until_ready(b)
+    build_s = time.perf_counter() - t0
+    summary = {
+        "problem": problem, "stencil": spec.name, "shape": list(shape),
+        "solver": args.solver, "backend": args.backend,
+        "schedule": args.schedule, "precond": args.precond,
+        "policy": pol.name, "nrhs": args.nrhs,
+        "platform": dev0.platform, "device_kind": kind,
+        "device_count": len(devices), "fabric": dict(mesh.shape),
+        "build_s": build_s,
+    }
 
     if args.refine:
         if args.nrhs > 1:
@@ -192,14 +278,17 @@ def _solve(args) -> None:
             raise SystemExit(
                 "--refine drives its own inner bicgstab/spmd solves and does "
                 "not honor --solver/--backend/--precond; drop those flags")
-        t0 = time.time()
+        t0 = time.perf_counter()
         x, rels = bicgstab.solve_refined(cf, b, mesh=mesh, inner_policy=pol)
-        dt = time.time() - t0
+        jax.block_until_ready(x)
+        dt = time.perf_counter() - t0
         print("refinement true-residual trajectory:",
               [f"{r:.2e}" for r in np.asarray(rels)])
-        err = float(jnp.abs(x - x_true).max())
+        err = float(jnp.abs(x - x_true.astype(jnp.float32)).max())
         print(f"max err vs manufactured solution: {err:.3e}  ({dt:.2f}s)")
-        return
+        summary.update(refine_residuals=np.asarray(rels).tolist(),
+                       max_err=err, wall_s=dt)
+        return summary
 
     from repro.core.solvers.common import emit_solve_metrics
     from repro.obs import metrics as obs_metrics
@@ -213,58 +302,76 @@ def _solve(args) -> None:
     labels = dict(solver=args.solver, backend=args.backend,
                   schedule=args.schedule, nrhs=args.nrhs, problem=problem,
                   policy=pol.name)
-    bs = b.astype(pol.storage)
-    t0 = time.time()
+    solve = jax.jit(lambda c, v: bicgstab.solve_distributed(
+        mesh, c, v, **solve_kwargs))
+    t0 = time.perf_counter()
+    with obs_trace.span("solve.compile", **labels):
+        lowered = solve.lower(cf, b)
+        compiled = lowered.compile()
+    compile_s = time.perf_counter() - t0
+    # the first call pays one-time set-up; the warm second call is the
+    # solve's time
+    t0 = time.perf_counter()
+    res = jax.block_until_ready(compiled(cf, b))
+    first_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
     with obs_trace.span("solve.krylov", **labels) as sp:
-        res = bicgstab.solve_distributed(mesh, cf, bs, **solve_kwargs)
+        res = compiled(cf, b)
         sp.block(res.x)
     jax.block_until_ready(res.x)
-    dt = time.time() - t0
-    emit_solve_metrics(res, wall_s=dt, **labels)
+    warm_s = time.perf_counter() - t0
+    emit_solve_metrics(res, wall_s=warm_s, **labels)
     if obs_trace.is_enabled():
-        # lowered-HLO collective counts for this exact solve (lower only,
-        # no second compile) — the events.jsonl ground truth tests check
-        with obs_trace.span("solve.lower_hlo"):
-            text = jax.jit(
-                lambda c, v: bicgstab.solve_distributed(
-                    mesh, c, v, **solve_kwargs)).lower(cf, bs).as_text()
-        counts = obs_metrics.record_collectives(text, **labels)
+        # collective counts of this exact solve program — the events.jsonl
+        # ground truth the tests check
+        counts = obs_metrics.record_collectives(lowered.as_text(), **labels)
         print(f"collectives (whole solve HLO): "
               f"allreduce={counts['allreduce_total']} "
               f"ppermute={counts['ppermute_total']}")
-    # achieved-vs-peak roofline fraction, the paper's accounting (§VII:
-    # ~1/3 of peak on the CS-1; a CPU smoke run reports a tiny fraction)
-    iters_total = int(np.asarray(res.iterations).sum())
-    from repro.core import perfmodel
 
-    achieved = (perfmodel.FLOPS_PER_PT * float(np.prod(shape))
-                * iters_total / max(dt, 1e-12))
-    frac = obs_metrics.roofline_fraction(achieved)
-    print(f"roofline: {achieved / 1e9:.2f} GFLOP/s achieved, "
-          f"{frac:.2e} of wafer peak")
-    bb = np.asarray(b, np.float64)
-    r = bb - np.asarray(
-        stencil.apply_ref(cf.astype(jnp.float32), res.x.astype(jnp.float32)))
-    if args.nrhs > 1:
-        axes = tuple(range(1, bb.ndim))
-        true_rel = (np.sqrt((r ** 2).sum(axes))
-                    / np.sqrt((bb ** 2).sum(axes)))
-        iters = np.asarray(res.iterations)
-        print(f"per-RHS iterations: {iters.tolist()}")
-        print(f"per-RHS converged:  {np.asarray(res.converged).tolist()}")
-        print("recurrence rel-residuals:",
-              [f"{v:.3e}" for v in np.asarray(res.rel_residual)])
-        print("true rel-residuals (f32 check):",
-              [f"{v:.3e}" for v in true_rel])
-        print(f"wall time: {dt:.2f}s for {args.nrhs} RHS "
-              f"({dt / max(int(iters.max()), 1) * 1e3:.1f} ms/iter on CPU)")
-        return
-    true_rel = np.linalg.norm(r) / np.linalg.norm(bb)
-    print(f"iterations: {int(res.iterations)}  converged: {bool(res.converged)}")
-    print(f"recurrence rel-residual: {float(res.rel_residual):.3e}")
-    print(f"true rel-residual (f32 check): {true_rel:.3e}")
-    print(f"wall time: {dt:.2f}s "
-          f"({dt / max(int(res.iterations), 1) * 1e3:.1f} ms/iter on CPU)")
+    true_rel = np.asarray(true_residual(mesh, cf, res.x, b))
+    iters = np.asarray(res.iterations)
+    rec_rel = np.asarray(res.rel_residual)
+    n_iter = int(iters.max())
+    peaks = peak_bytes(devices)
+    summary.update(
+        iterations=iters.tolist(), converged=np.asarray(res.converged).tolist(),
+        recurrence_rel_residual=rec_rel.tolist(),
+        true_rel_residual=true_rel.tolist(),
+        compile_s=compile_s, first_call_s=first_s, warm_s=warm_s,
+        tpu_custom_calls=compiled.as_text().count("tpu_custom_call"),
+        peak_bytes_per_device=peaks,
+        x=res.x)      # the solution itself, still on the device(s)
+
+    # rates per chip from the warm wall time and the perf model's
+    # per-iteration flops and HBM words over the meshpoints each chip
+    # holds; the FLOP rate is quoted against one chip's published peak.
+    # The byte rate is a model estimate (no trace counts the bytes moved),
+    # so it gets no peak share.
+    pts = float(np.prod(shape)) * args.nrhs / len(devices)
+    flops = perfmodel.FLOPS_PER_PT * pts * n_iter / max(warm_s, 1e-12)
+    words = perfmodel.SOLVER_COMMS.get(args.solver)
+    model_gbs = (words.words_per_pt * pol.storage.itemsize * pts * n_iter
+                 / max(warm_s, 1e-12) / 1e9) if words else None
+    frac = obs_metrics.roofline_fraction(flops, device_kind=kind)
+    summary.update(roofline_fraction=frac, model_gb_per_s=model_gbs)
+    share = (obs_metrics.NOT_MEASURED + f" (no published peak for {kind})"
+             if frac is None else f"{frac:.3e} of {kind} peak FLOP/s")
+    print(f"per chip (warm wall time): {flops / 1e9:.2f} GFLOP/s by the "
+          f"model, {share}"
+          + ("" if model_gbs is None else
+             f"; model-estimated HBM rate {model_gbs:.2f} GB/s"))
+    print(f"iterations: {iters.tolist() if iters.ndim else int(iters)}  "
+          f"converged: {np.asarray(res.converged).tolist()}")
+    print(f"recurrence rel-residual: {_fmt(rec_rel)}")
+    print(f"true rel-residual (f32, on device): {_fmt(true_rel)}")
+    print(f"build {build_s:.2f}s, compile {compile_s:.2f}s, first call "
+          f"{first_s:.2f}s, warm call "
+          f"{warm_s:.3f}s ({warm_s / max(n_iter, 1) * 1e3:.2f} ms/iter on "
+          f"{len(devices)} x {kind})")
+    if peaks:
+        print(f"peak bytes in use per device (this process so far): {peaks}")
+    return summary
 
 
 if __name__ == "__main__":

@@ -64,8 +64,7 @@ def main() -> None:
 
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     opt = adamw_init(params)
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if len(jax.devices()) > 1:
             shardings = M.param_shardings(cfg, mesh)
             params = jax.device_put(params, shardings)

@@ -83,8 +83,7 @@ def constrain(x: jax.Array, axes: tuple[str | None, ...], rules: dict | None = N
     Outside ``jax.sharding.set_mesh`` (smoke tests, single device) this is a
     no-op, so model code stays mesh-agnostic.
     """
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not mesh.axis_names:
         return x
     return jax.lax.with_sharding_constraint(

@@ -23,7 +23,6 @@ import dataclasses
 import json
 import os
 import platform
-import subprocess
 import sys
 import time
 
@@ -35,7 +34,7 @@ DEFAULT_ROOT = os.path.join("results", "runs")
 # Env vars worth pinning in the manifest: anything that changes lowering,
 # device fabric, kernels, or cache behavior.
 _ENV_KEYS = ("XLA_FLAGS", "JAX_ENABLE_X64", "JAX_PLATFORMS",
-             "REPRO_DEVICES", "REPRO_PALLAS_INTERPRET", "REPRO_TUNING_CACHE",
+             "JAX_COMPILATION_CACHE_DIR", "REPRO_TUNING_CACHE",
              "LD_PRELOAD", "TF_CPP_MIN_LOG_LEVEL")
 
 _REQUIRED_FIELDS = ("schema", "run_id", "kind", "created_unix", "created",
@@ -43,22 +42,28 @@ _REQUIRED_FIELDS = ("schema", "run_id", "kind", "created_unix", "created",
                     "metrics", "wall_s")
 
 
-def git_info() -> dict:
-    """Best-effort git SHA/branch/dirty for the working tree."""
-    def _run(*cmd):
+def git_info(root: str = ".") -> dict:
+    """Best-effort git SHA/branch of the checkout at ``root``, read from
+    ``.git`` directly (a run starts no child process); ``dirty`` is None
+    because only git itself can tell."""
+    def _read(*parts):
         try:
-            out = subprocess.run(["git", *cmd], capture_output=True,
-                                 text=True, timeout=10)
-            return out.stdout.strip() if out.returncode == 0 else None
-        except Exception:
+            with open(os.path.join(root, ".git", *parts)) as f:
+                return f.read().strip()
+        except OSError:
             return None
 
-    sha = _run("rev-parse", "HEAD")
-    return {
-        "sha": sha or "unknown",
-        "branch": _run("rev-parse", "--abbrev-ref", "HEAD") or "unknown",
-        "dirty": bool(_run("status", "--porcelain")) if sha else None,
-    }
+    head = _read("HEAD") or ""
+    branch, sha = "unknown", head or None
+    if head.startswith("ref: "):
+        ref = head[5:]
+        branch = ref.rsplit("/", 1)[-1]
+        sha = _read(*ref.split("/"))
+        if sha is None:
+            for line in (_read("packed-refs") or "").splitlines():
+                if line.endswith(" " + ref):
+                    sha = line.split()[0]
+    return {"sha": sha or "unknown", "branch": branch, "dirty": None}
 
 
 def versions() -> dict:
@@ -134,14 +139,12 @@ def start_run(kind: str, *, config: dict | None = None,
                      config=_jsonable(config or {}),
                      t_start=time.time(), profile=profile)
     if profile:
-        try:
-            import jax
+        import jax
 
-            ctx._profiler = jax.profiler.trace(
-                os.path.join(run_dir, "jax_profile"))
-            ctx._profiler.__enter__()
-        except Exception:  # pragma: no cover - profiler-less builds
-            ctx._profiler = None
+        # a profiler that cannot start is an error: --profile must never
+        # exit 0 without a trace
+        ctx._profiler = jax.profiler.trace(os.path.join(run_dir, "jax_profile"))
+        ctx._profiler.__enter__()
     metrics.event("run_start", run_id=run_id, kind=kind)
     return ctx
 
@@ -149,11 +152,8 @@ def start_run(kind: str, *, config: dict | None = None,
 def finish_run(ctx: RunContext, *, extra: dict | None = None) -> dict:
     """Write ``manifest.json``, ``events.jsonl``, and ``trace.json``."""
     if ctx._profiler is not None:
-        try:
-            ctx._profiler.__exit__(None, None, None)
-        except Exception:  # pragma: no cover
-            pass
-        ctx._profiler = None
+        profiler, ctx._profiler = ctx._profiler, None
+        profiler.__exit__(None, None, None)     # a failed stop raises
     wall = time.time() - ctx.t_start
     metrics.event("run_finish", run_id=ctx.run_id, wall_s=wall)
 

@@ -180,16 +180,22 @@ def record_collectives(hlo_text: str, **labels) -> dict:
 # ---------------------------------------------------------------------------
 # Roofline accounting (the paper's achieved-vs-peak framing).
 
-def roofline_fraction(achieved_flops_per_s: float,
-                      peak_flops_per_s: float | None = None) -> float:
-    """Achieved / peak FLOP fraction; peak defaults to the perfmodel's
-    wafer-scale peak so launch paths report the paper's metric unmodified."""
-    if peak_flops_per_s is None:
-        from repro.core import perfmodel
+NOT_MEASURED = "not measured"
 
-        peak_flops_per_s = perfmodel.PEAK_FLOPS
-    frac = achieved_flops_per_s / peak_flops_per_s
+
+def roofline_fraction(achieved_flops_per_s: float, *,
+                      device_kind: str) -> float | None:
+    """Achieved FLOP/s over ``device_kind``'s published peak FLOP/s
+    (``core/perfmodel.PEAKS``).  ``None`` — "not measured" — for a device
+    with no published peak, the CPU among them: its runs never report a
+    share of some other chip's peak."""
+    from repro.core import perfmodel
+
     gauge("roofline.achieved_flops_per_s").set(achieved_flops_per_s)
+    peak = perfmodel.PEAKS.get(device_kind)
+    if peak is None:
+        return None
+    frac = achieved_flops_per_s / peak.flops_per_s
     gauge("roofline.fraction").set(frac)
     return frac
 

@@ -19,6 +19,7 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     """Run a python snippet in a subprocess with N fake XLA host devices."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"    # a CPU fake fabric: never the chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],

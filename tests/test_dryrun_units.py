@@ -88,7 +88,7 @@ def test_lm_memory_estimate_orders_of_magnitude():
     from repro.launch.roofline_model import lm_cell_memory_estimate
     from repro.models.model import SHAPES
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_config("qwen2_1_5b")
     est = lm_cell_memory_estimate(cfg, SHAPES["smoke_decode"], mesh)

@@ -41,12 +41,12 @@ def test_stencil7_kernel_matches_core_apply():
 def test_stencil7_zc_chunking_equivalence():
     """Different VMEM chunkings must give identical results."""
     from repro.kernels.stencil7 import stencil7_pallas
-    shape = (4, 5, 32)
+    shape = (4, 16, 256)     # a split Z axis is cut at 128-lane multiples
     cf = stencil.random_nonsymmetric(jax.random.PRNGKey(4), shape)
     v = jax.random.normal(jax.random.PRNGKey(5), shape, jnp.float32)
     vp = jnp.pad(v, ((1, 1), (1, 1), (1, 1)))
     cl = [cf.diags[n] for n in ORDER]
-    outs = [stencil7_pallas(vp, cl, zc=zc) for zc in (32, 16, 8, 4)]
+    outs = [stencil7_pallas(vp, cl, zc=zc) for zc in (256, 128)]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o), rtol=0, atol=0)
 

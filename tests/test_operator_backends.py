@@ -70,7 +70,7 @@ def test_overlap_apply_bit_identical_and_same_ppermutes(subproc):
     — overlap changes *when* halos move, never how many messages."""
     subproc("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core import precision, stencil
         from repro.core.halo import FabricAxes, global_apply
         from repro.core.operator import make_operator

@@ -44,16 +44,17 @@ def test_kernel_matches_core_apply(specname):
 
 @pytest.mark.parametrize("specname", ["star13", "box27"])
 def test_zc_chunking_equivalence(specname):
-    """Different VMEM chunkings must give identical results (r-deep windows)."""
+    """Different VMEM chunkings must give identical results (r-deep windows).
+    A split Z axis is cut at multiples of the 128-lane tile."""
     from repro.kernels.stencil_nd.kernel import stencil_nd_pallas
     spec = stencil.get_spec(specname)
-    shape = (4, 5, 32)
+    shape = (4, 16, 256)
     cf = stencil.random_nonsymmetric(jax.random.PRNGKey(4), shape, spec=spec)
     v = jax.random.normal(jax.random.PRNGKey(5), shape, jnp.float32)
     vp = jnp.pad(v, spec.radius)
     cl = [cf.diags[n] for n in spec.names]
     outs = [stencil_nd_pallas(vp, cl, spec.offsets, radius=spec.radius, zc=zc)
-            for zc in (32, 16, 8, 4)]
+            for zc in (256, 128)]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    rtol=0, atol=0)
@@ -67,7 +68,7 @@ def test_stencil7_alias_is_generic_kernel():
     assert stencil7.__file__.endswith("stencil7.py")   # module, not package
     for name in ("stencil7_apply", "stencil7_ref", "stencil7_pallas",
                  "pallas_local_apply", "stencil7_dot", "stencil7_two_dots",
-                 "ORDER", "pick_zc", "VMEM_BUDGET_BYTES"):
+                 "ORDER", "default_tile", "VMEM_BUDGET_BYTES"):
         assert hasattr(stencil7, name), name          # legacy surface intact
     shape = (4, 4, 8)
     cf = stencil.random_nonsymmetric(jax.random.PRNGKey(6), shape)
@@ -81,12 +82,12 @@ def test_stencil7_alias_is_generic_kernel():
 
 
 def test_pick_zc_budget_scales_with_radius():
-    from repro.kernels.stencil_nd.ops import pick_zc
-    # same block: a deeper/wider stencil must not pick a LARGER chunk
-    zc1 = pick_zc(64, 64, 256, 4, radius=1, n_coeffs=6, budget=2 ** 22)
-    zc4 = pick_zc(64, 64, 256, 4, radius=4, n_coeffs=24, budget=2 ** 22)
-    assert zc4 <= zc1
-    assert 256 % zc4 == 0
+    from repro.kernels.stencil_nd.ops import default_tile
+    # same block: a deeper/wider stencil must not pick a LARGER tile
+    t1 = default_tile((64, 64, 256), 4, radius=1, n_coeffs=6, budget=2 ** 22)
+    t4 = default_tile((64, 64, 256), 4, radius=4, n_coeffs=24, budget=2 ** 22)
+    assert t4[0] * t4[1] * t4[2] <= t1[0] * t1[1] * t1[2]
+    assert t4[2] in (256, 128) and 64 % t4[0] == 0 and 64 % t4[1] == 0
 
 
 @pytest.mark.parametrize("specname", ["star13", "box27"])

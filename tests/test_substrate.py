@@ -114,7 +114,7 @@ def test_checkpoint_reshard_on_load(subproc):
         cm = CheckpointManager(d)
         cm.save(5, {"x": x})
         # restore onto a DIFFERENT layout: 4 of the 8 devices, model-only mesh
-        from repro.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh4 = make_mesh((4,), ("model",), devices=jax.devices()[:4])
         like = jax.ShapeDtypeStruct((8, 8), jnp.float32,
                                     sharding=NamedSharding(mesh4, P("model", None)))

@@ -1,0 +1,110 @@
+"""Ahead-of-time compiles of the main path's kernels for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: a described
+``v5e:2x2`` topology lets Mosaic accept or refuse each kernel at the local
+shapes of the paper's cells — what interpret mode cannot show (tile
+alignment, VMEM limits, unsupported lowerings).  Nothing runs; a compile
+that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every test worker imports
+this file.  Keep every such compile in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import stencil, tuning
+from repro.kernels import fused_iter
+from repro.kernels.stencil_nd import tile_apply
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A described-device compile cannot be read back without the chip, so
+    keep these compiles out of any persistent compilation cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()   # the kernel is there
+    return compiled
+
+
+# local blocks: joule_600 on one chip, cs1_paper (608x608x1536) per chip of
+# a 2x2 mesh, and a lane-aligned star25 block
+STENCIL_CELLS = [
+    ("star7", (608, 608, 608), jnp.bfloat16),
+    ("star7", (608, 608, 608), jnp.float32),
+    ("star7", (304, 304, 1536), jnp.bfloat16),
+    ("star7", (304, 304, 1536), jnp.float32),
+    ("star25", (256, 256, 512), jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("specname,shape,dtype", STENCIL_CELLS)
+def test_stencil_kernel_compiles_for_v5e(one_chip, specname, shape, dtype):
+    spec = stencil.get_spec(specname)
+    r = spec.radius
+    config = tuning.default_config(spec, dtype, shape)
+    assert config.valid_for(shape)
+    vp = jax.ShapeDtypeStruct(tuple(s + 2 * r for s in shape), dtype,
+                              sharding=one_chip)
+    cfs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)] * spec.n_offsets
+    compiled = _compile(
+        lambda v, *c: tile_apply(v, list(c), spec, config, interpret=False),
+        vp, *cfs)
+    # the arguments (window + diagonals) are the kernel's whole HBM footprint
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_overlap_ring_slabs_compile_for_v5e(one_chip):
+    """The split overlap epilogue's depth-1 ring slabs of a 2x2 cs1_paper
+    shard, (1, 304, 1536) and (304, 1, 1536), under their default tiles."""
+    spec = stencil.STAR7
+    for shape in ((1, 304, 1536), (304, 1, 1536)):
+        config = tuning.default_config(spec, jnp.bfloat16, shape)
+        vp = jax.ShapeDtypeStruct(tuple(s + 2 for s in shape), jnp.bfloat16,
+                                  sharding=one_chip)
+        cfs = [jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=one_chip)] * 6
+        _compile(lambda v, *c: tile_apply(v, list(c), spec, config,
+                                          interpret=False), vp, *cfs)
+
+
+@pytest.mark.parametrize("batch", [0, 8])
+@pytest.mark.parametrize("op", ["update_q_dots", "update_xr_dots",
+                                "update_p", "dot_mixed"])
+def test_fused_iter_kernel_compiles_for_v5e(one_chip, op, batch):
+    mesh = (608, 608, 608) if not batch else (64, 64, 608)
+    shape = ((batch,) if batch else ()) + mesh
+    vec = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    sca = jax.ShapeDtypeStruct((batch,) if batch else (), jnp.float32,
+                               sharding=one_chip)
+    n_scalars, n_vecs = {"update_q_dots": (1, 3), "update_xr_dots": (2, 5),
+                         "update_p": (2, 3), "dot_mixed": (0, 2)}[op]
+    fn = getattr(fused_iter, op)
+    _compile(lambda *a: fn(*a, interpret=False, batched=bool(batch)),
+             *([sca] * n_scalars + [vec] * n_vecs))

@@ -32,17 +32,21 @@ def _cell(specname, dtype, shape, seed=0):
 
 def test_cache_key_is_stable():
     # the literal format is the contract: cache files outlive code revisions
-    assert tuning.cache_key(stencil.STAR7, jnp.float32,
-                            (48, 48, 32)) == "star7/float32/48x48x32"
+    assert tuning.cache_key(stencil.STAR7, jnp.float32, (48, 48, 32),
+                            device_kind="cpu") == "cpu/star7/float32/48x48x32"
     assert tuning.cache_key(stencil.get_spec("box27"), jnp.bfloat16,
-                            (16, 8, 4)) == "box27/bfloat16/16x8x4"
+                            (16, 8, 4), device_kind="TPU v5 lite") \
+        == "tpu_v5_lite/box27/bfloat16/16x8x4"
+    # the device part defaults to the device this process computes on, so
+    # a CPU-interpret sweep never serves a chip (and vice versa)
+    assert tuning.cache_key(stencil.STAR7, jnp.float32, (8, 8, 8)) \
+        == f"{tuning.device_key(jax.devices()[0].device_kind)}/star7/float32/8x8x8"
 
 
 def test_cache_round_trip(tmp_path):
     path = str(tmp_path / "cache.json")
     cache = tuning.TuningCache(path)
-    cfg = tuning.KernelConfig(block=(8, 4), zc=16, resident=True,
-                              fuse_ring=True)
+    cfg = tuning.KernelConfig(block=(8, 4), zc=16, fuse_ring=True)
     cache.put("star7/float32/16x8x32", cfg, {"best_seconds": 1e-3})
     cache.save()
 
@@ -72,22 +76,31 @@ def test_lookup_defaults_without_cache():
 
 def test_lookup_hits_cache_and_rejects_stale():
     cache = tuning.TuningCache(None)
-    tuned = tuning.KernelConfig(block=(6, 5), zc=4, fuse_ring=True)
-    cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (12, 10, 8)),
+    tuned = tuning.KernelConfig(block=(6, 16), zc=128, fuse_ring=True)
+    cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (12, 32, 256)),
               tuned)
-    cfg, src = tuning.lookup_config(stencil.STAR7, jnp.float32, (12, 10, 8),
+    cfg, src = tuning.lookup_config(stencil.STAR7, jnp.float32, (12, 32, 256),
                                     cache=cache)
     assert (cfg, src) == (tuned, "cache")
 
     # same entry against a shape its tile no longer divides -> default + warn
-    cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (13, 10, 8)),
+    cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (13, 32, 256)),
               tuned)
     with pytest.warns(UserWarning, match="stale"):
         cfg, src = tuning.lookup_config(stencil.STAR7, jnp.float32,
-                                        (13, 10, 8), cache=cache)
+                                        (13, 32, 256), cache=cache)
     assert src == "stale"
     assert cfg == tuning.default_config(stencil.STAR7, jnp.float32,
-                                        (13, 10, 8))
+                                        (13, 32, 256))
+
+    # an entry whose tile divides but that Mosaic would refuse (a split Y
+    # axis cut off the 16-row tile) is stale too
+    cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (12, 10, 8)),
+              tuning.KernelConfig(block=(6, 5), zc=8))
+    with pytest.warns(UserWarning, match="stale"):
+        _, src = tuning.lookup_config(stencil.STAR7, jnp.float32,
+                                      (12, 10, 8), cache=cache)
+    assert src == "stale"
 
 
 def test_lookup_ignores_batch_dim():
@@ -95,7 +108,7 @@ def test_lookup_ignores_batch_dim():
     shape: only the trailing mesh dims key the lookup (the kernel's
     per-step working set is one RHS's tile either way)."""
     cache = tuning.TuningCache(None)
-    tuned = tuning.KernelConfig(block=(60, 35), zc=48, fuse_ring=True)
+    tuned = tuning.KernelConfig(block=(60, 595), zc=96, fuse_ring=True)
     cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (600, 595, 96)),
               tuned)
     for shape in ((600, 595, 96), (8, 600, 595, 96), (2, 8, 600, 595, 96)):
@@ -119,7 +132,7 @@ def test_env_var_disables_lookup(monkeypatch):
 def test_env_var_points_lookup_at_file(tmp_path, monkeypatch):
     path = str(tmp_path / "cache.json")
     cache = tuning.TuningCache(path)
-    tuned = tuning.KernelConfig(block=(4, 4), zc=8, fuse_ring=True)
+    tuned = tuning.KernelConfig(block=(4, 8), zc=8, fuse_ring=True)
     cache.put(tuning.cache_key(stencil.STAR7, jnp.float32, (8, 8, 8)), tuned)
     cache.save()
     monkeypatch.setenv("REPRO_TUNING_CACHE", path)
@@ -134,18 +147,28 @@ def test_env_var_points_lookup_at_file(tmp_path, monkeypatch):
 def test_nearest_divisor_paper_tiles():
     # the paper's unpadded 600 x 595 local tiles: a 64-ish request must
     # land on real divisors, not crash in pallas_call
-    assert tuning.nearest_divisor(600, 64) == 60
-    assert tuning.nearest_divisor(595, 64) == 35
-    assert tuning.nearest_divisor(7, 64) == 7
-    assert tuning.nearest_divisor(13, 4) == 1
+    from repro.kernels.stencil_nd.kernel import clamp_tile
+
+    # x takes any divisor; a split Y (Z) must be a multiple of 16 (128)
+    assert clamp_tile((64, 64, 64), (600, 595, 96)) == (60, 595, 96)
+    assert clamp_tile((64, 64, 4), (595, 7, 13)) == (35, 7, 13)
+    assert clamp_tile((4, 40, 300), (13, 64, 512)) == (1, 32, 256)
+    # a valid tile comes back unchanged
+    assert clamp_tile((4, 608, 608), (608, 608, 608)) == (4, 608, 608)
 
 
 def test_validate_config_clamps_and_warns():
     cfg = tuning.KernelConfig(block=(64, 64), zc=64)
     with pytest.warns(UserWarning, match="nearest valid tile"):
         fixed = tuning.validate_config(cfg, (600, 595, 96))
-    assert fixed.block == (60, 35) and fixed.zc == 48
-    assert fixed.divides((600, 595, 96))
+    # x takes any divisor; no multiple of 16 divides 595 and no multiple of
+    # 128 divides 96, so Y and Z stay whole (what Mosaic accepts)
+    assert fixed.block == (60, 595) and fixed.zc == 96
+    assert fixed.valid_for((600, 595, 96))
+    with pytest.warns(UserWarning, match="nearest valid tile"):
+        aligned = tuning.validate_config(cfg, (608, 608, 1536))
+    assert aligned.block == (38, 32) and aligned.zc == 1536
+    assert aligned.valid_for((608, 608, 1536))
     # an already-valid config passes through untouched, no warning
     with warnings.catch_warnings():
         warnings.simplefilter("error")
@@ -170,12 +193,13 @@ def test_kernel_clamps_bad_tile_at_trace_time():
 def test_xy_tiling_bitwise_equivalence(specname):
     """Any valid (bx, by, zc) tiling is bitwise identical to the full-block
     pass (per-element canonical-order accumulation is tile-independent)."""
-    spec, cl, v = _cell(specname, jnp.float32, (8, 12, 16))
+    spec, cl, v = _cell(specname, jnp.float32, (8, 32, 256))
     vp = jnp.pad(v, spec.radius)
     base = tile_apply(vp, cl, spec,
-                      tuning.KernelConfig(block=(8, 12), zc=16),
+                      tuning.KernelConfig(block=(8, 32), zc=256),
                       interpret=True)
-    for blk, zc in (((4, 12), 16), ((8, 6), 8), ((4, 4), 4), ((2, 3), 2)):
+    for blk, zc in (((4, 32), 256), ((8, 16), 128), ((2, 16), 128),
+                    ((1, 32), 128)):
         u = tile_apply(vp, cl, spec, tuning.KernelConfig(block=blk, zc=zc),
                        interpret=True)
         np.testing.assert_allclose(np.asarray(base), np.asarray(u),
@@ -282,7 +306,7 @@ def test_candidate_configs_default_first_and_valid():
     cands = tuning.candidate_configs(spec, jnp.float32, shape)
     assert cands[0] == tuning.default_config(spec, jnp.float32, shape)
     assert len(cands) == len(set(cands))      # deduplicated
-    assert all(c.divides(shape) for c in cands)
+    assert all(c.valid_for(shape) for c in cands)
     assert any(c.fuse_ring for c in cands)    # the epilogue axis is swept
 
 
