@@ -3,7 +3,7 @@
 
     python scripts/compare_runs.py <baseline_run_dir> <candidate_run_dir> \
         [--max-iter-increase-pct 0] [--max-collective-increase 0] \
-        [--min-solves-per-sec-ratio 0.8] [--min-roofline-ratio 0.5]
+        [--min-solves-per-sec-ratio 0.8]
 
 Each run dir is a ``repro.obs.v1`` bundle written by ``--obs`` launches
 (``results/runs/<run_id>/`` with ``manifest.json`` + ``events.jsonl``, see
@@ -17,10 +17,10 @@ solver stack:
   ``collectives`` events (the HLO-counted ground truth emitted at launch).
   Any growth beyond ``--max-collective-increase`` ops is a communication-
   schedule regression.  On by default (0 slack).
-* **solves/sec** and **roofline fraction** — throughput gauges.  Timing is
-  machine-dependent, so these checks are OFF by default (ratio 0); enable
-  with e.g. ``--min-solves-per-sec-ratio 0.8`` when comparing runs from
-  the same machine.
+* **solves/sec** — the throughput gauge.  Timing is machine-dependent, so
+  this check is OFF by default (ratio 0); enable it with e.g.
+  ``--min-solves-per-sec-ratio 0.8`` when comparing runs from the same
+  machine.
 
 Exits 0 when the candidate is no worse than the baseline under the active
 thresholds, 1 with a regression list otherwise, 2 on malformed bundles.
@@ -130,17 +130,13 @@ def compare(base_dir: str, cand_dir: str, args) -> int:
                       f"(--max-collective-increase {args.max_collective_increase})")
 
     # -- throughput (opt-in: machine-dependent) ------------------------
-    for name, ratio, flag in (
-            ("solve.solves_per_sec", args.min_solves_per_sec_ratio,
-             "--min-solves-per-sec-ratio"),
-            ("roofline.fraction", args.min_roofline_ratio,
-             "--min-roofline-ratio")):
-        b, c = gauge(base_man, name), gauge(cand_man, name)
-        if ratio <= 0 or b is None or c is None:
-            cmp.check(name, b, c, None)
-        else:
-            cmp.check(name, b, c, c >= b * ratio,
-                      f"(floor {b * ratio:.4g}, {flag} {ratio:g})")
+    name, ratio = "solve.solves_per_sec", args.min_solves_per_sec_ratio
+    b, c = gauge(base_man, name), gauge(cand_man, name)
+    if ratio <= 0 or b is None or c is None:
+        cmp.check(name, b, c, None)
+    else:
+        cmp.check(name, b, c, c >= b * ratio,
+                  f"(floor {b * ratio:.4g}, --min-solves-per-sec-ratio {ratio:g})")
 
     return cmp.report()
 
@@ -157,8 +153,6 @@ def main(argv=None) -> int:
                     help="allowed growth in AllReduce/ppermute totals (ops)")
     ap.add_argument("--min-solves-per-sec-ratio", type=float, default=0.0,
                     help="candidate/baseline throughput floor (0 = skip)")
-    ap.add_argument("--min-roofline-ratio", type=float, default=0.0,
-                    help="candidate/baseline roofline-fraction floor (0 = skip)")
     args = ap.parse_args(argv)
     return compare(args.baseline, args.candidate, args)
 

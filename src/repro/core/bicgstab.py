@@ -42,6 +42,7 @@ from repro.core.solvers.common import (  # noqa: F401
     safe_div as _safe_div,
 )
 from repro.core.stencil import StencilCoeffs, apply_ref
+from repro.obs import trace as obs_trace
 
 
 # ---------------------------------------------------------------------------
@@ -201,8 +202,8 @@ def solve_distributed(
             schedule=sched, fused_reductions=fused_reductions,
             interpret=interpret)
         if apply_impl is not None:
-            op = op.with_apply(lambda v: apply_impl(
-                op.coeffs, v, fabric, policy=policy, overlap=sched.overlap_halo))
+            op = op.with_apply(obs_trace.scoped("spmv")(lambda v: apply_impl(
+                op.coeffs, v, fabric, policy=policy, overlap=sched.overlap_halo)))
         M = build_precond(pconf, op)
         return solver_fn(op, b_local, x0_local, tol=tol, maxiter=maxiter,
                          policy=policy, record_history=record_history,
@@ -263,8 +264,8 @@ def make_iteration_fn(
             schedule=sched, fused_reductions=fused_reductions,
             interpret=interpret)
         if apply_impl is not None:
-            op = op.with_apply(lambda v: apply_impl(
-                op.coeffs, v, fabric, policy=policy, overlap=sched.overlap_halo))
+            op = op.with_apply(obs_trace.scoped("spmv")(lambda v: apply_impl(
+                op.coeffs, v, fabric, policy=policy, overlap=sched.overlap_halo)))
         axpy, axpy2 = _axpys(policy)
         if op.fused is not None:
             f = op.fused
@@ -272,7 +273,8 @@ def make_iteration_fn(
             s = op.apply(p)
             (r0s,) = op.reduce_partials([f.dot_partial(r0, s)])
             alpha, _ = safe_div(rho, r0s)
-            q_in = r - alpha.astype(st) * s
+            with obs_trace.scope("update"):
+                q_in = r - alpha.astype(st) * s
             y = op.apply(q_in)
             q, qy, yy = f.update_q_dots(alpha, r, s, y)
             qy, yy = op.reduce_partials([qy, yy])

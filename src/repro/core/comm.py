@@ -49,8 +49,6 @@ from repro.core.halo import (
 )
 from repro.core.precision import Policy, F32
 from repro.core.stencil import StencilCoeffs
-from repro.obs import metrics as obs_metrics
-from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,10 +118,7 @@ def start_halo_exchange(v: jax.Array, fabric: FabricAxes, radius: int, *,
     slab of every RHS at once (``(B, r, ...)``) — the message count per
     exchange is independent of the batch size.
     """
-    obs_metrics.counter("comm.halo_exchanges_traced").inc()
-    with obs_trace.span("comm.halo.issue", radius=radius, n_batch=n_batch):
-        padded = gather_halo(v, fabric, radius, corners=corners,
-                             n_batch=n_batch)
+    padded = gather_halo(v, fabric, radius, corners=corners, n_batch=n_batch)
     return HaloExchange(padded, radius, v.shape[n_batch:], n_batch)
 
 
@@ -204,31 +199,24 @@ def scheduled_apply(coeffs: StencilCoeffs, v: jax.Array, fabric: FabricAxes, *,
     nb = v.ndim - coeffs.ndim       # leading batch (many-RHS) axes
     sched = get_schedule(schedule)
 
-    # Spans here run at *trace* time (scheduled_apply executes under jit's
-    # tracer), so they time lowering work and record structure — they insert
-    # no ops, which keeps HLO bit-identical with obs on or off.
+    # the halo exchange names itself (``halo`` scope, in gather_halo); the
+    # rest is the SpMV's (``spmv``, opened by the operator around this)
     if not sched.overlap_halo:
-        with obs_trace.span("comm.halo.blocking", stencil=spec.name):
-            obs_metrics.counter("comm.halo_exchanges_traced").inc()
-            vp = gather_halo(v, fabric, r, corners=spec.needs_corners,
-                             n_batch=nb)
-            if full_fn is not None:
-                return full_fn(vp)
-            return padded_apply(coeffs, vp, v.shape,
-                                policy=policy).astype(policy.storage)
+        vp = gather_halo(v, fabric, r, corners=spec.needs_corners, n_batch=nb)
+        if full_fn is not None:
+            return full_fn(vp)
+        return padded_apply(coeffs, vp, v.shape,
+                            policy=policy).astype(policy.storage)
 
     exchange = start_halo_exchange(v, fabric, r, corners=spec.needs_corners,
                                    n_batch=nb)
     if fused_fn is not None:
-        with obs_trace.span("comm.halo.fused_epilogue", stencil=spec.name):
-            return fused_fn(exchange)
-    with obs_trace.span("comm.halo.interior", stencil=spec.name):
-        if interior_fn is None:
-            u = interior_apply(coeffs, v, policy=policy)
-        else:
-            u = interior_fn(v)
-    with obs_trace.span("comm.halo.ring", stencil=spec.name):
-        if patch_fn is not None:
-            return patch_fn(exchange, u)
-        u = boundary_ring_apply(coeffs, exchange, u, fabric, policy=policy)
-        return u.astype(policy.storage)
+        return fused_fn(exchange)
+    if interior_fn is None:
+        u = interior_apply(coeffs, v, policy=policy)
+    else:
+        u = interior_fn(v)
+    if patch_fn is not None:
+        return patch_fn(exchange, u)
+    u = boundary_ring_apply(coeffs, exchange, u, fabric, policy=policy)
+    return u.astype(policy.storage)

@@ -40,6 +40,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.precision import Policy, F32
 from repro.core.stencil import StencilCoeffs, _shift_nd, name_offset
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,6 +101,7 @@ def _take_slab(v: jax.Array, axis: int, sl: slice) -> jax.Array:
     return v[tuple(sl if i == axis else slice(None) for i in range(v.ndim))]
 
 
+@obs_trace.scoped("halo")
 def gather_halo(
     v: jax.Array,
     fabric: FabricAxes,

@@ -42,6 +42,7 @@ from repro.core.halo import FabricAxes
 from repro.core.precision import Policy, F32
 from repro.core.solvers.common import local_dots, local_partial
 from repro.core.stencil import StencilCoeffs, apply_ref
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -264,19 +265,33 @@ BACKENDS = {
 def make_operator(backend: str, coeffs: StencilCoeffs,
                   fabric: FabricAxes | None = None, *, policy: Policy = F32,
                   **kwargs) -> LinearOperator:
-    """Build a backend by name.  ``fabric`` is required semantics for the
+    """Build a backend by name, each of its layers in its scope
+    (:func:`named_layers`).  ``fabric`` is required semantics for the
     distributed backends (pass the shard_map-local view); the reference
     backend ignores it."""
     try:
         ctor = BACKENDS[backend]
     except KeyError:
         raise KeyError(f"unknown backend {backend!r}; have {sorted(BACKENDS)}") from None
-    from repro.obs import metrics as obs_metrics
-    from repro.obs import trace as obs_trace
+    if backend == "reference":
+        return named_layers(ctor(coeffs, policy=policy, **kwargs))
+    return named_layers(ctor(coeffs, fabric, policy=policy, **kwargs))
 
-    obs_metrics.counter(f"operator.build.{backend}").inc()
-    with obs_trace.span("operator.build", backend=backend,
-                        stencil=coeffs.spec.name, policy=policy.name):
-        if backend == "reference":
-            return ctor(coeffs, policy=policy, **kwargs)
-        return ctor(coeffs, fabric, policy=policy, **kwargs)
+
+def named_layers(op: LinearOperator) -> LinearOperator:
+    """``op`` with every layer it carries opened in its scope
+    (``obs.trace.SCOPES``), so the compiled solve names its work: the SpMV
+    (halo exchange, interior, ring, kernels) in ``spmv``, the inner
+    products and their AllReduce in ``dots``, the fused update kernels in
+    ``update`` (their dot partials ride in the update's pass, so their
+    bytes are the update's)."""
+    spmv, dots, update = (obs_trace.scoped(n) for n in ("spmv", "dots", "update"))
+    f = op.fused
+    if f is not None:
+        f = FusedOps(dot_partial=dots(f.dot_partial),
+                     update_q_dots=update(f.update_q_dots),
+                     update_xr_dots=update(f.update_xr_dots),
+                     update_p=update(f.update_p))
+    return dataclasses.replace(op, apply=spmv(op.apply), dots=dots(op.dots),
+                               reduce_partials=dots(op.reduce_partials),
+                               fused=f)

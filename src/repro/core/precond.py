@@ -36,6 +36,7 @@ import jax.numpy as jnp
 
 from repro.core.operator import LinearOperator
 from repro.core.solvers.common import SolveResult
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -217,7 +218,8 @@ def wrap_right(op: LinearOperator, precond):
     if precond is None or isinstance(precond, IdentityPrecond):
         return op, lambda res: res
 
-    wrapped = op.with_apply(lambda v: op.apply(precond.apply(v)))
+    apply_m = obs_trace.scoped("precond")(precond.apply)
+    wrapped = op.with_apply(lambda v: op.apply(apply_m(v)))
 
     def unwrap(res: SolveResult) -> SolveResult:
         return dataclasses.replace(res, x=precond.apply(res.x))

@@ -34,6 +34,7 @@ from repro.core.solvers.common import (
     SolveResult, axpy_family, bcast_scalar, convergence_test, finish,
     init_counters, run_krylov, safe_div,
 )
+from repro.obs import trace as obs_trace
 
 
 def bicgstab_loop(
@@ -145,7 +146,8 @@ def bicgstab_fused_loop(
         (r0s,) = op.reduce_partials([f.dot_partial(r0, s)])     # AllReduce 1
         alpha, bad1 = safe_div(rho, r0s)
         # SpMV input (kernel-identical); bcast aligns a per-RHS [B] alpha
-        q_in = r - bcast_scalar(alpha.astype(st), s) * s
+        with obs_trace.scope("update"):
+            q_in = r - bcast_scalar(alpha.astype(st), s) * s
         y = op.apply(q_in)
         q, qy, yy = f.update_q_dots(alpha, r, s, y)
         qy, yy = op.reduce_partials([qy, yy])                   # AllReduce 2

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.precision import Policy
+from repro.obs import trace as obs_trace
 
 
 @functools.partial(
@@ -93,13 +94,16 @@ def bcast_scalar(a, x):
 
 
 def axpy_family(policy: Policy):
-    """AXPY family in compute precision (paper Table I: 6 HP AXPYs/iter)."""
+    """AXPY family in compute precision (paper Table I: 6 HP AXPYs/iter),
+    in the ``update`` scope."""
     c = policy.compute
 
+    @obs_trace.scoped("update")
     def axpy(a, x, y):  # y + a*x
         ac = bcast_scalar(jnp.asarray(a).astype(c), x)
         return (y.astype(c) + ac * x.astype(c)).astype(policy.storage)
 
+    @obs_trace.scoped("update")
     def axpy2(a, x, b, y, z):  # z + a*x + b*y
         ac = bcast_scalar(jnp.asarray(a).astype(c), x)
         bc = bcast_scalar(jnp.asarray(b).astype(c), y)

@@ -60,10 +60,10 @@ def _acc_init(*refs):
             r[...] = jnp.zeros_like(r)
 
 
-def _call(kernel, B, M, bm, in_specs, out_specs, out_shape, interpret):
+def _call(kernel, name, B, M, bm, in_specs, out_specs, out_shape, interpret):
     return pl.pallas_call(kernel, grid=(B, M // bm), in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
-                          interpret=interpret)
+                          interpret=interpret, name=name)
 
 
 # --- q = r - alpha*s ; partials <q,y>, <y,y> ------------------------------
@@ -82,7 +82,7 @@ def update_q_dots_pallas(alpha, r, s, y, *, bm: int, interpret: bool = True):
     B, M, _ = r.shape
     row = _row_spec(bm)
     return _call(
-        _update_q_kernel, B, M, bm,
+        _update_q_kernel, "update_q_dots", B, M, bm,
         [_scalars_spec(), row, row, row],
         [row, _partial_spec(), _partial_spec()],
         [jax.ShapeDtypeStruct(r.shape, r.dtype), _partial_shape(B),
@@ -115,7 +115,7 @@ def update_xr_dots_pallas(alpha, omega, x, p, q, y, r0, *, bm: int,
                    axis=-1).astype(jnp.float32)              # (B, 2)
     row = _row_spec(bm)
     return _call(
-        _update_xr_kernel, B, M, bm,
+        _update_xr_kernel, "update_xr_dots", B, M, bm,
         [_scalars_spec()] + [row] * 5,
         [row, row, _partial_spec(), _partial_spec()],
         [jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -140,7 +140,7 @@ def update_p_pallas(beta, omega, r, p, s, *, bm: int, interpret: bool = True):
                    axis=-1).astype(jnp.float32)              # (B, 2)
     row = _row_spec(bm)
     return _call(
-        _update_p_kernel, B, M, bm,
+        _update_p_kernel, "update_p", B, M, bm,
         [_scalars_spec()] + [row] * 3, row,
         jax.ShapeDtypeStruct(r.shape, r.dtype),
         interpret,
@@ -159,5 +159,5 @@ def dot_mixed_pallas(a, b, *, bm: int, interpret: bool = True):
     """Per-RHS partials ``(B, 1, 1)`` of <a, b> for (B, M, 128) operands."""
     B, M, _ = a.shape
     row = _row_spec(bm)
-    return _call(_dot_kernel, B, M, bm, [row, row], _partial_spec(),
+    return _call(_dot_kernel, "dot_partial", B, M, bm, [row, row], _partial_spec(),
                  _partial_shape(B), interpret)(a, b)

@@ -52,7 +52,7 @@ def stencil7_pallas(v_padded: jax.Array, coeffs: list[jax.Array], *,
     in the order xp, xm, yp, ym, zp, zm (== STAR7.offsets order)."""
     return stencil_nd_pallas(v_padded, coeffs, STAR7.offsets, radius=1,
                              zc=zc, accum_dtype=accum_dtype,
-                             interpret=interpret)
+                             interpret=interpret, name="stencil_star7")
 
 
 def stencil7_ref(v: jax.Array, coeffs: list[jax.Array],

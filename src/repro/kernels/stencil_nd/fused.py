@@ -97,7 +97,7 @@ def _dot_kernel(vp_ref, w_ref, *refs, tile, rows, accum_dtype, two_dots):
 
 
 def _call(coeffs: StencilCoeffs, v: jax.Array, w: jax.Array, *, two_dots: bool,
-          accum_dtype=jnp.float32, interpret: bool | None = None):
+          name: str, accum_dtype=jnp.float32, interpret: bool | None = None):
     shape = v.shape
     # tile and row chunk as for the plain SpMV, with w as one more operand
     tile = default_tile(shape, jnp.dtype(v.dtype).itemsize, n_coeffs=7)
@@ -107,7 +107,7 @@ def _call(coeffs: StencilCoeffs, v: jax.Array, w: jax.Array, *, two_dots: bool,
         functools.partial(_dot_kernel, tile=tile, rows=rows,
                           accum_dtype=accum_dtype, two_dots=two_dots),
         jnp.pad(v, 1), [w] + [coeffs.diags[n] for n in ORDER],
-        radius=1, tile=tile,
+        name=name, radius=1, tile=tile,
         out_shape=[
             jax.ShapeDtypeStruct(shape, v.dtype),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
@@ -122,7 +122,8 @@ def _call(coeffs: StencilCoeffs, v: jax.Array, w: jax.Array, *, two_dots: bool,
 def stencil7_dot(coeffs: StencilCoeffs, p: jax.Array, r0: jax.Array, *,
                  interpret: bool | None = None):
     """s = A p, <r0, s> in one pass. Returns (s, r0s_partial)."""
-    s, d1, _ = _call(coeffs, p, r0, two_dots=False, interpret=interpret)
+    s, d1, _ = _call(coeffs, p, r0, two_dots=False, name="stencil7_dot",
+                     interpret=interpret)
     return s, d1
 
 
@@ -130,5 +131,6 @@ def stencil7_dot(coeffs: StencilCoeffs, p: jax.Array, r0: jax.Array, *,
 def stencil7_two_dots(coeffs: StencilCoeffs, q: jax.Array, *,
                       interpret: bool | None = None):
     """y = A q, <q, y>, <y, y> in one pass. Returns (y, qy, yy)."""
-    y, qy, yy = _call(coeffs, q, q, two_dots=True, interpret=interpret)
+    y, qy, yy = _call(coeffs, q, q, two_dots=True, name="stencil7_two_dots",
+                      interpret=interpret)
     return y, qy, yy

@@ -181,9 +181,9 @@ def _valid_tile(block: tuple[int, int] | None, zc: int | None,
 
 
 def window_call(kernel, v_padded: jax.Array, tiled: list[jax.Array], *,
-                radius: int, tile: tuple[int, int, int],
+                name: str, radius: int, tile: tuple[int, int, int],
                 out_shape, out_specs_for, interpret: bool):
-    """``pallas_call`` over the tile grid of an r-padded block.
+    """``pallas_call`` named ``name`` over the tile grid of an r-padded block.
 
     ``v_padded`` (optionally with a leading batch axis) gets the halo'd
     window spec; each of ``tiled`` (mesh-shaped, shared across the batch)
@@ -230,6 +230,7 @@ def window_call(kernel, v_padded: jax.Array, tiled: list[jax.Array], *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name=name,
     )(v_padded, *tiled)
 
 
@@ -238,8 +239,8 @@ def stencil_nd_pallas(v_padded: jax.Array, coeffs: list[jax.Array],
                       radius: int, zc: int | None = None,
                       block: tuple[int, int] | None = None,
                       accum_dtype=jnp.float32,
-                      interpret: bool = True):
-    """u = A v on one local block.
+                      interpret: bool = True, name: str = "stencil"):
+    """u = A v on one local block, a kernel named ``name``.
 
     ``v_padded``: (bx+2r, by+2r, Z+2r) iterate with halo (zero-padded for a
     standalone block, fabric-filled by ``core.halo.gather_halo`` inside the
@@ -261,7 +262,7 @@ def stencil_nd_pallas(v_padded: jax.Array, coeffs: list[jax.Array],
         _kernel, offsets=tuple(offsets), radius=r, tile=tile, rows=rows,
         accum_dtype=accum_dtype)
     return window_call(
-        kernel, v_padded, list(coeffs), radius=r, tile=tile,
+        kernel, v_padded, list(coeffs), name=name, radius=r, tile=tile,
         out_shape=jax.ShapeDtypeStruct(v_padded.shape[:nb] + shape,
                                        v_padded.dtype),
         out_specs_for=lambda nb, ospec: ospec, interpret=interpret)

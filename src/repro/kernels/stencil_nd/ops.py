@@ -68,7 +68,7 @@ def tile_apply(vp: jax.Array, cf_list: list[jax.Array], spec: StencilSpec,
     return stencil_nd_pallas(
         vp, cf_list, spec.offsets, radius=spec.radius, zc=config.zc,
         block=config.block, accum_dtype=accum_dtype,
-        interpret=resolve_interpret(interpret))
+        interpret=resolve_interpret(interpret), name=f"stencil_{spec.name}")
 
 
 def ring_patch_apply(exchange, cf_list: list[jax.Array], spec: StencilSpec,

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from repro.core import bicgstab, perfmodel, precision, stencil
+from repro.core import bicgstab, precision, stencil
 from repro.core.comm import SCHEDULES
 from repro.core.halo import FabricAxes, global_apply
 from repro.core.operator import BACKENDS
@@ -343,24 +343,6 @@ def _solve(args) -> dict:
         peak_bytes_per_device=peaks,
         x=res.x)      # the solution itself, still on the device(s)
 
-    # rates per chip from the warm wall time and the perf model's
-    # per-iteration flops and HBM words over the meshpoints each chip
-    # holds; the FLOP rate is quoted against one chip's published peak.
-    # The byte rate is a model estimate (no trace counts the bytes moved),
-    # so it gets no peak share.
-    pts = float(np.prod(shape)) * args.nrhs / len(devices)
-    flops = perfmodel.FLOPS_PER_PT * pts * n_iter / max(warm_s, 1e-12)
-    words = perfmodel.SOLVER_COMMS.get(args.solver)
-    model_gbs = (words.words_per_pt * pol.storage.itemsize * pts * n_iter
-                 / max(warm_s, 1e-12) / 1e9) if words else None
-    frac = obs_metrics.roofline_fraction(flops, device_kind=kind)
-    summary.update(roofline_fraction=frac, model_gb_per_s=model_gbs)
-    share = (obs_metrics.NOT_MEASURED + f" (no published peak for {kind})"
-             if frac is None else f"{frac:.3e} of {kind} peak FLOP/s")
-    print(f"per chip (warm wall time): {flops / 1e9:.2f} GFLOP/s by the "
-          f"model, {share}"
-          + ("" if model_gbs is None else
-             f"; model-estimated HBM rate {model_gbs:.2f} GB/s"))
     print(f"iterations: {iters.tolist() if iters.ndim else int(iters)}  "
           f"converged: {np.asarray(res.converged).tolist()}")
     print(f"recurrence rel-residual: {_fmt(rec_rel)}")

@@ -2,14 +2,16 @@
 
 Three small, dependency-light modules threaded through the solver stack:
 
-* :mod:`repro.obs.trace` — nestable context-manager spans with opt-in
-  ``block_until_ready`` device-sync timing and Chrome-trace-event
-  (Perfetto-loadable) export.  Spans live *outside* jit: enabling them
+* :mod:`repro.obs.trace` — the solve's layer scopes (``jax.named_scope``
+  names inside the compiled program) and nestable context-manager spans
+  with opt-in ``block_until_ready`` device-sync timing, Chrome-trace-event
+  (Perfetto-loadable) export, and a ``jax.profiler.TraceAnnotation`` each
+  on the profiler's clock.  Spans live *outside* jit: enabling them
   cannot change lowered HLO (asserted in tests/test_obs.py).
 * :mod:`repro.obs.metrics` — a process-local registry of counters,
   gauges, histograms and structured events: solver iterations, per-RHS
   convergence, AllReduce/ppermute counts, kernel launch counts,
-  tuning-cache hit/miss/stale, roofline fraction.
+  tuning-cache hit/miss/stale.
 * :mod:`repro.obs.manifest` — run bundles under
   ``results/runs/<run_id>/{manifest.json,events.jsonl,trace.json}``
   with a versioned ``repro.obs.v1`` schema (config cell, git SHA,

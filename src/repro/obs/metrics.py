@@ -4,8 +4,7 @@ One global :class:`Registry` collects everything a run emits — solver
 iterations and per-RHS convergence (from ``SolveResult``), residual
 histories, AllReduce/ppermute counts (the HLO-counting idiom the tests
 use, lifted here as :func:`count_collectives`), ``kernels/stencil_nd``
-launch counts, tuning-cache hit/miss/stale, and the achieved-vs-peak
-roofline fraction the paper reports (~1/3 of peak on the CS-1).
+launch counts and tuning-cache hit/miss/stale.
 
 The registry is always on (counter bumps are a dict lookup + integer
 add); *spans* are the opt-in part of observability.  Tests get a clean
@@ -175,29 +174,6 @@ def record_collectives(hlo_text: str, **labels) -> dict:
         counts["ppermute_total"])
     event("collectives", **labels, **counts)
     return counts
-
-
-# ---------------------------------------------------------------------------
-# Roofline accounting (the paper's achieved-vs-peak framing).
-
-NOT_MEASURED = "not measured"
-
-
-def roofline_fraction(achieved_flops_per_s: float, *,
-                      device_kind: str) -> float | None:
-    """Achieved FLOP/s over ``device_kind``'s published peak FLOP/s
-    (``core/perfmodel.PEAKS``).  ``None`` — "not measured" — for a device
-    with no published peak, the CPU among them: its runs never report a
-    share of some other chip's peak."""
-    from repro.core import perfmodel
-
-    gauge("roofline.achieved_flops_per_s").set(achieved_flops_per_s)
-    peak = perfmodel.PEAKS.get(device_kind)
-    if peak is None:
-        return None
-    frac = achieved_flops_per_s / peak.flops_per_s
-    gauge("roofline.fraction").set(frac)
-    return frac
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,19 @@
 """Nestable spans with Chrome-trace export; zero-cost no-ops when disabled.
 
-Spans are plain Python context managers and therefore live *outside* jit:
-inside traced code they time *tracing*, not device execution, and insert
-no jaxprs — which is exactly why enabling observability cannot change
-lowered HLO (tests assert bit-identical HLO text with obs on/off).
+Two instruments, one for each side of jit:
+
+* **Scopes** name the solve's layers *inside* the compiled program.
+  :data:`SCOPES` lists them; each is a ``jax.named_scope`` opened (via
+  :func:`scope`) at the one place where that work is composed, so every
+  HLO instruction it lowers to carries the name in its ``op_name``
+  metadata.  Scopes change metadata only, never the program.
+* **Spans** time host-side regions (compile, a whole solve, a CFD outer
+  step).  They are plain Python context managers and insert no jaxprs,
+  which is why enabling observability cannot change lowered HLO (tests
+  assert bit-identical HLO text with obs on/off).  While enabled, each
+  span also enters a ``jax.profiler.TraceAnnotation`` of its name, so a
+  profiler trace shows it on the host lines of the same ``.xplane.pb``
+  as the device's operations, on the profiler's clock.
 
 To time device work, opt into sync timing (``enable(sync=True)`` or a
 per-span ``sync=True``) and hand the span the values to wait on::
@@ -21,9 +31,18 @@ at https://ui.perfetto.dev.  ``profile(dir)`` wraps a region in
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import threading
 import time
+
+#: The solve's layers, each opened by :func:`scope` where its work is
+#: composed: ``spmv`` around every backend's ``LinearOperator.apply``,
+#: ``halo`` around ``core.halo.gather_halo`` (pads, slab slices,
+#: ppermutes; nested in ``spmv``), ``dots`` around the local partials and
+#: their AllReduce, ``update`` around the AXPY family and the fused update
+#: kernels, ``precond`` around a right preconditioner's apply.
+SCOPES = ("spmv", "halo", "dots", "update", "precond")
 
 _ENABLED = False
 _SYNC = False
@@ -66,10 +85,31 @@ def _stack() -> list:
     return st
 
 
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of :data:`SCOPES`: the operations
+    traced inside it carry ``name`` in their HLO ``op_name``."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; have {SCOPES}")
+    import jax
+
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator: the function runs inside :func:`scope` ``(name)``."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
 class Span:
     """A single recorded span.  Use via :func:`span`, not directly."""
 
-    __slots__ = ("name", "attrs", "t0", "depth", "parent")
+    __slots__ = ("name", "attrs", "t0", "depth", "parent", "annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
@@ -77,17 +117,23 @@ class Span:
         self.t0 = 0.0
         self.depth = 0
         self.parent = None
+        self.annotation = None
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation
+
         st = _stack()
         self.parent = st[-1].name if st else None
         self.depth = len(st)
         st.append(self)
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        self.annotation.__exit__(*exc)
         st = _stack()
         if st and st[-1] is self:
             st.pop()

@@ -8,9 +8,7 @@ import sys
 import jax
 import pytest
 
-from repro.core import perfmodel
 from repro.launch import solve as launch_solve
-from repro.obs import metrics as obs_metrics
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -38,7 +36,8 @@ def test_main_returns_summary_in_process(restore_compile_cache):
     assert abs(s["true_rel_residual"] - s["recurrence_rel_residual"]) < 1e-5
     assert s["compile_s"] > 0 and s["warm_s"] > 0
     assert s["x"].shape == (8, 8, 16)
-    assert s["roofline_fraction"] is None       # no published CPU peak
+    # no rate from the host's clock against a peak: the benchmark reads the trace
+    assert "roofline_fraction" not in s and "model_gb_per_s" not in s
 
 
 def test_cell_sets_mesh_and_policy():
@@ -80,16 +79,6 @@ def test_build_problem_sharded_matches_global(subproc):
                                                   np.asarray(s, np.float32))
         print("OK")
     """, n_devices=4)
-
-
-def test_roofline_fraction_unknown_device_is_not_measured():
-    assert obs_metrics.roofline_fraction(1e12, device_kind="cpu") is None
-    assert "roofline.fraction" not in obs_metrics.snapshot()["gauges"]
-    peak = perfmodel.PEAKS["TPU v5 lite"]
-    frac = obs_metrics.roofline_fraction(0.25 * peak.flops_per_s,
-                                         device_kind="TPU v5 lite")
-    assert frac == pytest.approx(0.25)          # the FLOP share alone
-    assert obs_metrics.roofline_fraction(1e12, device_kind="no such chip") is None
 
 
 def test_chip_smoke_refuses_the_cpu():

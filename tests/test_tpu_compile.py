@@ -11,14 +11,21 @@ only one process may load the TPU library, and every test worker imports
 this file.  Keep every such compile in this one file.
 """
 
+import math
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
-from repro.core import stencil, tuning
+from repro.core import bicgstab, precision, stencil, tuning
 from repro.kernels import fused_iter
 from repro.kernels.stencil_nd import tile_apply
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from bench import scopes  # noqa: E402
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +115,29 @@ def test_fused_iter_kernel_compiles_for_v5e(one_chip, op, batch):
     fn = getattr(fused_iter, op)
     _compile(lambda *a: fn(*a, interpret=False, batched=bool(batch)),
              *([sca] * n_scalars + [vec] * n_vecs))
+
+
+@pytest.mark.parametrize("backend", ["spmd", "pallas"])
+def test_solve_layers_named_for_v5e(one_chip, backend):
+    """The star7 solve on one chip, compiled by the TPU compiler: the layer
+    rules of ``bench/scopes.py`` claim every vector-sized instruction of
+    its loop in the TPU's own fusions (the ones a chip trace names), and
+    each Pallas kernel's custom call carries its ``name=``."""
+    shape = (128, 128, 608)
+    mesh = Mesh([[one_chip.device_set.pop()]], ("data", "model"))
+    arr = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    cf = stencil.StencilCoeffs({n: arr for n in stencil.STAR7.names})
+    pol = precision.get_policy("bf16_mixed")
+    hlo = jax.jit(lambda c, v: bicgstab.solve_distributed(
+        mesh, c, v, tol=1e-3, maxiter=50, policy=pol, backend=backend,
+        schedule="overlap", interpret=False)).lower(cf, arr).compile().as_text()
+    assert scopes.unscoped_vectors(hlo, math.prod(shape)) == []
+    layers = scopes.layer_map(hlo)
+    assert {"spmv", "update", "dots", "setup"} <= set(layers.values())
+    kernels = set(scopes.pallas_kernels(hlo))
+    assert kernels == ({"stencil_star7", "dot_partial", "update_q_dots",
+                        "update_xr_dots", "update_p"} if backend == "pallas" else set())
+    if backend == "pallas":
+        # the stencil kernels of the loop are the SpMV (the one of x0 is set-up)
+        stencils = {layers[k] for k in layers if k.startswith("stencil_star7")}
+        assert stencils == {"spmv", "setup"}
