@@ -8,8 +8,10 @@ the normal entry point (``repro.launch.solve.main``), in this one process.
 One chip: ``joule_600`` (608^3, star7 convection-diffusion, bf16_mixed,
 BiCGStab, overlap schedule) on the ``spmd`` and ``pallas`` backends; one
 Pallas stencil SpMV at 608^3 under the solve's own tiles, in bf16 and
-f32, against the plain jnp stencil on the chip; then both backends at
-32x32x128 f32 against ``solve_ref`` run on the host CPU.
+f32, against the plain jnp stencil on the chip; the spmd backend's
+``spmv_stream`` SpMV against the jnp apply it replaces at every element
+(star7 608^3, star25 504x504x352); then both
+backends at 32x32x128 f32 against ``solve_ref`` run on the host CPU.
 Four chips: ``cs1_paper`` (608x608x1536) on a 2x2 mesh with both
 backends, ``joule_370`` on the 2x2 mesh against one chip, and both
 backends at 64x64x256 f32 on the 2x2 mesh against ``solve_ref``.
@@ -226,6 +228,41 @@ def spmv_check(shape: tuple[int, int, int], dtype) -> None:
               f"{r:.3e} against the f32 stencil (<= 8 u = {8 * U_BF16:.3e})")
 
 
+def stream_check(specname: str, shape: tuple[int, int, int]) -> None:
+    """The spmd backend's SpMV kernel (``spmv_stream``) on the chip against
+    the jnp interior apply it replaces, both in bf16_mixed as the solve
+    runs them: equal at every element."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import precision, stencil
+    from repro.core.halo import interior_apply
+    from repro.kernels.stencil_nd.stream import stream_interior_apply
+
+    spec = stencil.get_spec(specname)
+    pol = precision.MIXED
+    f32 = jnp.float32
+
+    @jax.jit
+    def operands(key):
+        keys = jax.random.split(key, spec.n_offsets + 1)
+        cf = stencil.StencilCoeffs({
+            n: jax.random.uniform(k, shape, f32, -0.15, 0.15).astype(pol.storage)
+            for n, k in zip(spec.names, keys)})
+        return cf, jax.random.normal(keys[-1], shape, f32).astype(pol.storage)
+
+    cf, x = operands(jax.random.PRNGKey(5))
+    out = {"xla": jax.jit(lambda c, v: interior_apply(c, v, policy=pol))(cf, x),
+           "stream": jax.jit(lambda c, v: stream_interior_apply(
+               c, v, policy=pol))(cf, x)}
+    d = jnp.abs(out["stream"].astype(f32) - out["xla"].astype(f32))
+    n_diff = int(jnp.sum(out["stream"] != out["xla"]))
+    check(n_diff == 0,
+          f"{specname} {shape}: spmv_stream equals the jnp/XLA interior "
+          f"apply at every element ({n_diff} differ, max |diff| "
+          f"{float(jnp.max(d)):.3e})")
+
+
 def one_chip() -> None:
     import jax.numpy as jnp
 
@@ -244,6 +281,8 @@ def one_chip() -> None:
     check_agree(runs["spmd"], runs["pallas"], "joule_600 pallas vs spmd")
     for dtype in (jnp.bfloat16, jnp.float32):
         spmv_check((608, 608, 608), dtype)
+    stream_check("star7", (608, 608, 608))
+    stream_check("star25", (504, 504, 352))
     f32_oracle((32, 32, 128), 1)
 
 

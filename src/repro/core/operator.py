@@ -38,10 +38,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import CommSchedule, OVERLAP, get_schedule, scheduled_apply
-from repro.core.halo import FabricAxes
+from repro.core.halo import FabricAxes, interior_apply
 from repro.core.precision import Policy, F32
 from repro.core.solvers.common import local_dots, local_partial
-from repro.core.stencil import StencilCoeffs, apply_ref
+from repro.core.stencil import StencilCoeffs, StencilSpec, apply_ref
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 
@@ -159,6 +160,41 @@ def reference_operator(coeffs: StencilCoeffs, *, policy: Policy = F32,
     )
 
 
+#: storage dtypes the stream kernel takes (it accumulates in f32)
+STREAM_DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+
+
+def stream_applies(spec: StencilSpec, operand_ndim: int, dtype,
+                   platform: str) -> bool:
+    """Whether the spmd interior apply takes the plane-streaming Pallas
+    kernel (``kernels/stencil_nd/stream.py``): on a TPU, for a 3-D star
+    spec, on a bf16 or f32 operand without a leading batch axis.
+    Everything else — the CPU, box stencils (corner terms), batched
+    many-RHS operands, f64 — keeps the jnp shifted-window apply."""
+    return (platform == "tpu" and spec.pattern == "star" and spec.ndim == 3
+            and operand_ndim == spec.ndim
+            and jnp.dtype(dtype) in STREAM_DTYPES)
+
+
+def spmd_interior(coeffs: StencilCoeffs, policy: Policy):
+    """The spmd backend's ``interior_fn``: the stream kernel where
+    :func:`stream_applies`, else ``core.halo.interior_apply``; each traced
+    call counts under ``operator.spmv_interior.<path>``."""
+    def interior(v):
+        if stream_applies(coeffs.spec, v.ndim, v.dtype,
+                          jax.default_backend()):
+            # imported here: only a process that runs the kernel pays for
+            # importing Pallas
+            from repro.kernels.stencil_nd.stream import stream_interior_apply
+
+            obs_metrics.counter("operator.spmv_interior.stream").inc()
+            return stream_interior_apply(coeffs, v, policy=policy)
+        obs_metrics.counter("operator.spmv_interior.xla").inc()
+        return interior_apply(coeffs, v, policy=policy)
+
+    return interior
+
+
 def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
                   policy: Policy = F32, overlap: bool | None = None,
                   schedule=None, fused_reductions: bool = True,
@@ -167,16 +203,18 @@ def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
 
     ``schedule`` picks the halo schedule (``core.comm.SCHEDULES``); the
     legacy ``overlap`` boolean spells the same choice and loses ties.
+    The overlap schedule's interior is :func:`spmd_interior`.
     """
     fabric = fabric or FabricAxes()
     cf = coeffs.astype(policy.storage)
     sched = get_schedule(schedule if schedule is not None else overlap)
     dots, reduce_partials, reduce_max = _make_reductions(
         _fabric_axis_names(fabric), fused_reductions, mesh_ndim=cf.ndim)
+    interior = spmd_interior(cf, policy)
     return LinearOperator(
         name="spmd", coeffs=cf, policy=policy,
         apply=lambda v: scheduled_apply(cf, v, fabric, policy=policy,
-                                        schedule=sched),
+                                        schedule=sched, interior_fn=interior),
         dots=dots,
         reduce_partials=reduce_partials,
         reduce_max=reduce_max,

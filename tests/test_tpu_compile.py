@@ -23,6 +23,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from repro.core import bicgstab, precision, stencil, tuning
 from repro.kernels import fused_iter
 from repro.kernels.stencil_nd import tile_apply
+from repro.kernels.stencil_nd.stream import spmv_stream
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from bench import scopes  # noqa: E402
@@ -87,6 +88,25 @@ def test_stencil_kernel_compiles_for_v5e(one_chip, specname, shape, dtype):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
+# the benchmark cells' local blocks: star7 608^3 on one chip, star25
+# 504x504x352 on one chip, star7 304x304x1536 per chip of the 2x2 mesh
+STREAM_CELLS = [
+    ("star7", (608, 608, 608)),
+    ("star25", (504, 504, 352)),
+    ("star7", (304, 304, 1536)),
+]
+
+
+@pytest.mark.parametrize("specname,shape", STREAM_CELLS)
+def test_spmv_stream_compiles_for_v5e(one_chip, specname, shape):
+    spec = stencil.get_spec(specname)
+    arr = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    compiled = _compile(
+        lambda v, *c: spmv_stream(v, list(c), spec.offsets),
+        arr, *[arr] * spec.n_offsets)
+    assert scopes.pallas_kernels(compiled.as_text()) == ["spmv_stream"]
+
+
 def test_overlap_ring_slabs_compile_for_v5e(one_chip):
     """The split overlap epilogue's depth-1 ring slabs of a 2x2 cs1_paper
     shard, (1, 304, 1536) and (304, 1, 1536), under their default tiles."""
@@ -141,3 +161,25 @@ def test_solve_layers_named_for_v5e(one_chip, backend):
         # the stencil kernels of the loop are the SpMV (the one of x0 is set-up)
         stencils = {layers[k] for k in layers if k.startswith("stencil_star7")}
         assert stencils == {"spmv", "setup"}
+
+
+@pytest.mark.parametrize("specname", ["star7", "star25"])
+def test_spmd_solve_streams_spmv_for_v5e(one_chip, monkeypatch, specname):
+    """The spmd solve as a TPU process traces it (the platform read as
+    ``tpu``): its SpMVs are ``spmv_stream`` kernels, in the ``spmv`` layer
+    in the loop, and nothing else is a custom call."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = stencil.get_spec(specname)
+    shape = (64, 64, 608)
+    mesh = Mesh([[one_chip.device_set.pop()]], ("data", "model"))
+    arr = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    cf = stencil.StencilCoeffs({n: arr for n in spec.names})
+    pol = precision.get_policy("bf16_mixed")
+    hlo = jax.jit(lambda c, v: bicgstab.solve_distributed(
+        mesh, c, v, tol=1e-3, maxiter=50, policy=pol, backend="spmd",
+        schedule="overlap")).lower(cf, arr).compile().as_text()
+    assert set(scopes.pallas_kernels(hlo)) == {"spmv_stream"}
+    assert scopes.unscoped_vectors(hlo, math.prod(shape)) == []
+    layers = scopes.layer_map(hlo)
+    assert {layers[k] for k in layers if k.startswith("spmv_stream")} \
+        == {"spmv", "setup"}
