@@ -4,6 +4,7 @@ the normal entry point (``repro.launch.solve.main``), in this one process.
 
     python chip_smoke.py              # one chip
     python chip_smoke.py --chips 4    # the distributed solve on a 2x2 mesh
+    python chip_smoke.py --hpcg       # one chip: HPCG's MG-PCG at 384^3
 
 One chip: ``joule_600`` (608^3, star7 convection-diffusion, bf16_mixed,
 BiCGStab, overlap schedule) on the ``spmd`` and ``pallas`` backends; one
@@ -15,6 +16,10 @@ backends at 32x32x128 f32 against ``solve_ref`` run on the host CPU.
 Four chips: ``cs1_paper`` (608x608x1536) on a 2x2 mesh with both
 backends, ``joule_370`` on the 2x2 mesh against one chip, and both
 backends at 64x64x256 f32 on the 2x2 mesh against ``solve_ref``.
+HPCG: the 27-point CG solve with the multigrid V-cycle at 384^3 f32
+through the entry point; then the timed path's ``symgs`` sweeps and one
+V-cycle against ``bench/reference_mg.py`` at 384^3, in f32 (within
+``MG_RTOL``) and in bf16_mixed (outside it).
 
 Informative lines first; the last line is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -55,6 +60,12 @@ ITER_RTOL_F32 = 0.10
 # summation, u_f32 = 2^-24 each).
 F32_SLACK = 2.0 ** -19
 OUTPUT_ROUNDING = {"bfloat16": U_BF16, "float32": 0.0}
+# The V-cycle and its sweeps against the plain reference, max error over
+# the largest value: both sum each row's 26 terms in f32 in different
+# orders and Gauss-Seidel contracts errors, 2.4e-7 measured on the CPU
+# (tests/test_mg.py); 1e-5 leaves 40 times that, and bf16 storage misses
+# it by 2000 times.
+MG_RTOL = 1e-5
 
 
 class SmokeFailure(Exception):
@@ -263,6 +274,64 @@ def stream_check(specname: str, shape: tuple[int, int, int]) -> None:
           f"{float(jnp.max(d)):.3e})")
 
 
+def mg_check(shape: tuple[int, int, int]) -> None:
+    """The timed path's ``symgs`` sweeps and one V-cycle on HPCG's fields
+    and random vectors against ``bench/reference_mg.py``, in f32 and with
+    the program in bf16_mixed."""
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, HERE)
+    from bench import reference_mg
+    from repro.core import operator, precision, stencil
+    from repro.core.multigrid import BACKWARD, FORWARD, build_levels, colours, vcycle
+    from repro.kernels import resolve_interpret
+    from repro.kernels.stencil_nd.symgs import symgs_sweep
+
+    offsets = stencil.BOX27.offsets
+    fields = jax.jit(lambda: {o: jnp.full(shape, -1.0 / 26, jnp.float32)
+                              for o in offsets})()
+    cf = stencil.StencilCoeffs({stencil.offset_name(o): f for o, f in fields.items()})
+    r, x = (jax.random.normal(jax.random.PRNGKey(k), shape, jnp.float32) for k in (1, 2))
+
+    def rel(got, want):
+        return float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))
+                     / jnp.max(jnp.abs(want)))
+
+    for sweep, name in ((FORWARD, "forward"), (BACKWARD, "backward")):
+        got = jax.jit(lambda r, x, f: symgs_sweep(
+            r, x, [f[o] for o in offsets], offsets, first=sweep[0],
+            inplane=sweep[1], interpret=resolve_interpret()))(r, x, fields)
+        want = reference_mg.apply_sweep(fields, r, x, tuple(colours(sweep)))
+        err = rel(got, want)
+        del got, want
+        check(err <= MG_RTOL, f"{shape} symgs {name} sweep against the reference: "
+              f"max relative error {err:.3e} (<= {MG_RTOL:.0e})")
+    want = reference_mg.apply_vcycle(fields, r)
+    for pol in (precision.F32, precision.MIXED):
+        def cycle(c, v):
+            op = operator.make_operator("spmd", c.astype(pol.storage),
+                                        policy=pol, schedule="overlap")
+            return vcycle(build_levels(op), v.astype(pol.storage))
+        err = rel(jax.jit(cycle)(cf, r), want)
+        ok = err <= MG_RTOL if pol is precision.F32 else err > MG_RTOL
+        check(ok, f"{shape} V-cycle in {pol.name} against the f32 reference: max "
+              f"relative error {err:.3e} ({'<=' if pol is precision.F32 else '>'} "
+              f"{MG_RTOL:.0e})")
+
+
+def hpcg() -> None:
+    s = solve("--stencil", "box27", "--problem", "poisson", "--solver", "cg",
+              "--precond", "mg", "--mesh", "384", "384", "384", "--policy", "f32",
+              "--devices", "1", "--tol", "1e-6", "--maxiter", "500")
+    s.pop("x")
+    check_solve(s, 1)
+    check(bool(s["converged"]) and s["true_rel_residual"] < 1e-5,
+          f"HPCG 384^3: converged in {s['iterations']} iterations, true "
+          f"rel-residual {s['true_rel_residual']:.3e}")
+    mg_check((384, 384, 384))
+
+
 def one_chip() -> None:
     import jax.numpy as jnp
 
@@ -327,6 +396,8 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="1: the one-chip phases; 4: only the 2x2 phases")
+    ap.add_argument("--hpcg", action="store_true",
+                    help="only HPCG's MG-PCG phases, on one chip")
     args = ap.parse_args()
     try:
         import jax
@@ -342,7 +413,10 @@ def main() -> int:
         return 1
     print(f"compile cache: {enable_compile_cache()}", flush=True)
     try:
-        four_chips() if args.chips == 4 else one_chip()
+        if args.hpcg:
+            hpcg()
+        else:
+            four_chips() if args.chips == 4 else one_chip()
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

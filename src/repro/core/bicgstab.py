@@ -167,9 +167,11 @@ def solve_distributed(
     them under the interior apply, bit-identical to ``blocking``.  The
     legacy ``overlap_halo`` boolean spells the same choice and loses ties.
 
-    ``precond`` ("none" | "jacobi" | "chebyshev" | a PrecondConfig) applies
-    on the right, so the collective schedule is unchanged.  ``apply_impl``
-    is the legacy hook swapping the local SpMV for a custom kernel.
+    ``precond`` ("none" | "jacobi" | "chebyshev" | "mg" | a PrecondConfig)
+    applies on the right (CG: as the textbook PCG), local work only, so
+    the collective schedule is unchanged; "mg" runs on one device only.
+    ``apply_impl`` is the legacy hook swapping the local SpMV for a custom
+    kernel.
 
     Block (many-RHS) solves: pass ``b`` with a leading batch axis
     ``(B,) + coeffs.shape``.  The batch axis is replicated (each shard owns
@@ -194,6 +196,11 @@ def solve_distributed(
     cf_spec = fabric.spec(coeffs.ndim)
     cf = coeffs.astype(policy.storage)
     pconf = get_precond_config(precond)
+    if pconf.name == "mg" and mesh.devices.size > 1:
+        # HPCG's V-cycle sweeps one process's block; split over chips, each
+        # sweep would need its own halo exchange: a different smoother
+        raise ValueError("precond='mg' runs on one device only; "
+                         f"the mesh has {mesh.devices.size}")
     solver_fn = get_solver(solver)
 
     def solve_fn(cf_local, b_local, x0_local):

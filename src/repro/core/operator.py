@@ -168,10 +168,12 @@ def stream_applies(spec: StencilSpec, operand_ndim: int, dtype,
                    platform: str) -> bool:
     """Whether the spmd interior apply takes the plane-streaming Pallas
     kernel (``kernels/stencil_nd/stream.py``): on a TPU, for a 3-D star
-    spec, on a bf16 or f32 operand without a leading batch axis.
-    Everything else — the CPU, box stencils (corner terms), batched
-    many-RHS operands, f64 — keeps the jnp shifted-window apply."""
-    return (platform == "tpu" and spec.pattern == "star" and spec.ndim == 3
+    spec or the radius-1 box (27 points), on a bf16 or f32 operand
+    without a leading batch axis.  Everything else — the CPU, wider
+    boxes, batched many-RHS operands, f64 — keeps the jnp shifted-window
+    apply."""
+    return (platform == "tpu" and spec.ndim == 3
+            and (spec.pattern == "star" or spec.radius == 1)
             and operand_ndim == spec.ndim
             and jnp.dtype(dtype) in STREAM_DTYPES)
 

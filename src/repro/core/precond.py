@@ -1,9 +1,8 @@
 """Preconditioners (beyond-paper: the iteration-count lever the WSE
 follow-on work identifies — Woo et al., Jacquelin et al.).
 
-Two families, both *local* operations so the per-iteration collective
-schedule of the solve is unchanged (the whole point of right
-preconditioning on this fabric):
+Three families, all *local* operations so the per-iteration collective
+schedule of the solve is unchanged:
 
 * :class:`JacobiPrecond` — ``M^-1 = D^-1`` from the stencil's stored main
   diagonal.  The paper's operators are pre-normalized (unit diagonal — the
@@ -21,6 +20,13 @@ preconditioning on this fabric):
   Bounds default to fabric-reduced Gershgorin estimates with a relative
   floor on ``lmin``.
 
+* :class:`~repro.core.multigrid.MGPrecond` (``"mg"``) — HPCG's multigrid
+  V-cycle: 4 levels, one symmetric 8-colour Gauss–Seidel sweep before and
+  after each coarse correction, injection both ways (``core/multigrid``).
+  Symmetric positive definite but not a polynomial in ``A``: CG applies it
+  as the textbook PCG (``core/solvers/cg``), BiCGStab on the right.  One
+  device only.
+
 Preconditioners are built *inside* the shard_map body (they close over
 local coefficient shards and the operator's local apply); the static
 choices (name, degree, floor, explicit bounds) travel in a
@@ -34,6 +40,7 @@ from typing import Callable
 
 import jax.numpy as jnp
 
+from repro.core.multigrid import MGPrecond, build_levels
 from repro.core.operator import LinearOperator
 from repro.core.solvers.common import SolveResult
 from repro.obs import trace as obs_trace
@@ -140,7 +147,7 @@ class ChebyshevPrecond:
         return z.astype(st)
 
 
-PRECONDS = ("none", "jacobi", "chebyshev")
+PRECONDS = ("none", "jacobi", "chebyshev", "mg")
 
 
 # ---------------------------------------------------------------------------
@@ -172,6 +179,8 @@ def build_precond(config: PrecondConfig, op: LinearOperator):
             return IdentityPrecond()  # the family is already unit-diagonal
         return JacobiPrecond(inv_diag=1.0 / op.coeffs.diag.astype(jnp.float32),
                              storage=pol.storage, compute=pol.compute)
+    if config.name == "mg":
+        return MGPrecond(build_levels(op))
     # chebyshev
     if config.lmin is not None and config.lmax is not None:
         lmin = jnp.float32(config.lmin)

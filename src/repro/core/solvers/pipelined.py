@@ -222,12 +222,19 @@ def pipelined_cg_loop(
     return finish(final, bnorm2, history=_align_history(hist))
 
 
-def _right_preconditioned(loop):
+def _right_preconditioned(loop, *, refuses=()):
+    """``loop`` right-preconditioned; a preconditioner named in ``refuses``
+    is an error."""
     def solver(op, b, x0=None, *, tol: float = 1e-6, maxiter: int = 200,
                policy: Policy = F32, record_history: bool = False,
                precond=None) -> SolveResult:
         from repro.core.precond import warm_start, wrap_right
 
+        if getattr(precond, "name", None) in refuses:
+            raise ValueError(
+                f"{loop.__name__} preconditions on the right, which is CG only "
+                f"for an M^-1 that commutes with A; {precond.name!r} does not: "
+                f"use solver='cg', the textbook PCG")
         wrapped, unwrap = wrap_right(op, precond)
         res = loop(wrapped.apply, wrapped.dots, b, warm_start(precond, x0),
                    tol=tol, maxiter=maxiter, policy=policy,
@@ -241,5 +248,5 @@ def _right_preconditioned(loop):
 #: (1 AllReduce/iter) is untouched by any preconditioner.
 pipelined_bicgstab_solver = _right_preconditioned(pipelined_bicgstab_loop)
 pipelined_bicgstab_solver.__name__ = "pipelined_bicgstab_solver"
-pipelined_cg_solver = _right_preconditioned(pipelined_cg_loop)
+pipelined_cg_solver = _right_preconditioned(pipelined_cg_loop, refuses=("mg",))
 pipelined_cg_solver.__name__ = "pipelined_cg_solver"

@@ -3,7 +3,7 @@ from HBM once per apply.
 
 ``u = A v`` on an unpadded local block with zero-Dirichlet faces — what
 ``core.halo.interior_apply`` computes — for any star spec (offsets on the
-axes, any radius r).  The grid walks the x planes in order, r steps ahead
+axes, any radius r) and for the radius-1 box (27 points).  The grid walks the x planes in order, r steps ahead
 of the output: step ``t`` receives plane ``t`` of the iterate through a
 plane BlockSpec (the pipeline fetches plane ``t + 1`` meanwhile) and
 widens it to f32 into a VMEM ring of ``2r + 1`` slots, then computes
@@ -16,7 +16,8 @@ Each slot keeps :data:`ROW_BORDER` zero rows above and below the plane
 and zero lanes past ``Z`` (its lane extent is rounded up past ``Z + r``).
 A y term is a static row slice of an aligned row chunk of the centre
 slot, a z term a lane roll of that chunk: the zero rows and lanes are the
-faces, so no mask is needed.
+faces, so no mask is needed.  A box's corner term (more than one nonzero
+offset) is a row slice of the ``dx`` slot's aligned chunk, rolled in z.
 
 Terms accumulate in f32 in the canonical order
 (``StencilCoeffs.ordered_items``, the diagonal first) and round once to the
@@ -45,13 +46,28 @@ ROW_BORDER = 8
 CHUNK_VREGS = 32
 
 
-def chunk_rows(Y: int, Zp: int) -> int:
+def chunk_rows(Y: int, Zp: int, vregs: int = CHUNK_VREGS) -> int:
     """Rows per inner step: a multiple of 16 (a whole bf16 tile, so the
     ring and coefficient loads stay aligned) whose f32 chunk spans at most
-    :data:`CHUNK_VREGS` vregs; at least 16, at most ``Y``."""
+    ``vregs`` vregs; at least 16, at most ``Y``."""
     lane_vregs = Zp // LANES
-    rows = max(16, (CHUNK_VREGS // lane_vregs) * 8 // 16 * 16)
+    rows = max(16, (vregs // lane_vregs) * 8 // 16 * 16)
     return min(rows, Y)
+
+
+def row_chunks(Y: int, rows: int, body) -> None:
+    """``body(q, m)`` over the rows ``[0, Y)`` of a plane, ``m = rows`` at
+    a time (``q`` a multiple of ``rows``) in a loop, then the tail."""
+    n, tail = divmod(Y, rows)
+
+    def step(k, carry):
+        body(pl.multiple_of(k * rows, rows), rows)
+        return carry
+
+    if n:
+        jax.lax.fori_loop(0, n, step, 0)
+    if tail:
+        body(n * rows, tail)
 
 
 def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
@@ -67,16 +83,7 @@ def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
         return jax.lax.rem(j + slots, slots)      # j >= -r
 
     def chunks(body):
-        n, tail = divmod(Y, rows)
-
-        def step(k, carry):
-            body(pl.multiple_of(k * rows, rows), rows)
-            return carry
-
-        if n:
-            jax.lax.fori_loop(0, n, step, 0)
-        if tail:
-            body(n * rows, tail)
+        row_chunks(Y, rows, body)
 
     @pl.when(t == 0)
     def _zero_ring():                              # planes -r..-1, borders
@@ -104,9 +111,17 @@ def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
         def apply(q, m):
             win = ring[sc, pl.ds(q, m + 2 * B), :]  # rows q-B .. q+m+B
             mid = win[B:B + m]
+            planes = {0: win}                       # dx -> its row window
 
             def term(off):
                 dx, dy, dz = off
+                if sum(o != 0 for o in off) > 1:    # a corner of a box
+                    if dx not in planes:
+                        planes[dx] = ring[slot(i + dx), pl.ds(q, m + 2 * B), :]
+                    rows = planes[dx][B + dy:B + dy + m]
+                    if dz:
+                        rows = pltpu.roll(rows, (-dz) % rows.shape[1], 1)
+                    return rows[:, :Z]
                 if dx:
                     return ring[slot(i + dx), pl.ds(B + q, m), :][:, :Z]
                 if dy:
@@ -133,8 +148,8 @@ def spmv_stream(v: jax.Array, fields: list[jax.Array],
                 interpret: bool = False) -> jax.Array:
     """``u = A v`` on an unpadded ``(X, Y, Z)`` block, zero-Dirichlet faces.
 
-    ``fields[k]`` multiplies ``v`` shifted by ``offsets[k]`` (star offsets:
-    one nonzero axis each), in the order given; ``diag`` (None: unit)
+    ``fields[k]`` multiplies ``v`` shifted by ``offsets[k]`` (star offsets,
+    one nonzero axis each, or radius-1 box offsets), in the order given; ``diag`` (None: unit)
     multiplies the centre first.  One kernel named ``spmv_stream``.
     """
     obs_metrics.counter("kernels.stencil_stream.traced_calls").inc()
@@ -149,8 +164,9 @@ def spmv_stream(v: jax.Array, fields: list[jax.Array],
 def _spmv_stream(v, fields, offsets, *, diag, out_dtype, interpret):
     X, Y, Z = v.shape
     for off in offsets:
-        if sum(o != 0 for o in off) != 1:
-            raise ValueError(f"spmv_stream takes star offsets, got {off}")
+        if sum(o != 0 for o in off) > 1 and max(map(abs, off)) > 1:
+            raise ValueError(f"spmv_stream takes star offsets and box offsets "
+                             f"of radius 1, got {off}")
     r = max(max(abs(o) for o in off) for off in offsets)
     if r > ROW_BORDER:
         raise ValueError(f"radius {r} exceeds the scratch border {ROW_BORDER}")

@@ -7,6 +7,8 @@ preconditioner matrix.
     PYTHONPATH=src python -m repro.launch.solve --stencil star25 --mesh 24 24 16
     PYTHONPATH=src python -m repro.launch.solve --solver cg --problem poisson
     PYTHONPATH=src python -m repro.launch.solve --precond chebyshev --problem poisson
+    PYTHONPATH=src python -m repro.launch.solve --stencil box27 --problem poisson \
+        --solver cg --precond mg --mesh 32 32 32 --policy f32      # HPCG's solve
     PYTHONPATH=src python -m repro.launch.solve --solver pipelined_bicgstab --schedule overlap
     PYTHONPATH=src python -m repro.launch.solve --backend pallas --autotune --mesh 16 16 8
 
@@ -146,8 +148,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                          "ppermutes under the interior apply (bit-identical "
                          "to blocking)")
     ap.add_argument("--precond", default="none", choices=sorted(PRECONDS),
-                    help="right preconditioner (local — the collective "
-                         "schedule is unchanged)")
+                    help="preconditioner (local — the collective schedule "
+                         "is unchanged; cg applies it as the textbook PCG; "
+                         "mg is HPCG's V-cycle, one device only)")
     ap.add_argument("--cheb-degree", type=int, default=3,
                     help="Chebyshev polynomial degree (extra local SpMVs "
                          "per apply, no extra AllReduces)")

@@ -20,7 +20,7 @@ from repro.obs import metrics
 
 # ragged blocks: rows and lanes not multiples of the (16, 128) bf16 tile;
 # the combs put a unit point on every face
-SHAPES = {"star7": (10, 20, 36), "star25": (12, 24, 40)}
+SHAPES = {"star7": (10, 20, 36), "star25": (12, 24, 40), "box27": (9, 20, 36)}
 
 
 def _bf16_values(key, shape, dtype, scale=1.0):
@@ -58,7 +58,7 @@ def _operands(spec, shape, dtype, probe):
                                    "comb_high"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-@pytest.mark.parametrize("specname", ["star7", "star25"])
+@pytest.mark.parametrize("specname", ["star7", "star25", "box27"])
 def test_stream_equals_interior_apply(specname, dtype, probe):
     spec = stencil.get_spec(specname)
     shape = SHAPES[specname]
@@ -75,9 +75,56 @@ def test_stream_equals_interior_apply(specname, dtype, probe):
 
 
 def test_stream_refuses_offsets_off_the_axes():
+    """Off the axes it takes the radius-1 box's corners only."""
     v = jnp.zeros((4, 8, 8), jnp.float32)
-    with pytest.raises(ValueError, match="star offsets"):
-        spmv_stream(v, [v], ((1, 1, 0),), interpret=True)
+    with pytest.raises(ValueError, match="box offsets of radius 1"):
+        spmv_stream(v, [v], ((2, 1, 0),), interpret=True)
+
+
+def _kernel_jaxpr(specname, shape, dtype) -> str:
+    """The text of the jaxpr inside ``spmv_stream``'s pallas_call: what
+    Mosaic lowers."""
+    spec = stencil.get_spec(specname)
+    v = jax.ShapeDtypeStruct(shape, dtype)
+    closed = jax.make_jaxpr(lambda v, *c: spmv_stream(v, list(c), spec.offsets))(
+        v, *[v] * spec.n_offsets)
+
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for p in eqn.params.values():
+                sub = getattr(p, "jaxpr", None)
+                if sub is not None:
+                    found = find(getattr(sub, "jaxpr", sub))
+                    if found is not None:
+                        return found
+        return None
+
+    return str(find(closed.jaxpr).params["jaxpr"])
+
+
+# sha256 of _kernel_jaxpr before the stream kernel took box specs
+STAR_KERNELS = {
+    ("star7", (608, 608, 608), "bfloat16"):
+        "c815401089e2d19e263489e00fe9c39173ea1a391c064441b445de7b591f5e7e",
+    ("star7", (608, 608, 608), "float32"):
+        "c3f24b7314cce10ee7dc57bbd1613edbab321da4427154e9d1f84e93b2f0bb8e",
+    ("star25", (504, 504, 352), "bfloat16"):
+        "50238eb1db622da7716145a30947cb1f76253db6a0aa18c949a8f64d646e158e",
+    ("star25", (504, 504, 352), "float32"):
+        "f0bcf9d2775d6a972cced7f3bb6f5d3fb3b7818f3d750a57c9d08aaf571f1f97",
+}
+
+
+@pytest.mark.parametrize("specname,shape,dtype", sorted(STAR_KERNELS))
+def test_star_kernels_lower_as_before(specname, shape, dtype):
+    """The box's corner terms leave the star kernels' text as it was."""
+    import hashlib
+
+    text = _kernel_jaxpr(specname, shape, jnp.dtype(dtype))
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        STAR_KERNELS[(specname, shape, dtype)]
 
 
 @pytest.mark.parametrize("specname,operand_ndim,dtype,platform,takes", [
@@ -85,7 +132,8 @@ def test_stream_refuses_offsets_off_the_axes():
     ("star25", 3, jnp.bfloat16, "tpu", True),
     ("star13", 3, jnp.float32, "tpu", True),
     ("star7", 3, jnp.bfloat16, "cpu", False),
-    ("box27", 3, jnp.bfloat16, "tpu", False),
+    ("box27", 3, jnp.bfloat16, "tpu", True),
+    ("box27", 4, jnp.float32, "tpu", False),      # leading batch axis
     ("star7", 4, jnp.bfloat16, "tpu", False),     # leading batch axis
     ("star25", 4, jnp.float32, "tpu", False),
     ("star7", 3, jnp.float64, "tpu", False),
@@ -115,13 +163,15 @@ def test_spmd_operator_keeps_jnp_on_cpu():
 @pytest.mark.parametrize("specname,batch,path", [
     ("star7", (), "stream"),
     ("star25", (), "stream"),
-    ("box27", (), "xla"),
+    ("box27", (), "stream"),
+    ("box27", (2,), "xla"),
     ("star7", (2,), "xla"),
 ])
 def test_spmd_operator_selects_on_tpu(monkeypatch, specname, batch, path):
     """With the platform read as a TPU (and kernels interpreted, as there is
-    no chip here), star specs without a batch axis take the stream kernel
-    and the rest keep the jnp apply; both answer as the reference."""
+    no chip here), star and radius-1 box specs without a batch axis take
+    the stream kernel and the rest keep the jnp apply; both answer as the
+    reference."""
     from repro.kernels.stencil_nd import stream
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

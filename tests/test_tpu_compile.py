@@ -183,3 +183,44 @@ def test_spmd_solve_streams_spmv_for_v5e(one_chip, monkeypatch, specname):
     layers = scopes.layer_map(hlo)
     assert {layers[k] for k in layers if k.startswith("spmv_stream")} \
         == {"spmv", "setup"}
+
+
+# HPCG's 384^3 block and its coarse levels (core/multigrid, 4 levels)
+HPCG_LEVELS = [(384, 384, 384), (192, 192, 192), (96, 96, 96), (48, 48, 48)]
+
+
+@pytest.mark.parametrize("shape", HPCG_LEVELS, ids=str)
+def test_symgs_and_box_stream_compile_for_v5e(one_chip, shape):
+    from repro.core.multigrid import BACKWARD, FORWARD
+    from repro.kernels.stencil_nd.symgs import symgs_sweep
+
+    offsets = stencil.BOX27.offsets
+    arr = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    fields = [arr] * len(offsets)
+    for first, inplane in (FORWARD, BACKWARD):
+        compiled = _compile(lambda r, x, *c: symgs_sweep(
+            r, x, list(c), offsets, first=first, inplane=inplane), arr, arr, *fields)
+        assert scopes.pallas_kernels(compiled.as_text()) == ["symgs"]
+    _compile(lambda r, *c: symgs_sweep(r, None, list(c), offsets, first=1,
+                                       inplane=FORWARD[1]), arr, *fields)
+    compiled = _compile(lambda v, *c: spmv_stream(v, list(c), offsets), arr, *fields)
+    assert scopes.pallas_kernels(compiled.as_text()) == ["spmv_stream"]
+
+
+def test_hpcg_solve_compiles_for_v5e(one_chip, monkeypatch):
+    """The HPCG cell's MG-PCG solve at 384^3 as a TPU process traces it:
+    its kernels are ``symgs`` and ``spmv_stream``, every vector-sized loop
+    instruction has a layer, and it fits a v5e's 16 GB."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shape = HPCG_LEVELS[0]
+    mesh = Mesh([[one_chip.device_set.pop()]], ("data", "model"))
+    arr = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    cf = stencil.StencilCoeffs({n: arr for n in stencil.BOX27.names})
+    compiled = jax.jit(lambda c, v: bicgstab.solve_distributed(
+        mesh, c, v, tol=1e-6, maxiter=500, policy=precision.F32, solver="cg",
+        precond="mg", backend="spmd", schedule="overlap")).lower(cf, arr).compile()
+    hlo = compiled.as_text()
+    assert set(scopes.pallas_kernels(hlo)) == {"symgs", "spmv_stream"}
+    assert scopes.unscoped_vectors(hlo, math.prod(shape)) == []
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
