@@ -10,8 +10,9 @@ One chip: ``joule_600`` (608^3, star7 convection-diffusion, bf16_mixed,
 BiCGStab, overlap schedule) on the ``spmd`` and ``pallas`` backends; one
 Pallas stencil SpMV at 608^3 under the solve's own tiles, in bf16 and
 f32, against the plain jnp stencil on the chip; the spmd backend's
-``spmv_stream`` SpMV against the jnp apply it replaces at every element
-(star7 608^3, star25 504x504x352); then both
+``spmv_stream`` SpMV against the jnp apply it replaces at every element,
+and its dot partials against ``Policy.dot`` (star7 608^3, star25
+504x504x352); then both
 backends at 32x32x128 f32 against ``solve_ref`` run on the host CPU.
 Four chips: ``cs1_paper`` (608x608x1536) on a 2x2 mesh with both
 backends, ``joule_370`` on the 2x2 mesh against one chip, and both
@@ -19,7 +20,8 @@ backends at 64x64x256 f32 on the 2x2 mesh against ``solve_ref``.
 HPCG: the 27-point CG solve with the multigrid V-cycle at 384^3 f32
 through the entry point; then the timed path's ``symgs`` sweeps and one
 V-cycle against ``bench/reference_mg.py`` at 384^3, in f32 (within
-``MG_RTOL``) and in bf16_mixed (outside it).
+``MG_RTOL``) and in bf16_mixed (outside it); the box ``spmv_stream`` and
+its dot partials at 384^3 f32.
 
 Informative lines first; the last line is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -66,6 +68,9 @@ OUTPUT_ROUNDING = {"bfloat16": U_BF16, "float32": 0.0}
 # (tests/test_mg.py); 1e-5 leaves 40 times that, and bf16 storage misses
 # it by 2000 times.
 MG_RTOL = 1e-5
+# the stream kernel's dot partials against Policy.dot: the same f32
+# products, summed in another order
+DOT_RTOL = 1e-5
 
 
 class SmokeFailure(Exception):
@@ -239,28 +244,37 @@ def spmv_check(shape: tuple[int, int, int], dtype) -> None:
               f"{r:.3e} against the f32 stencil (<= 8 u = {8 * U_BF16:.3e})")
 
 
-def stream_check(specname: str, shape: tuple[int, int, int]) -> None:
+def stream_check(specname: str, shape: tuple[int, int, int],
+                 policy: str = "bf16_mixed") -> None:
     """The spmd backend's SpMV kernel (``spmv_stream``) on the chip against
-    the jnp interior apply it replaces, both in bf16_mixed as the solve
-    runs them: equal at every element."""
+    the jnp interior apply it replaces, both in the policy as the solve
+    runs them: equal at every element.  Its dot epilogue's partials
+    against ``Policy.dot`` of the stored output, within ``DOT_RTOL``."""
     import jax
     import jax.numpy as jnp
 
     from repro.core import precision, stencil
     from repro.core.halo import interior_apply
-    from repro.kernels.stencil_nd.stream import stream_interior_apply
+    from repro.kernels.stencil_nd.stream import (
+        stream_interior_apply, stream_interior_partials,
+    )
 
     spec = stencil.get_spec(specname)
-    pol = precision.MIXED
+    pol = precision.get_policy(policy)
     f32 = jnp.float32
 
     @jax.jit
     def operands(key):
         keys = jax.random.split(key, spec.n_offsets + 1)
+        # bf16 values, also in f32 storage: every product is exact, so the
+        # equality below cannot depend on a fused multiply-add
+        def values(x):
+            return x.astype(jnp.bfloat16).astype(pol.storage)
+
         cf = stencil.StencilCoeffs({
-            n: jax.random.uniform(k, shape, f32, -0.15, 0.15).astype(pol.storage)
+            n: values(jax.random.uniform(k, shape, f32, -0.15, 0.15))
             for n, k in zip(spec.names, keys)})
-        return cf, jax.random.normal(keys[-1], shape, f32).astype(pol.storage)
+        return cf, values(jax.random.normal(keys[-1], shape, f32))
 
     cf, x = operands(jax.random.PRNGKey(5))
     out = {"xla": jax.jit(lambda c, v: interior_apply(c, v, policy=pol))(cf, x),
@@ -269,9 +283,21 @@ def stream_check(specname: str, shape: tuple[int, int, int]) -> None:
     d = jnp.abs(out["stream"].astype(f32) - out["xla"].astype(f32))
     n_diff = int(jnp.sum(out["stream"] != out["xla"]))
     check(n_diff == 0,
-          f"{specname} {shape}: spmv_stream equals the jnp/XLA interior "
-          f"apply at every element ({n_diff} differ, max |diff| "
+          f"{specname} {shape} {policy}: spmv_stream equals the jnp/XLA "
+          f"interior apply at every element ({n_diff} differ, max |diff| "
           f"{float(jnp.max(d)):.3e})")
+    del d
+    w = jax.jit(lambda k: jax.random.normal(k, shape, f32).astype(pol.storage))(
+        jax.random.PRNGKey(6))
+    u, partials = jax.jit(lambda c, v, w: stream_interior_partials(
+        c, v, w, ("u", "v", "w"), policy=pol))(cf, x, w)
+    want = jax.jit(lambda u, v, w: [pol.dot(a, u) for a in (u, v, w)])(u, x, w)
+    for name, got, ref in zip(("<u, u>", "<v, u>", "<w, u>"), partials, want):
+        rel = abs(float(got) - float(ref)) / abs(float(ref))
+        check(rel <= DOT_RTOL,
+              f"{specname} {shape} {policy}: spmv_stream's {name} partial "
+              f"{float(got):.9e} against Policy.dot {float(ref):.9e}: "
+              f"relative {rel:.3e} (<= {DOT_RTOL:.0e})")
 
 
 def mg_check(shape: tuple[int, int, int]) -> None:
@@ -330,6 +356,7 @@ def hpcg() -> None:
           f"HPCG 384^3: converged in {s['iterations']} iterations, true "
           f"rel-residual {s['true_rel_residual']:.3e}")
     mg_check((384, 384, 384))
+    stream_check("box27", (384, 384, 384), "f32")
 
 
 def one_chip() -> None:

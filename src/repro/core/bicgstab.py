@@ -292,12 +292,10 @@ def make_iteration_fn(
             alpha_frac, _ = safe_div(alpha, omega)
             p = f.update_p(beta_frac * alpha_frac, omega, r_new, p, s)
             return x, r_new, p, rho_new, res2
-        s = op.apply(p)
-        (r0s,) = op.dots([(r0, s)], policy)
+        s, (r0s,) = op.apply_dots(p, r0, ("w",))
         alpha, _ = safe_div(rho, r0s)
         q = axpy(-alpha, s, r)
-        y = op.apply(q)
-        qy, yy = op.dots([(q, y), (y, y)], policy)
+        y, (qy, yy) = op.apply_dots(q, None, ("v", "u"))
         omega, _ = safe_div(qy, yy)
         x = axpy2(alpha, p, omega, q, x)
         r_new = axpy(-omega, y, q)

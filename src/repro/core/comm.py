@@ -48,6 +48,7 @@ from repro.core.halo import (
     FabricAxes, gather_halo, interior_apply, padded_apply,
 )
 from repro.core.precision import Policy, F32
+from repro.core.solvers.common import dot_operands
 from repro.core.stencil import StencilCoeffs
 
 
@@ -137,6 +138,22 @@ def boundary_regions(shape: tuple[int, ...], fabric: FabricAxes,
     return regions
 
 
+def ring_pieces(shape: tuple[int, ...], fabric: FabricAxes,
+                radius: int) -> list[tuple[slice, ...]]:
+    """The points of :func:`boundary_regions` as disjoint boxes: each split
+    axis's two slabs, cut to the inner range of the split axes before it."""
+    pieces, inner = [], [slice(None)] * len(shape)
+    for axis, name, n in fabric.split_info(len(shape)):
+        if name is None or n == 1:
+            continue
+        m = shape[axis]
+        for side in (slice(0, radius), slice(max(radius, m - radius), m)):
+            pieces.append(tuple(side if i == axis else inner[i]
+                                for i in range(len(shape))))
+        inner[axis] = slice(radius, m - radius)
+    return pieces
+
+
 def boundary_ring_apply(coeffs: StencilCoeffs, exchange: HaloExchange,
                         u: jax.Array, fabric: FabricAxes, *,
                         policy: Policy = F32) -> jax.Array:
@@ -220,3 +237,30 @@ def scheduled_apply(coeffs: StencilCoeffs, v: jax.Array, fabric: FabricAxes, *,
         return patch_fn(exchange, u)
     u = boundary_ring_apply(coeffs, exchange, u, fabric, policy=policy)
     return u.astype(policy.storage)
+
+
+def overlap_apply_partials(coeffs: StencilCoeffs, v: jax.Array, w, which,
+                           fabric: FabricAxes, *, policy: Policy,
+                           interior_fn) -> tuple[jax.Array, list]:
+    """The overlap schedule's ``u = A v`` with the f32 local partials
+    ``<x, u>`` for ``x`` the ``which`` of ``u``, ``v`` and ``w``
+    (:func:`~repro.core.solvers.common.dot_operands`).
+
+    ``interior_fn(v, ring) -> (u, partials)`` is the zero-Dirichlet apply
+    with partials over the points farther than ``ring[a]`` from either face
+    of each axis ``a`` (the radius on a split axis, else 0): the points the
+    boundary ring patch rewrites.  Their products are added from the
+    patched ``u``, one disjoint piece of the ring at a time.
+    """
+    spec = coeffs.spec
+    r = spec.radius
+    exchange = start_halo_exchange(v, fabric, r, corners=spec.needs_corners)
+    ring = tuple(r if name is not None and n > 1 else 0
+                 for _, name, n in fabric.split_info(v.ndim))
+    u, partials = interior_fn(v, ring)
+    u = boundary_ring_apply(coeffs, exchange, u, fabric, policy=policy)
+    u = u.astype(policy.storage)
+    for piece in ring_pieces(v.shape, fabric, r):
+        partials = [p + policy.dot(x[piece], u[piece])
+                    for p, x in zip(partials, dot_operands(which, u, v, w))]
+    return u, partials

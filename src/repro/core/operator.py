@@ -6,6 +6,10 @@ matrix side —
 * ``apply(v)``            : u = A v (the SpMV, local to this shard);
 * ``dots(pairs, policy)`` : fully-reduced inner products (the operator owns
   the reduction schedule: local stack / fused psum / separate psums);
+* ``apply_dots(v, w, which)`` : ``u = A v`` and the reduced ``<x, u>`` for
+  ``x`` the ``which`` of ``u``, ``v`` and ``w``; where the backend has an
+  SpMV that emits the local partials (``apply_partials``) they ride in its
+  pass, else ``apply``, then ``dots``;
 * ``reduce_partials(ps)`` : AllReduce of *precomputed* f32 local partials
   (the fused-kernel path computes partials inside Pallas epilogues and only
   needs the reduction);
@@ -37,10 +41,14 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core.comm import CommSchedule, OVERLAP, get_schedule, scheduled_apply
+from repro.core.comm import (
+    CommSchedule, OVERLAP, get_schedule, overlap_apply_partials, scheduled_apply,
+)
 from repro.core.halo import FabricAxes, interior_apply
 from repro.core.precision import Policy, F32
-from repro.core.solvers.common import local_dots, local_partial
+from repro.core.solvers.common import (
+    apply_then_dots, local_dots, local_partial,
+)
 from repro.core.stencil import StencilCoeffs, StencilSpec, apply_ref
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -81,14 +89,32 @@ class LinearOperator:
     reduce_max: Callable
     fused: FusedOps | None = None
     schedule: CommSchedule = OVERLAP
+    #: (v, w, which) -> (A v, f32 local partials) from one pass, or None
+    #: where it has none for these operands (:meth:`apply_dots`)
+    apply_partials: Callable | None = None
 
     @property
     def spec(self):
         return self.coeffs.spec
 
     def with_apply(self, apply: Callable) -> "LinearOperator":
-        """A copy with the SpMV swapped (how right preconditioning wraps)."""
-        return dataclasses.replace(self, apply=apply)
+        """A copy with the SpMV swapped (how right preconditioning wraps);
+        its dots are then the generic ones."""
+        return dataclasses.replace(self, apply=apply, apply_partials=None)
+
+    def apply_dots(self, v, w, which: tuple[str, ...]):
+        """``(u, dots)``: ``u = A v`` and ``<x, u>`` for ``x`` the ``which``
+        of ``u``, ``v`` and ``w`` (:func:`~repro.core.solvers.common
+        .dot_operands`), reduced in one sync point: from
+        ``apply_partials`` where it has a pass for these operands, else
+        ``apply`` and then ``dots``."""
+        got = None if self.apply_partials is None else self.apply_partials(
+            v, w, which)
+        if got is None:
+            return apply_then_dots(self.apply, self.dots, self.policy)(
+                v, w, which)
+        u, partials = got
+        return u, self.reduce_partials(partials)
 
 
 # ---------------------------------------------------------------------------
@@ -197,6 +223,43 @@ def spmd_interior(coeffs: StencilCoeffs, policy: Policy):
     return interior
 
 
+def stream_dots_apply(policy: Policy, *dtypes) -> bool:
+    """Whether the stream kernel's dot epilogue forms ``policy.dot``'s
+    products of operands of these dtypes: f32 reduction, and every operand
+    exact in the compute dtype (its own dtype, or f32 compute)."""
+    f32 = jnp.dtype(jnp.float32)
+    return (jnp.dtype(policy.reduce) == f32
+            and all(jnp.dtype(policy.compute) in (f32, jnp.dtype(d))
+                    for d in dtypes))
+
+
+def spmd_partials(coeffs: StencilCoeffs, fabric: FabricAxes, policy: Policy,
+                  sched: CommSchedule):
+    """The spmd backend's ``apply_partials``: where the overlap interior is
+    the stream kernel (:func:`stream_applies`) and its epilogue forms
+    :func:`stream_dots_apply`'s products, the partials ride in the kernel
+    and the boundary ring's are added from the patched output; else None
+    (``apply``, then the dots in passes of their own).  Each traced call
+    counts under ``operator.spmv_dots.<path>``."""
+    def apply_partials(v, w, which):
+        if (sched.overlap_halo
+                and stream_applies(coeffs.spec, v.ndim, v.dtype,
+                                   jax.default_backend())
+                and stream_dots_apply(policy, policy.storage, v.dtype,
+                                      *([] if w is None else [w.dtype]))):
+            from repro.kernels.stencil_nd.stream import stream_interior_partials
+
+            obs_metrics.counter("operator.spmv_dots.stream").inc()
+            return overlap_apply_partials(
+                coeffs, v, w, which, fabric, policy=policy,
+                interior_fn=lambda v, ring: stream_interior_partials(
+                    coeffs, v, w, which, policy=policy, ring=ring))
+        obs_metrics.counter("operator.spmv_dots.xla").inc()
+        return None
+
+    return apply_partials
+
+
 def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
                   policy: Policy = F32, overlap: bool | None = None,
                   schedule=None, fused_reductions: bool = True,
@@ -205,7 +268,8 @@ def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
 
     ``schedule`` picks the halo schedule (``core.comm.SCHEDULES``); the
     legacy ``overlap`` boolean spells the same choice and loses ties.
-    The overlap schedule's interior is :func:`spmd_interior`.
+    The overlap schedule's interior is :func:`spmd_interior`, and its
+    SpMV-with-dots :func:`spmd_partials`.
     """
     fabric = fabric or FabricAxes()
     cf = coeffs.astype(policy.storage)
@@ -221,6 +285,7 @@ def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
         reduce_partials=reduce_partials,
         reduce_max=reduce_max,
         schedule=sched,
+        apply_partials=spmd_partials(cf, fabric, policy, sched),
     )
 
 
@@ -324,7 +389,8 @@ def named_layers(op: LinearOperator) -> LinearOperator:
     (halo exchange, interior, ring, kernels) in ``spmv``, the inner
     products and their AllReduce in ``dots``, the fused update kernels in
     ``update`` (their dot partials ride in the update's pass, so their
-    bytes are the update's)."""
+    bytes are the update's).  An SpMV-with-dots is ``spmv``: the partials
+    its kernel emits are the SpMV's bytes."""
     spmv, dots, update = (obs_trace.scoped(n) for n in ("spmv", "dots", "update"))
     f = op.fused
     if f is not None:
@@ -332,6 +398,7 @@ def named_layers(op: LinearOperator) -> LinearOperator:
                      update_q_dots=update(f.update_q_dots),
                      update_xr_dots=update(f.update_xr_dots),
                      update_p=update(f.update_p))
+    ap = op.apply_partials
     return dataclasses.replace(op, apply=spmv(op.apply), dots=dots(op.dots),
                                reduce_partials=dots(op.reduce_partials),
-                               fused=f)
+                               fused=f, apply_partials=None if ap is None else spmv(ap))

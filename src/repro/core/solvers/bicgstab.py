@@ -18,6 +18,11 @@ Reduction schedule per iteration (paper counts 4 dot products):
     y = A q;                <q, y>, <y, y>               (sync point 2)
     r+ = q - w y;           <r0, r+>, <r+, r+>           (sync point 3)
 
+The dots of sync points 1 and 2 pair the SpMV's output with its input or
+with ``r0``: they come from the operator's SpMV-with-dots
+(``LinearOperator.apply_dots``), whose partials ride in the SpMV's own
+pass where the backend has such a kernel.
+
 With fused reductions each sync point is one AllReduce => 3/iter; the
 paper-faithful separate schedule is one blocking AllReduce per dot => 5/iter
 (incl. the convergence norm).
@@ -31,8 +36,8 @@ import jax.numpy as jnp
 
 from repro.core.precision import Policy, F32
 from repro.core.solvers.common import (
-    SolveResult, axpy_family, bcast_scalar, convergence_test, finish,
-    init_counters, run_krylov, safe_div,
+    SolveResult, apply_then_dots, axpy_family, bcast_scalar, convergence_test,
+    finish, init_counters, run_krylov, safe_div,
 )
 from repro.obs import trace as obs_trace
 
@@ -49,16 +54,20 @@ def bicgstab_loop(
     record_history: bool = False,
     axpy=None,
     axpy2=None,
+    apply_dots=None,
 ):
     """The generic algorithm body; composable inside jit/shard_map.
 
     ``apply_A`` and ``dots`` are bare callables (the pre-operator surface,
     kept because ``solve_refined`` and external callers compose it freely);
-    :func:`bicgstab_solver` adapts a LinearOperator onto it.
+    :func:`bicgstab_solver` adapts a LinearOperator onto it, passing its
+    ``apply_dots`` for the SpMVs of sync points 1 and 2 (default: the SpMV,
+    then ``dots``).
     """
     default_axpy, default_axpy2 = axpy_family(policy)
     axpy = axpy or default_axpy
     axpy2 = axpy2 or default_axpy2
+    apply_dots = apply_dots or apply_then_dots(apply_A, dots, policy)
 
     b = b.astype(policy.storage)
     if x0 is None:
@@ -73,12 +82,10 @@ def bicgstab_loop(
 
     def step(carry):
         i, x, r, p, rho, res2, conv, brk = carry
-        s = apply_A(p)
-        (r0s,) = dots([(r0, s)], policy)
+        s, (r0s,) = apply_dots(p, r0, ("w",))
         alpha, bad1 = safe_div(rho, r0s)
         q = axpy(-alpha, s, r)
-        y = apply_A(q)
-        qy, yy = dots([(q, y), (y, y)], policy)
+        y, (qy, yy) = apply_dots(q, None, ("v", "u"))
         omega, bad2 = safe_div(qy, yy)
         x = axpy2(alpha, p, omega, q, x)
         r_new = axpy(-omega, y, q)
@@ -198,5 +205,6 @@ def bicgstab_solver(
     else:
         res = bicgstab_loop(
             wrapped.apply, wrapped.dots, b, x0, tol=tol, maxiter=maxiter,
-            policy=policy, record_history=record_history)
+            policy=policy, record_history=record_history,
+            apply_dots=wrapped.apply_dots)
     return unwrap(res)

@@ -25,8 +25,8 @@ import jax.numpy as jnp
 
 from repro.core.precision import Policy, F32
 from repro.core.solvers.common import (
-    SolveResult, axpy_family, convergence_test, finish, init_counters,
-    run_krylov, safe_div,
+    SolveResult, apply_then_dots, axpy_family, convergence_test, finish,
+    init_counters, run_krylov, safe_div,
 )
 from repro.obs import trace as obs_trace
 
@@ -41,9 +41,14 @@ def cg_loop(
     maxiter: int = 200,
     policy: Policy = F32,
     record_history: bool = False,
+    apply_dots=None,
 ) -> SolveResult:
-    """The algorithm body; composable inside jit/shard_map. Returns SolveResult."""
+    """The algorithm body; composable inside jit/shard_map. Returns SolveResult.
+
+    ``apply_dots`` (``LinearOperator.apply_dots``; default: ``apply_A``,
+    then ``dots``) gives ``A p`` with ``<p, A p>``."""
     axpy, _ = axpy_family(policy)
+    apply_dots = apply_dots or apply_then_dots(apply_A, dots, policy)
     b = b.astype(policy.storage)
     if x0 is None:
         x = jnp.zeros_like(b)
@@ -56,8 +61,7 @@ def cg_loop(
 
     def step(carry):
         i, x, r, p, rho, conv, brk = carry
-        ap = apply_A(p)
-        (pap,) = dots([(p, ap)], policy)
+        ap, (pap,) = apply_dots(p, None, ("v",))
         alpha, bad1 = safe_div(rho, pap)
         x = axpy(alpha, p, x)
         r = axpy(-alpha, ap, r)
@@ -86,14 +90,17 @@ def pcg_loop(
     maxiter: int = 200,
     policy: Policy = F32,
     record_history: bool = False,
+    apply_dots=None,
 ) -> SolveResult:
     """The textbook preconditioned CG of HPCG's ``CG_ref``: ``z = M^-1 r``,
     ``rho = <r, z>``, ``p = z + beta p``, the iterate in real space.
 
     ``<r, r>`` for the convergence test rides in the same reduction as
     ``<r, z>``, so an iteration keeps CG's 2 sync points; the price is one
-    application of ``M^-1`` to the final residual."""
+    application of ``M^-1`` to the final residual.  ``<p, A p>`` comes
+    with ``A p`` from ``apply_dots``, as in :func:`cg_loop`."""
     axpy, _ = axpy_family(policy)
+    apply_dots = apply_dots or apply_then_dots(apply_A, dots, policy)
     b = b.astype(policy.storage)
     if x0 is None:
         x = jnp.zeros_like(b)
@@ -107,8 +114,7 @@ def pcg_loop(
 
     def step(carry):
         i, x, r, p, rz, rr, conv, brk = carry
-        ap = apply_A(p)
-        (pap,) = dots([(p, ap)], policy)
+        ap, (pap,) = apply_dots(p, None, ("v",))
         alpha, bad1 = safe_div(rz, pap)
         x = axpy(alpha, p, x)
         r = axpy(-alpha, ap, r)
@@ -149,7 +155,8 @@ def cg_solver(
 
     if precond is None or isinstance(precond, IdentityPrecond):
         return cg_loop(op.apply, op.dots, b, x0, tol=tol, maxiter=maxiter,
-                       policy=policy, record_history=record_history)
+                       policy=policy, record_history=record_history,
+                       apply_dots=op.apply_dots)
     return pcg_loop(op.apply, op.dots, obs_trace.scoped("precond")(precond.apply),
                     b, x0, tol=tol, maxiter=maxiter, policy=policy,
-                    record_history=record_history)
+                    record_history=record_history, apply_dots=op.apply_dots)

@@ -128,6 +128,23 @@ def local_partial(a, b, policy: Policy, *, mesh_ndim: int | None = None):
     return jnp.stack([policy.dot(a[i], b[i]) for i in range(a.shape[0])])
 
 
+def dot_operands(which, u, v, w):
+    """The left operands of the dots an SpMV-with-dots names: ``"u"`` the
+    output ``u = A v``, ``"v"`` its input, ``"w"`` one more vector."""
+    return [{"u": u, "v": v, "w": w}[k] for k in which]
+
+
+def apply_then_dots(apply_A, dots, policy: Policy):
+    """An SpMV-with-dots ``(v, w, which) -> (u, reduced dots)`` from a bare
+    ``apply_A`` and ``dots``: the SpMV, then one sync point of
+    ``<x, u>`` for each ``x`` of :func:`dot_operands`."""
+    def apply_dots(v, w, which):
+        u = apply_A(v)
+        return u, dots([(x, u) for x in dot_operands(which, u, v, w)], policy)
+
+    return apply_dots
+
+
 def local_dots(pairs, policy: Policy, *, mesh_ndim: int | None = None):
     """Single-address-space reduction: stack of FMAC-style inner products.
 

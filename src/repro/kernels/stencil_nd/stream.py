@@ -22,6 +22,14 @@ offset) is a row slice of the ``dx`` slot's aligned chunk, rolled in z.
 Terms accumulate in f32 in the canonical order
 (``StencilCoeffs.ordered_items``, the diagonal first) and round once to the
 output dtype: the arithmetic of ``interior_apply`` with f32 compute.
+
+The dot epilogue (``dots``) forms, beside ``u``, f32 partial inner
+products of the stored output with itself, with the input (its plane is
+in the ring) or with one more operand ``w`` (one more plane BlockSpec):
+the products of the widened stored values, summed over the rows of each
+output plane into an ``(X, len(dots), Z)`` block that the caller sums.
+An inner product then needs no pass of its own over a vector the SpMV
+already holds.
 """
 
 from __future__ import annotations
@@ -71,9 +79,14 @@ def row_chunks(Y: int, rows: int, body) -> None:
 
 
 def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
-            rows):
+            rows, dots=(), has_w=False, ring_rows=0):
     cf_refs = refs[:n_cf]
-    u_ref, ring = refs[n_cf:]
+    refs = refs[n_cf:]
+    w_ref = refs[0] if has_w else None
+    if dots:
+        u_ref, d_ref, ring = refs[has_w:]
+    else:
+        u_ref, ring = refs
     r, B = radius, ROW_BORDER
     X, Y, Z = shape
     t = pl.program_id(0)
@@ -107,6 +120,8 @@ def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
     @pl.when(t >= r)
     def _apply():
         sc = slot(i)
+        if dots:
+            d_ref[...] = jnp.zeros(d_ref.shape, f32)
 
         def apply(q, m):
             win = ring[sc, pl.ds(q, m + 2 * B), :]  # rows q-B .. q+m+B
@@ -138,6 +153,18 @@ def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
             for off, cf_ref in zip(offsets, fields):
                 u = u + cf_ref[pl.ds(q, m), :].astype(f32) * term(off)
             u_ref[pl.ds(q, m), :] = u.astype(u_ref.dtype)
+            if dots:
+                epilogue(q, m, u.astype(u_ref.dtype).astype(f32), mid[:, :Z])
+
+        def epilogue(q, m, us, vs):
+            if ring_rows:                       # rows a later patch rewrites
+                row = jax.lax.broadcasted_iota(jnp.int32, us.shape, 0) + q
+                us = jnp.where((row >= ring_rows) & (row < Y - ring_rows),
+                               us, 0.0)
+            for j, which in enumerate(dots):
+                x = {"u": lambda: us, "v": lambda: vs,
+                     "w": lambda: w_ref[pl.ds(q, m), :].astype(f32)}[which]()
+                d_ref[j:j + 1, :] += jnp.sum(x * us, axis=0, keepdims=True)
 
         chunks(apply)
 
@@ -145,23 +172,36 @@ def _kernel(v_ref, *refs, n_cf, has_diag, offsets, radius, shape, slots,
 def spmv_stream(v: jax.Array, fields: list[jax.Array],
                 offsets: tuple[tuple[int, int, int], ...], *,
                 diag: jax.Array | None = None, out_dtype=None,
-                interpret: bool = False) -> jax.Array:
+                interpret: bool = False, dots: tuple[str, ...] = (),
+                w: jax.Array | None = None, ring_rows: int = 0):
     """``u = A v`` on an unpadded ``(X, Y, Z)`` block, zero-Dirichlet faces.
 
     ``fields[k]`` multiplies ``v`` shifted by ``offsets[k]`` (star offsets,
     one nonzero axis each, or radius-1 box offsets), in the order given; ``diag`` (None: unit)
     multiplies the centre first.  One kernel named ``spmv_stream``.
+
+    With ``dots`` (each of ``"u"``, ``"v"``, ``"w"``) it returns ``(u,
+    partials)``: ``partials[x, j, z]`` is the f32 sum over the rows ``y``
+    of ``dots[j]``'s operand times the stored ``u``, both widened to f32,
+    at ``(x, y, z)``.  ``ring_rows`` leaves that many rows at either face
+    of ``y`` out of the sums.
     """
     obs_metrics.counter("kernels.stencil_stream.traced_calls").inc()
+    if ("w" in dots) != (w is not None):
+        raise ValueError("spmv_stream takes w exactly when dots names it")
     return _spmv_stream(v, list(fields), tuple(map(tuple, offsets)),
                         diag=diag, out_dtype=jnp.dtype(out_dtype or v.dtype),
-                        interpret=interpret)
+                        interpret=interpret, dots=tuple(dots), w=w,
+                        ring_rows=ring_rows)
 
 
 # jitted so that the SpMVs of one solve share one trace and one lowering
+# (one for each epilogue)
 @functools.partial(jax.jit, static_argnames=("offsets", "out_dtype",
-                                             "interpret"))
-def _spmv_stream(v, fields, offsets, *, diag, out_dtype, interpret):
+                                             "interpret", "dots",
+                                             "ring_rows"))
+def _spmv_stream(v, fields, offsets, *, diag, out_dtype, interpret, dots, w,
+                 ring_rows):
     X, Y, Z = v.shape
     for off in offsets:
         if sum(o != 0 for o in off) > 1 and max(map(abs, off)) > 1:
@@ -180,11 +220,21 @@ def _spmv_stream(v, fields, offsets, *, diag, out_dtype, interpret):
         _kernel, n_cf=len(cfs), has_diag=diag is not None,
         offsets=offsets, radius=r, shape=(X, Y, Z), slots=slots,
         rows=rows)
+    u_shape = jax.ShapeDtypeStruct((X, Y, Z), out_dtype)
+    out_specs, out_shape, ins = plane, u_shape, list(cfs)
+    if dots:
+        kernel = functools.partial(kernel, dots=dots, has_w=w is not None,
+                                   ring_rows=ring_rows)
+        ins += [w] if w is not None else []
+        out_specs = [plane, pl.BlockSpec(
+            (None, len(dots), Z), lambda t: (jnp.maximum(t - r, 0), 0, 0))]
+        out_shape = [u_shape,
+                     jax.ShapeDtypeStruct((X, len(dots), Z), jnp.float32)]
     return pl.pallas_call(
         kernel, grid=(X + r,),
-        in_specs=[lead] + [plane] * len(cfs),
-        out_specs=plane,
-        out_shape=jax.ShapeDtypeStruct((X, Y, Z), out_dtype),
+        in_specs=[lead] + [plane] * len(ins),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((slots, Y + 2 * ROW_BORDER, zp), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -192,14 +242,31 @@ def _spmv_stream(v, fields, offsets, *, diag, out_dtype, interpret):
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="spmv_stream",
-    )(v, *cfs)
+    )(v, *ins)
 
 
-def stream_interior_apply(coeffs, v: jax.Array, *, policy) -> jax.Array:
+def stream_interior_apply(coeffs, v: jax.Array, *, policy,
+                          **epilogue):
     """``core.halo.interior_apply`` through :func:`spmv_stream`: the
-    stored diagonals in canonical order, the result in storage dtype."""
+    stored diagonals in canonical order, the result in storage dtype.
+    ``epilogue``: :func:`spmv_stream`'s ``dots``, ``w`` and
+    ``ring_rows``."""
     items = coeffs.ordered_items()
     return spmv_stream(v, [cf for _, cf in items],
                        tuple(name_offset(n, coeffs.ndim) for n, _ in items),
                        diag=coeffs.diag, out_dtype=policy.storage,
-                       interpret=resolve_interpret(None))
+                       interpret=resolve_interpret(None), **epilogue)
+
+
+def stream_interior_partials(coeffs, v: jax.Array, w: jax.Array | None,
+                             which: tuple[str, ...], *, policy,
+                             ring: tuple[int, int, int] = (0, 0, 0)):
+    """:func:`stream_interior_apply` with the dot epilogue: ``(u,
+    partials)``, ``partials[j]`` the f32 local ``<x, u>`` for ``x`` the
+    ``which[j]`` of ``u``, ``v`` and ``w``, over the points farther than
+    ``ring[a]`` from either face of each axis ``a``."""
+    u, parts = stream_interior_apply(coeffs, v, policy=policy,
+                                     dots=tuple(which), w=w,
+                                     ring_rows=ring[1])
+    (X, _, Z), (rx, _, rz) = v.shape, ring
+    return u, list(jnp.sum(parts[rx:X - rx, :, rz:Z - rz], axis=(0, 2)))

@@ -8,6 +8,8 @@ compiler fuses a multiply and an add; the sums still run in the order each
 side chose.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -185,3 +187,207 @@ def test_spmd_operator_selects_on_tpu(monkeypatch, specname, batch, path):
     np.testing.assert_allclose(np.asarray(u),
                                np.asarray(stencil.apply_ref(coeffs, v)),
                                rtol=1e-6, atol=1e-6)
+
+
+def _reassociation_tol(x, u, n):
+    """How far two f32 sums of the ``n`` products ``x u`` may lie apart in
+    their order alone: a few ``sqrt(n)`` f32 epsilons of ``sum |x u|``
+    (``n`` epsilons at worst)."""
+    scale = float(jnp.sum(jnp.abs(x.astype(jnp.float32) * u.astype(jnp.float32))))
+    return 4 * math.sqrt(n) * float(jnp.finfo(jnp.float32).eps) * scale
+
+
+@pytest.mark.parametrize("ring", ["whole", "ring_left_out"])
+@pytest.mark.parametrize("policy", [precision.MIXED, precision.F32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("specname", ["star7", "star25", "box27"])
+def test_stream_dots_equal_policy_dot(specname, policy, ring):
+    """The dot epilogue's partials are ``Policy.dot`` of the stored output
+    with itself, with the input and with a third vector, over the points
+    it is asked for; its ``u`` is the plain kernel's, bit for bit."""
+    from repro.kernels.stencil_nd.stream import stream_interior_partials
+
+    spec = stencil.get_spec(specname)
+    shape = SHAPES[specname]
+    dtype = policy.storage
+    coeffs, v = _operands(spec, shape, dtype, "random_diag")
+    w = _bf16_values(jax.random.PRNGKey(11), shape, dtype)
+    depth = (spec.radius,) * 3 if ring == "ring_left_out" else (0, 0, 0)
+    u, partials = stream_interior_partials(coeffs, v, w, ("u", "v", "w"),
+                                           policy=policy, ring=depth)
+    np.testing.assert_array_equal(
+        np.asarray(u), np.asarray(stream_interior_apply(coeffs, v, policy=policy)))
+    inner = tuple(slice(d, n - d) for d, n in zip(depth, shape))
+    n = math.prod(s.stop - s.start for s in inner)
+    for x, got in zip((u, v, w), partials):
+        want = policy.dot(x[inner], u[inner])
+        assert abs(float(got) - float(want)) <= _reassociation_tol(
+            x[inner], u[inner], n), (float(got), float(want))
+
+
+def test_stream_dots_take_w_exactly_when_named():
+    v = jnp.zeros((4, 8, 8), jnp.float32)
+    with pytest.raises(ValueError, match="w exactly"):
+        spmv_stream(v, [v], ((1, 0, 0),), dots=("v",), w=v, interpret=True)
+    with pytest.raises(ValueError, match="w exactly"):
+        spmv_stream(v, [v], ((1, 0, 0),), dots=("w",), interpret=True)
+
+
+def _as_tpu(monkeypatch):
+    from repro.kernels.stencil_nd import stream
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(stream, "resolve_interpret", lambda i=None: True)
+
+
+@pytest.mark.parametrize("policy,path", [
+    (precision.MIXED, "stream"),
+    (precision.F32, "stream"),
+    (precision.BF16_PURE, "xla"),      # bf16 sums: not the kernel's f32 ones
+])
+def test_spmd_apply_dots_on_tpu(monkeypatch, policy, path):
+    """On a 1x1 fabric with the platform read as a TPU, the spmd
+    SpMV-with-dots takes the epilogue where its products are the policy's,
+    and gives ``apply`` and the separate dots either way."""
+    _as_tpu(monkeypatch)
+    spec = stencil.STAR7
+    shape = (6, 8, 12)
+    coeffs, v = _operands(spec, shape, policy.storage, "random")
+    w = _bf16_values(jax.random.PRNGKey(3), shape, policy.storage)
+    op = operator.make_operator("spmd", coeffs, FabricAxes(), policy=policy,
+                                schedule="overlap")
+    u, dots = op.apply_dots(v, w, ("w", "v", "u"))
+    counters = metrics.snapshot()["counters"]
+    assert counters[f"operator.spmv_dots.{path}"] == 1
+    assert len([k for k in counters if k.startswith("operator.spmv_dots.")]) == 1
+    want_u = op.apply(v)
+    np.testing.assert_array_equal(np.asarray(u), np.asarray(want_u))
+    want = op.dots([(w, want_u), (v, want_u), (want_u, want_u)], policy)
+    np.testing.assert_allclose(np.asarray(dots), np.asarray(want), rtol=2e-5)
+
+
+@pytest.mark.parametrize("backend", ["spmd", "reference"])
+def test_wrapped_operator_dots_fall_back(monkeypatch, backend):
+    """An operator whose SpMV was swapped (a right preconditioner's
+    ``A M^-1``) has no SpMV-with-dots of its own: ``apply_dots`` is its
+    ``apply`` and then the dots, counted under no stream path."""
+    _as_tpu(monkeypatch)
+    shape = (6, 8, 12)
+    coeffs, v = _operands(stencil.STAR7, shape, jnp.float32, "random")
+    op = operator.make_operator(backend, coeffs, FabricAxes(),
+                                policy=precision.F32, schedule="overlap")
+    wrapped = op.with_apply(lambda x: op.apply(2 * x))
+    assert wrapped.apply_partials is None
+    u, (vu, uu) = wrapped.apply_dots(v, None, ("v", "u"))
+    assert "operator.spmv_dots.stream" not in metrics.snapshot()["counters"]
+    np.testing.assert_array_equal(np.asarray(u), np.asarray(op.apply(2 * v)))
+    np.testing.assert_array_equal(
+        np.asarray([vu, uu]),
+        np.asarray(op.dots([(v, u), (u, u)], precision.F32)))
+
+
+def test_spmd_apply_dots_on_split_fabric(subproc):
+    """On a 2x2 fabric under the overlap schedule the kernel leaves the
+    boundary ring out and the operator adds it from the patched output:
+    the reduced dots are ``apply`` and the separate dots', for the star
+    radii 1 and 4 (a ring as deep as half the block) and the box's
+    corners, in bf16 and f32."""
+    subproc(f"""
+        import math
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core import operator, precision, stencil
+        from repro.core.halo import FabricAxes
+        from repro.kernels.stencil_nd import stream
+        from repro.launch.mesh import make_mesh_for_devices
+        from repro.obs import metrics
+
+        jax.default_backend = lambda: "tpu"
+        stream.resolve_interpret = lambda i=None: True
+        mesh = make_mesh_for_devices(4)
+        fabric = FabricAxes.from_mesh(mesh)
+        assert (fabric.nx, fabric.ny) == (2, 2)
+        spec_v = fabric.spec(3)
+        for specname, shape in (("star7", (12, 16, 36)), ("star25", (16, 16, 40)),
+                                ("box27", (12, 16, 36))):
+            spec = stencil.get_spec(specname)
+            for pol in (precision.MIXED, precision.F32):
+                keys = jax.random.split(jax.random.PRNGKey(5), 2)
+                cf = stencil.random_nonsymmetric(keys[0], shape, pol.storage,
+                                                 spec=spec)
+                v, w = (jax.random.normal(k, shape).astype(pol.storage)
+                        for k in jax.random.split(keys[1]))
+
+                def both(c, v, w):
+                    op = operator.make_operator("spmd", c, fabric, policy=pol,
+                                                schedule="overlap")
+                    u, got = op.apply_dots(v, w, ("w", "v", "u"))
+                    want_u = op.apply(v)
+                    want = op.dots([(w, want_u), (v, want_u),
+                                    (want_u, want_u)], pol)
+                    return u, want_u, got, want
+
+                u, want_u, got, want = jax.jit(jax.shard_map(
+                    both, mesh=mesh, in_specs=(spec_v, spec_v, spec_v),
+                    out_specs=(spec_v, spec_v, jax.sharding.PartitionSpec(),
+                               jax.sharding.PartitionSpec()),
+                    check_vma=False))(cf, v, w)
+                np.testing.assert_array_equal(np.asarray(u), np.asarray(want_u))
+                uf = np.asarray(u, np.float32)
+                for x, g, h in zip((w, v, u), np.asarray(got), np.asarray(want)):
+                    scale = np.abs(np.asarray(x, np.float32) * uf).sum()
+                    tol = 4 * math.sqrt(uf.size) * np.finfo(np.float32).eps * scale
+                    assert abs(g - h) <= tol, (specname, pol.name, g, h, tol)
+        assert metrics.snapshot()["counters"]["operator.spmv_dots.stream"] == 6
+        print("OK")
+    """, n_devices=4)
+
+
+def test_stream_dots_solve_matches_xla_dots(subproc):
+    """BiCGStab and CG on a 2x2 fabric, the platform read as a TPU: with the
+    sync points' dots in the stream kernel the solve follows the trajectory
+    of the same solve with the dots in passes of their own (the epilogue
+    refused), in iterations and final residual, and keeps 3 (BiCGStab) and
+    2 (CG) AllReduces an iteration."""
+    subproc(f"""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.core import bicgstab, operator, precision, stencil
+        from repro.kernels.stencil_nd import stream
+        from repro.launch.mesh import make_mesh_for_devices
+        from repro.obs import metrics
+
+        jax.default_backend = lambda: "tpu"
+        stream.resolve_interpret = lambda i=None: True
+        mesh = make_mesh_for_devices(4)
+        shape = (12, 16, 36)
+        x_true = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
+        systems = {{
+            "bicgstab": stencil.random_nonsymmetric(jax.random.PRNGKey(0), shape),
+            "cg": stencil.poisson(shape),
+        }}
+        exact = operator.stream_dots_apply
+        for solver, cf in systems.items():
+            b = stencil.rhs_for_solution(cf, x_true)
+            runs = {{}}
+            for path in ("stream", "xla"):
+                operator.stream_dots_apply = (exact if path == "stream"
+                                              else lambda *a: False)
+                metrics.reset()
+                f = jax.jit(lambda c, bb: bicgstab.solve_distributed(
+                    mesh, c, bb, tol=1e-5, maxiter=200, policy=precision.F32,
+                    solver=solver, schedule="overlap"))
+                text = f.lower(cf, b).as_text()
+                n_ar = text.count("all_reduce") + text.count("all-reduce")
+                assert n_ar == 1 + {{"bicgstab": 3, "cg": 2}}[solver], (solver, n_ar)
+                assert metrics.snapshot()["counters"][
+                    f"operator.spmv_dots.{{path}}"] >= 1, (solver, path)
+                runs[path] = f(cf, b)
+            s, x = runs["stream"], runs["xla"]
+            assert bool(s.converged) and bool(x.converged), solver
+            assert int(s.iterations) == int(x.iterations), (
+                solver, int(s.iterations), int(x.iterations))
+            np.testing.assert_allclose(float(s.rel_residual),
+                                       float(x.rel_residual), rtol=1e-2)
+            np.testing.assert_allclose(np.asarray(s.x), np.asarray(x.x),
+                                       rtol=1e-4, atol=1e-4)
+        print("OK")
+    """, n_devices=4)

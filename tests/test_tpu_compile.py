@@ -107,6 +107,32 @@ def test_spmv_stream_compiles_for_v5e(one_chip, specname, shape):
     assert scopes.pallas_kernels(compiled.as_text()) == ["spmv_stream"]
 
 
+# the cells' SpMVs-with-dots: BiCGStab's sync points 1 (<r0, s>) and 2
+# (<q, y>, <y, y>), CG's <p, Ap>; a 2x2 block leaves its ring rows out
+STREAM_DOTS_CELLS = [
+    ("star7", (608, 608, 608), jnp.bfloat16, ("w",), 0),
+    ("star7", (608, 608, 608), jnp.bfloat16, ("v", "u"), 0),
+    ("star25", (504, 504, 352), jnp.bfloat16, ("v", "u"), 0),
+    ("star7", (304, 304, 1536), jnp.bfloat16, ("w",), 1),
+    ("star7", (304, 304, 1536), jnp.bfloat16, ("v", "u"), 1),
+    ("box27", (384, 384, 384), jnp.float32, ("v",), 0),
+]
+
+
+@pytest.mark.parametrize("specname,shape,dtype,dots,ring_rows",
+                         STREAM_DOTS_CELLS)
+def test_spmv_stream_dots_compiles_for_v5e(one_chip, specname, shape, dtype,
+                                           dots, ring_rows):
+    spec = stencil.get_spec(specname)
+    arr = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    w = arr if "w" in dots else None
+    compiled = _compile(
+        lambda v, w, *c: spmv_stream(v, list(c), spec.offsets, dots=dots, w=w,
+                                     ring_rows=ring_rows),
+        arr, w, *[arr] * spec.n_offsets)
+    assert scopes.pallas_kernels(compiled.as_text()) == ["spmv_stream"]
+
+
 def test_overlap_ring_slabs_compile_for_v5e(one_chip):
     """The split overlap epilogue's depth-1 ring slabs of a 2x2 cs1_paper
     shard, (1, 304, 1536) and (304, 1, 1536), under their default tiles."""
