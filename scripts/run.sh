@@ -1,13 +1,14 @@
 #!/usr/bin/env bash
-# Known-good environment for repro runs and benchmarks, so timings are
-# comparable across machines and CI:
+# Known-good CPU environment for repro runs (the CI smoke solves), so runs
+# are reproducible across machines and CI:
 #
 #   scripts/run.sh -m repro.launch.solve --mesh 48 48 32
-#   scripts/run.sh -m benchmarks.kernel_autotune --smoke
-#   REPRO_DEVICES=512 scripts/run.sh -m benchmarks.hillclimb --cell stencil
+#   scripts/run.sh -m repro.launch.cfd --scenario cavity --n 24 --backend spmd
 #
-# Pins: tcmalloc (when installed) — thread-friendly malloc, matters for the
-# interpret-mode Pallas sweeps; quiet TF/XLA logging; a fixed fake-device
+# The chip benchmark is `python3 bench/run.py --workload <cell>` (PERF.md).
+#
+# Pins: tcmalloc (when installed) — thread-friendly malloc, matters for
+# interpret-mode Pallas; quiet TF/XLA logging; a fixed fake-device
 # count so shard_map fabrics are reproducible; PYTHONPATH=src.  Set
 # REPRO_X64=1 to enable float64 (the f64 policy path); REPRO_DEVICES to
 # change the host-platform device count (default 8: the 2x2x2 test fabric).

@@ -335,8 +335,7 @@ def measure_solve_share(cfg: CFDConfig, opts: SolverOptions, mesh, state, *,
     attributed to the solves.  The split lands in the observability
     registry (``cfd.solve_share`` / ``cfd.form_share`` gauges plus a
     ``cfd_solve_share`` event) so every run reports the paper's 50-70%
-    MFIX band the same way — ``benchmarks/cfd_step.py`` is a sweep over
-    this function, not a bespoke accounting of its own.
+    MFIX band the same way.
     """
     import time
 

@@ -69,19 +69,6 @@ SEISMIC_CELLS = {
 }
 
 
-#: Batched (many-RHS) workload cells, kept out of SEISMIC_CELLS: they are
-#: not star25 workloads (the ops-table assertions over SEISMIC_CELLS assume
-#: the seismic stencil) but the batched-solve benchmark's configuration
-#: surface.  ``batched_poisson`` is the cell ``benchmarks/batched_solve.py``
-#: sweeps over B.
-BATCHED_CELLS = {
-    "batched_poisson": StencilFamilyCell(
-        "batched_poisson", (24, 24, 16), "star7", policy="f32",
-        problem="poisson", solver="pipelined_bicgstab", schedule="overlap",
-        nrhs=8),
-}
-
-
 def ops_per_meshpoint_star25() -> dict:
     """Per-iteration per-meshpoint counts, Table-I style, for star25.
 

@@ -4,7 +4,7 @@ Layers: stencil operators (stencil.py), fabric halo exchange (halo.py),
 the pluggable operator backends (operator.py), the solver registry
 (solvers/), right preconditioning (precond.py), precision policies
 (precision.py), the drivers gluing them together (bicgstab.py), the
-analytic performance model (perfmodel.py) and the SIMPLE CFD driver
+device peak table (perfmodel.py) and the SIMPLE CFD re-export
 (simple_cfd.py).
 """
 

@@ -1,48 +1,36 @@
 """Solver drivers: wire mesh + operator backend + preconditioner + solver.
 
-This module is the glue layer (and the historical import surface — the
-algorithm bodies moved to ``core/solvers/``, the SpMV backends to
-``core/operator.py``, preconditioning to ``core/precond.py``):
+The algorithm bodies live in ``core/solvers/``, the SpMV backends in
+``core/operator.py``, preconditioning in ``core/precond.py``; this module
+wires them:
 
-* :func:`solve_ref`          — single-address-space solve (oracle);
+* :func:`solve_ref`          — single-device solve (oracle);
 * :func:`solve_distributed`  — the paper's run: the whole Krylov iteration
   inside one ``shard_map``, any registered solver x backend x precond;
-* :func:`make_iteration_fn`  — one SPMD iteration (the unit the paper
-  measures and the dry-run lowers);
-* :func:`solve_refined`      — bf16 inner solves + f32 iterative refinement;
-* :func:`solve_ref_fused`    — single-block BiCGStab through the fused
-  stencil7 dot-epilogue kernels (the per-chip reference schedule).
+* :func:`make_iteration_fn`  — one SPMD BiCGStab iteration (the unit the
+  paper measures and the dry run lowers): the solver's own step;
+* :func:`solve_refined`      — bf16 inner solves + f32 iterative refinement.
 
-Legacy names (``bicgstab_loop``, ``cg_loop``, ``SolveResult``, ...) are
-re-exported so existing callers and tests keep working.
+The two distributed drivers build their operator through one helper
+(:func:`_local_operator`).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.comm import SCHEDULES, get_schedule  # noqa: F401
+from repro.core.comm import get_schedule
 from repro.core.halo import FabricAxes
-from repro.core.operator import BACKENDS, make_operator  # noqa: F401
+from repro.core.operator import make_operator
 from repro.core.precision import Policy, F32, MIXED
 from repro.core.precond import PrecondConfig, build_precond, get_precond_config
-from repro.core.solvers import SOLVERS, get_solver  # noqa: F401
-from repro.core.solvers.bicgstab import bicgstab_fused_loop, bicgstab_loop  # noqa: F401
-from repro.core.solvers.cg import cg_loop  # noqa: F401
-from repro.core.solvers.common import (  # noqa: F401
-    EPS as _EPS,
-    SolveResult,
-    axpy_family as _axpys,
-    local_dots as _local_dots,
-    safe_div as _safe_div,
-)
+from repro.core.solvers import bicgstab as bicgstab_solvers, get_solver
+from repro.core.solvers.common import EPS, SolveResult
 from repro.core.stencil import StencilCoeffs, apply_ref
-from repro.obs import trace as obs_trace
 
 
 # ---------------------------------------------------------------------------
@@ -77,62 +65,35 @@ def solve_ref(
         record_history=record_history, precond=M)
 
 
-def cg_ref(coeffs: StencilCoeffs, b, **kw):
-    """CG oracle (kept for the historical call sites)."""
-    return solve_ref(coeffs, b, solver="cg",
-                     **{k: v for k, v in kw.items() if k != "x0"})
-
-
-def solve_ref_fused(
-    coeffs: StencilCoeffs,
-    b: jax.Array,
-    *,
-    tol: float = 1e-6,
-    maxiter: int = 200,
-    interpret: bool | None = None,
-):
-    """BiCGStab evaluated entirely through the fused Pallas schedule
-    (EXPERIMENTS §Perf stencil v3): SpMV+dot epilogues and fused
-    update+dot passes — 31 words/meshpoint/iteration instead of 42.
-
-    Single-block (per-chip) reference; the distributed solver composes the
-    same vector kernels via ``backend="pallas"``.  Python loop (not
-    lax.while) because pallas_call is re-traced per call in interpret mode.
-    """
-    from repro.kernels import resolve_interpret
-    from repro.kernels.fused_iter import update_p, update_xr_dots
-    from repro.kernels.stencil_nd.fused import stencil7_dot, stencil7_two_dots
-
-    interpret = resolve_interpret(interpret)
-    x = jnp.zeros_like(b)
-    r = b
-    p = b
-    r0 = b
-    bnorm2 = float(jnp.vdot(b.astype(jnp.float32), b.astype(jnp.float32)))
-    rho = jnp.float32(bnorm2)
-    n_iter = 0
-    rel = 1.0
-    for n_iter in range(1, maxiter + 1):
-        s, r0s = stencil7_dot(coeffs, p, r0, interpret=interpret)   # pass 1
-        alpha = rho / r0s
-        q = r - alpha.astype(r.dtype) * s                            # pass 2
-        y, qy, yy = stencil7_two_dots(coeffs, q, interpret=interpret)  # pass 3
-        omega = qy / yy
-        x, r, rho_new, rr = update_xr_dots(alpha, omega, x, p, q, y, r0,
-                                           interpret=interpret)      # pass 4
-        beta = (alpha / omega) * (rho_new / rho)
-        p = update_p(beta, omega, r, p, s, interpret=interpret)      # pass 5
-        rho = rho_new
-        rel = float(jnp.sqrt(rr / bnorm2))
-        if rel < tol:
-            break
-    return SolveResult(x, jnp.int32(n_iter), jnp.float32(rel),
-                       jnp.bool_(rel < tol), jnp.bool_(False))
-
-
 # ---------------------------------------------------------------------------
-# Distributed (shard_map) entry point — the paper's implementation
+# Distributed (shard_map) entry points — the paper's implementation
 # ---------------------------------------------------------------------------
+
+def _local_operator(mesh, *, backend: str, policy: Policy, schedule,
+                    fused_reductions: bool, interpret: bool | None):
+    """``(fabric, build)``: the mesh's fabric and ``build(cf_local)``, which
+    makes the backend's operator over a local coefficient shard inside
+    ``shard_map`` under the resolved halo schedule (a name or
+    :class:`~repro.core.comm.CommSchedule`; None is ``overlap``)."""
+    sched = get_schedule(schedule)
+    fabric = FabricAxes.from_mesh(mesh)
+    if backend == "reference" and mesh.devices.size > 1:
+        # the reference backend has no halo exchange and local-only dots:
+        # inside shard_map each shard would silently solve an unrelated
+        # zero-Dirichlet sub-problem
+        raise ValueError(
+            "backend='reference' is single-address-space only; use "
+            "backend='spmd' or 'pallas' on a multi-device mesh "
+            "(or solve_ref on the undistributed arrays)")
+
+    def build(cf_local):
+        return make_operator(
+            backend, cf_local, fabric, policy=policy,
+            schedule=sched, fused_reductions=fused_reductions,
+            interpret=interpret)
+
+    return fabric, build
+
 
 def solve_distributed(
     mesh,
@@ -144,14 +105,12 @@ def solve_distributed(
     maxiter: int = 200,
     policy: Policy = MIXED,
     fused_reductions: bool = True,
-    overlap_halo: bool | None = None,
-    schedule: str | None = None,
+    schedule=None,
     record_history: bool = False,
     solver: str = "bicgstab",
     backend: str = "spmd",
     precond: str | PrecondConfig | None = None,
     interpret: bool | None = None,
-    apply_impl: Callable | None = None,
 ) -> SolveResult:
     """A Krylov solve with the entire iteration inside one ``shard_map``.
 
@@ -162,16 +121,14 @@ def solve_distributed(
     With ``backend="pallas"`` the local work additionally runs as the fused
     stencil + vector-update Pallas kernels.
 
-    ``schedule`` ("blocking" | "overlap", ``core.comm.SCHEDULES``) picks
-    the halo schedule — ``overlap`` issues the ppermutes first and hides
-    them under the interior apply, bit-identical to ``blocking``.  The
-    legacy ``overlap_halo`` boolean spells the same choice and loses ties.
+    ``schedule`` ("blocking" | "overlap" or a ``CommSchedule``,
+    ``core.comm.SCHEDULES``; default overlap) picks the halo schedule —
+    ``overlap`` issues the ppermutes first and hides them under the
+    interior apply, bit-identical to ``blocking``.
 
     ``precond`` ("none" | "jacobi" | "chebyshev" | "mg" | a PrecondConfig)
     applies on the right (CG: as the textbook PCG), local work only, so
     the collective schedule is unchanged; "mg" runs on one device only.
-    ``apply_impl`` is the legacy hook swapping the local SpMV for a custom
-    kernel.
 
     Block (many-RHS) solves: pass ``b`` with a leading batch axis
     ``(B,) + coeffs.shape``.  The batch axis is replicated (each shard owns
@@ -181,16 +138,9 @@ def solve_distributed(
     iteration counts / flags / residuals.  The collective count per
     iteration is independent of B.
     """
-    sched = get_schedule(schedule if schedule is not None else overlap_halo)
-    fabric = FabricAxes.from_mesh(mesh)
-    if backend == "reference" and mesh.devices.size > 1:
-        # the reference backend has no halo exchange and local-only dots:
-        # inside shard_map each shard would silently solve an unrelated
-        # zero-Dirichlet sub-problem
-        raise ValueError(
-            "backend='reference' is single-address-space only; use "
-            "backend='spmd' or 'pallas' on a multi-device mesh "
-            "(or solve_ref on the undistributed arrays)")
+    fabric, build = _local_operator(
+        mesh, backend=backend, policy=policy, schedule=schedule,
+        fused_reductions=fused_reductions, interpret=interpret)
     nb = b.ndim - coeffs.ndim       # leading batch (many-RHS) axes
     spec = fabric.spec(coeffs.ndim, n_batch=nb)
     cf_spec = fabric.spec(coeffs.ndim)
@@ -204,13 +154,7 @@ def solve_distributed(
     solver_fn = get_solver(solver)
 
     def solve_fn(cf_local, b_local, x0_local):
-        op = make_operator(
-            backend, cf_local, fabric, policy=policy,
-            schedule=sched, fused_reductions=fused_reductions,
-            interpret=interpret)
-        if apply_impl is not None:
-            op = op.with_apply(obs_trace.scoped("spmv")(lambda v: apply_impl(
-                op.coeffs, v, fabric, policy=policy, overlap=sched.overlap_halo)))
+        op = build(cf_local)
         M = build_precond(pconf, op)
         return solver_fn(op, b_local, x0_local, tol=tol, maxiter=maxiter,
                          policy=policy, record_history=record_history,
@@ -240,70 +184,30 @@ def make_iteration_fn(
     *,
     policy: Policy = MIXED,
     fused_reductions: bool = True,
-    overlap_halo: bool | None = None,
-    schedule: str | None = None,
+    schedule=None,
     backend: str = "spmd",
     interpret: bool | None = None,
-    apply_impl: Callable | None = None,
 ):
     """One BiCGStab iteration as a standalone SPMD function.
 
     This is the unit the paper measures (28.1 us/iter on the CS-1) and the
     unit the dry-run lowers for the roofline: 2 halo-exchange SpMVs, 6 AXPYs,
-    4 inner products, 3 (fused) or 5 (separate) AllReduce points.  With
-    ``backend="pallas"`` the body is the fused-kernel dataflow, so lowering
-    it shows the 3-AllReduce schedule of the wired fused iteration.
+    4 inner products, 3 (fused) or 5 (separate) AllReduce points.  The body
+    is the solver's own step (``core.solvers.bicgstab.dataflow``): with
+    ``backend="pallas"`` the fused-kernel dataflow, so lowering it shows the
+    3-AllReduce schedule of the wired fused iteration.
 
     Signature: (coeffs, x, r, p, r0, rho) -> (x, r, p, rho, res2).
     """
-    from repro.core.solvers.common import safe_div
-
-    sched = get_schedule(schedule if schedule is not None else overlap_halo)
-    fabric = FabricAxes.from_mesh(mesh)
-    if backend == "reference" and mesh.devices.size > 1:
-        raise ValueError(
-            "backend='reference' is single-address-space only; use "
-            "backend='spmd' or 'pallas' on a multi-device mesh")
+    fabric, build = _local_operator(
+        mesh, backend=backend, policy=policy, schedule=schedule,
+        fused_reductions=fused_reductions, interpret=interpret)
 
     def iteration(cf, x, r, p, r0, rho):
-        op = make_operator(
-            backend, cf, fabric, policy=policy,
-            schedule=sched, fused_reductions=fused_reductions,
-            interpret=interpret)
-        if apply_impl is not None:
-            op = op.with_apply(obs_trace.scoped("spmv")(lambda v: apply_impl(
-                op.coeffs, v, fabric, policy=policy, overlap=sched.overlap_halo)))
-        axpy, axpy2 = _axpys(policy)
-        if op.fused is not None:
-            f = op.fused
-            st = policy.storage
-            s = op.apply(p)
-            (r0s,) = op.reduce_partials([f.dot_partial(r0, s)])
-            alpha, _ = safe_div(rho, r0s)
-            with obs_trace.scope("update"):
-                q_in = r - alpha.astype(st) * s
-            y = op.apply(q_in)
-            q, qy, yy = f.update_q_dots(alpha, r, s, y)
-            qy, yy = op.reduce_partials([qy, yy])
-            omega, _ = safe_div(qy, yy)
-            x, r_new, r0r, rr = f.update_xr_dots(alpha, omega, x, p, q, y, r0)
-            rho_new, res2 = op.reduce_partials([r0r, rr])
-            beta_frac, _ = safe_div(rho_new, rho)
-            alpha_frac, _ = safe_div(alpha, omega)
-            p = f.update_p(beta_frac * alpha_frac, omega, r_new, p, s)
-            return x, r_new, p, rho_new, res2
-        s, (r0s,) = op.apply_dots(p, r0, ("w",))
-        alpha, _ = safe_div(rho, r0s)
-        q = axpy(-alpha, s, r)
-        y, (qy, yy) = op.apply_dots(q, None, ("v", "u"))
-        omega, _ = safe_div(qy, yy)
-        x = axpy2(alpha, p, omega, q, x)
-        r_new = axpy(-omega, y, q)
-        rho_new, res2 = op.dots([(r0, r_new), (r_new, r_new)], policy)
-        beta_frac, _ = safe_div(rho_new, rho)
-        alpha_frac, _ = safe_div(alpha, omega)
-        p = axpy(beta_frac * alpha_frac, axpy(-omega, s, p), r_new)
-        return x, r_new, p, rho_new, res2
+        op = build(cf)
+        _, step = bicgstab_solvers.dataflow(op)
+        x, r, p, rho, res2, _ = step(op, policy, x, r, p, r0, rho)
+        return x, r, p, rho, res2
 
     spec = fabric.spec(3)
     scalar = P()
@@ -357,9 +261,9 @@ def solve_refined(
     rels = []
     for _ in range(outer_iters):
         r = b.astype(jnp.float32) - apply32(x)
-        rels.append(jnp.linalg.norm(r) / jnp.maximum(bnorm, _EPS))
+        rels.append(jnp.linalg.norm(r) / jnp.maximum(bnorm, EPS))
         d = inner(r.astype(inner_policy.storage))
         x = x + d.x.astype(jnp.float32)
     r = b.astype(jnp.float32) - apply32(x)
-    rels.append(jnp.linalg.norm(r) / jnp.maximum(bnorm, _EPS))
+    rels.append(jnp.linalg.norm(r) / jnp.maximum(bnorm, EPS))
     return x, jnp.stack(rels)

@@ -26,7 +26,10 @@ This module makes that schedule a first-class, pluggable object:
     (:func:`boundary_ring_apply`).  The collectives' dependent region is
     minimal, so XLA's latency-hiding scheduler runs them under the interior
     work.  The result is bit-identical to ``blocking``: both paths
-    accumulate the same terms in the same (canonical spec) order.
+    accumulate the same terms in the same (canonical spec) order, through
+    one expression of the stencil sum (``core.stencil.padded_apply``; the
+    XLA interior is it over a zero-padded block), so the compiler
+    contracts their multiply-adds the same way.
 
 * :func:`scheduled_apply` — the one composition point: every operator
   backend's SpMV is ``scheduled_apply`` with a backend-specific interior
@@ -44,12 +47,10 @@ import dataclasses
 
 import jax
 
-from repro.core.halo import (
-    FabricAxes, gather_halo, interior_apply, padded_apply,
-)
+from repro.core.halo import FabricAxes, gather_halo, interior_apply
 from repro.core.precision import Policy, F32
 from repro.core.solvers.common import dot_operands
-from repro.core.stencil import StencilCoeffs
+from repro.core.stencil import StencilCoeffs, padded_apply
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,13 +77,11 @@ SCHEDULES = {s.name: s for s in (BLOCKING, OVERLAP)}
 
 
 def get_schedule(schedule, default: CommSchedule = OVERLAP) -> CommSchedule:
-    """Normalize a name / CommSchedule / legacy ``overlap`` bool / None."""
+    """Normalize a name / CommSchedule / None (``default``)."""
     if schedule is None:
         return default
     if isinstance(schedule, CommSchedule):
         return schedule
-    if isinstance(schedule, bool):  # legacy overlap= flag
-        return OVERLAP if schedule else BLOCKING
     try:
         return SCHEDULES[schedule]
     except KeyError:

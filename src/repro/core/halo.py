@@ -14,8 +14,8 @@ ICI torus); fabric-edge chips receive zeros from ``ppermute``, which is
 exactly the zero-Dirichlet boundary.  The CS-1 FIFO/task overlap machinery
 is replaced by dataflow: the interior stencil terms do not depend on the
 permutes, so XLA's latency-hiding scheduler runs the collectives under the
-interior compute (``overlap=True`` makes this explicit by shrinking the
-halo-dependent computation to the outer shell of the block).
+interior compute (the ``overlap`` schedule makes this explicit by shrinking
+the halo-dependent computation to the outer shell of the block).
 
 Stencil-family generalization (:func:`gather_halo`): a radius-r spec moves
 slabs of thickness r instead of single faces — the r stacked face shifts of
@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.precision import Policy, F32
-from repro.core.stencil import StencilCoeffs, _shift_nd, name_offset
+from repro.core.stencil import StencilCoeffs, padded_apply
 from repro.obs import trace as obs_trace
 
 
@@ -175,66 +175,20 @@ def gather_halo(
     return vp
 
 
-def _window(vp: jax.Array, off: tuple[int, ...], shape: tuple[int, ...],
-            r: int, n_batch: int = 0) -> jax.Array:
-    """The ``shape``-sized window of the r-padded block shifted by ``off``.
-
-    ``n_batch`` leading axes of ``vp`` are unpadded batch axes, taken whole.
-    """
-    return vp[(slice(None),) * n_batch
-              + tuple(slice(r + o, r + o + n) for o, n in zip(off, shape))]
-
-
-def padded_apply(
-    coeffs: StencilCoeffs,
-    vp: jax.Array,
-    shape: tuple[int, ...],
-    *,
-    policy: Policy = F32,
-    region: tuple[slice, ...] | None = None,
-) -> jax.Array:
-    """u = A v from an r-padded local block (halos already in place).
-
-    ``vp`` (and ``shape``) may carry a leading batch axis: the coefficients
-    broadcast across it and ``region`` keeps addressing the trailing mesh
-    dims only.
-
-    ``region`` restricts the computation to a sub-box of the local block —
-    used by the overlap schedule to recompute only the halo-dependent
-    boundary ring (``core.comm.boundary_ring_apply``).
-    """
-    spec = coeffs.spec
-    c = policy.compute
-    nb = vp.ndim - coeffs.ndim
-    mesh_shape = tuple(shape[len(shape) - coeffs.ndim:])
-    reg = region if region is not None else tuple(slice(None) for _ in mesh_shape)
-    vreg = (slice(None),) * nb + tuple(reg)
-    sub = lambda off: _window(vp, off, mesh_shape, spec.radius, nb)[vreg].astype(c)
-    center = sub((0,) * coeffs.ndim)
-    if coeffs.diag is None:  # unit main diagonal (Jacobi-normalized family)
-        u = center
-    else:
-        u = coeffs.diag[reg].astype(c) * center
-    for name, cf in coeffs.ordered_items():   # canonical order — see StencilCoeffs
-        u = u + cf[reg].astype(c) * sub(name_offset(name, coeffs.ndim))
-    return u
-
-
 def interior_apply(coeffs: StencilCoeffs, v: jax.Array, *,
                    policy: Policy = F32) -> jax.Array:
     """Zero-Dirichlet local apply in compute dtype — reads nothing a
     collective produced, so it is the work the overlap schedule runs while
     the halo faces are in flight.  Correct everywhere except the depth-r
     boundary ring bordering a split axis (patched afterwards).  ``v`` may
-    carry a leading batch axis (shifts act on the trailing mesh dims)."""
-    c = policy.compute
+    carry a leading batch axis (shifts act on the trailing mesh dims).
+
+    It is ``core.stencil.padded_apply`` over the zero-padded block, the
+    blocking apply's own expression (see there for why it must be)."""
+    r = coeffs.spec.radius
     nb = v.ndim - coeffs.ndim
-    vc = v.astype(c)
-    u = vc if coeffs.diag is None else coeffs.diag.astype(c) * vc
-    for name, cf in coeffs.ordered_items():   # canonical order — see StencilCoeffs
-        u = u + cf.astype(c) * _shift_nd(
-            vc, (0,) * nb + name_offset(name, coeffs.ndim))
-    return u
+    vp = jnp.pad(v, [(0, 0)] * nb + [(r, r)] * coeffs.ndim)
+    return padded_apply(coeffs, vp, v.shape, policy=policy)
 
 
 def local_apply(
@@ -243,7 +197,6 @@ def local_apply(
     fabric: FabricAxes,
     *,
     policy: Policy = F32,
-    overlap: bool | None = None,
     schedule=None,
 ) -> jax.Array:
     """Local shard of u = A v with depth-r halo exchange.  Runs inside
@@ -260,14 +213,11 @@ def local_apply(
       depth-r boundary ring — bit-identical to blocking, with a minimal
       collective-dependent region for the latency-hiding scheduler.
 
-    ``overlap=True/False`` is the legacy boolean spelling of the same
-    choice; ``schedule`` (a name or :class:`~repro.core.comm.CommSchedule`)
-    wins when both are given.
+    ``schedule`` is a name or :class:`~repro.core.comm.CommSchedule`.
     """
-    from repro.core.comm import get_schedule, scheduled_apply
+    from repro.core.comm import scheduled_apply
 
-    sched = get_schedule(schedule if schedule is not None else overlap)
-    return scheduled_apply(coeffs, v, fabric, policy=policy, schedule=sched)
+    return scheduled_apply(coeffs, v, fabric, policy=policy, schedule=schedule)
 
 
 # Reductions (paper §IV-3: AllReduce for the BiCGStab inner products) live
@@ -278,7 +228,7 @@ def local_apply(
 
 
 def global_apply(mesh, coeffs: StencilCoeffs, v: jax.Array, *, policy: Policy = F32,
-                 overlap: bool | None = None, schedule=None) -> jax.Array:
+                 schedule=None) -> jax.Array:
     """Convenience wrapper: one distributed SpMV on global arrays."""
     fabric = FabricAxes.from_mesh(mesh)
     nb = v.ndim - coeffs.ndim
@@ -286,8 +236,7 @@ def global_apply(mesh, coeffs: StencilCoeffs, v: jax.Array, *, policy: Policy = 
     v_spec = fabric.spec(coeffs.ndim, n_batch=nb)
 
     def fn(cf, vv):
-        return local_apply(cf, vv, fabric, policy=policy, overlap=overlap,
-                           schedule=schedule)
+        return local_apply(cf, vv, fabric, policy=policy, schedule=schedule)
 
     return jax.shard_map(fn, mesh=mesh, in_specs=(cf_spec, v_spec),
                      out_specs=v_spec, check_vma=False)(coeffs, v)

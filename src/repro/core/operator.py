@@ -261,19 +261,18 @@ def spmd_partials(coeffs: StencilCoeffs, fabric: FabricAxes, policy: Policy,
 
 
 def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
-                  policy: Policy = F32, overlap: bool | None = None,
-                  schedule=None, fused_reductions: bool = True,
+                  policy: Policy = F32, schedule=None,
+                  fused_reductions: bool = True,
                   **_unused) -> LinearOperator:
     """Halo-exchange SPMD backend (the paper's scheme; runs inside shard_map).
 
-    ``schedule`` picks the halo schedule (``core.comm.SCHEDULES``); the
-    legacy ``overlap`` boolean spells the same choice and loses ties.
+    ``schedule`` picks the halo schedule (``core.comm.SCHEDULES``).
     The overlap schedule's interior is :func:`spmd_interior`, and its
     SpMV-with-dots :func:`spmd_partials`.
     """
     fabric = fabric or FabricAxes()
     cf = coeffs.astype(policy.storage)
-    sched = get_schedule(schedule if schedule is not None else overlap)
+    sched = get_schedule(schedule)
     dots, reduce_partials, reduce_max = _make_reductions(
         _fabric_axis_names(fabric), fused_reductions, mesh_ndim=cf.ndim)
     interior = spmd_interior(cf, policy)
@@ -290,8 +289,8 @@ def spmd_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
 
 
 def pallas_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
-                    policy: Policy = F32, overlap: bool | None = None,
-                    schedule=None, fused_reductions: bool = True,
+                    policy: Policy = F32, schedule=None,
+                    fused_reductions: bool = True,
                     interpret: bool | None = None,
                     fuse_ring: bool | None = None, **_unused) -> LinearOperator:
     """Pallas-fused backend: halo exchange + fused stencil kernel for the
@@ -313,7 +312,7 @@ def pallas_operator(coeffs: StencilCoeffs, fabric: FabricAxes | None = None, *,
 
     fabric = fabric or FabricAxes()
     cf = coeffs.astype(policy.storage)
-    sched = get_schedule(schedule if schedule is not None else overlap)
+    sched = get_schedule(schedule)
     it = resolve_interpret(interpret)
     _dots, reduce_partials, reduce_max = _make_reductions(
         _fabric_axis_names(fabric), fused_reductions, mesh_ndim=cf.ndim)

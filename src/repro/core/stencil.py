@@ -273,28 +273,57 @@ class StencilCoeffs:
         return cls(dict(zip(keys, values)))
 
 
-def _shift(v: jax.Array, axis: int, offset: int) -> jax.Array:
-    """v shifted so result[i] = v[i + offset] along ``axis``; zero fill."""
-    if offset == 0:
-        return v
-    pad = [(0, 0)] * v.ndim
-    if offset > 0:
-        pad[axis] = (0, offset)
-        return jnp.pad(v, pad)[
-            tuple(slice(offset, None) if a == axis else slice(None) for a in range(v.ndim))
-        ]
-    pad[axis] = (-offset, 0)
-    return jnp.pad(v, pad)[
-        tuple(slice(0, offset) if a == axis else slice(None) for a in range(v.ndim))
-    ]
+def _window(vp: jax.Array, off: tuple[int, ...], shape: tuple[int, ...],
+            r: int, n_batch: int = 0) -> jax.Array:
+    """The ``shape``-sized window of the r-padded block shifted by ``off``.
+
+    ``n_batch`` leading axes of ``vp`` are unpadded batch axes, taken whole.
+    """
+    return vp[(slice(None),) * n_batch
+              + tuple(slice(r + o, r + o + n) for o, n in zip(off, shape))]
 
 
-def _shift_nd(v: jax.Array, off: tuple[int, ...]) -> jax.Array:
-    """v shifted by a (possibly multi-axis) offset, zero fill at the edges."""
-    for axis, o in enumerate(off):
-        if o != 0:
-            v = _shift(v, axis, o)
-    return v
+def padded_apply(
+    coeffs: StencilCoeffs,
+    vp: jax.Array,
+    shape: tuple[int, ...],
+    *,
+    policy: Policy = F32,
+    region: tuple[slice, ...] | None = None,
+) -> jax.Array:
+    """u = A v from an r-padded local block (halos already in place).
+
+    ``vp`` (and ``shape``) may carry a leading batch axis: the coefficients
+    broadcast across it and ``region`` keeps addressing the trailing mesh
+    dims only.
+
+    ``region`` restricts the computation to a sub-box of the local block —
+    used by the overlap schedule to recompute only the halo-dependent
+    boundary ring (``core.comm.boundary_ring_apply``).
+
+    This is the one expression of the stencil sum in XLA: :func:`apply_ref`,
+    the overlap interior (``core.halo.interior_apply``), the blocking apply
+    and the ring patch all call it, and must.  XLA may contract a product
+    and its add into one fused multiply-add, and a sum written another way
+    is contracted differently at some points, one ulp apart; the bitwise
+    schedule and batch contracts (``tests/test_operator_backends.py``,
+    ``tests/test_batched.py``) rest on every path sharing this expression.
+    """
+    spec = coeffs.spec
+    c = policy.compute
+    nb = vp.ndim - coeffs.ndim
+    mesh_shape = tuple(shape[len(shape) - coeffs.ndim:])
+    reg = region if region is not None else tuple(slice(None) for _ in mesh_shape)
+    vreg = (slice(None),) * nb + tuple(reg)
+    sub = lambda off: _window(vp, off, mesh_shape, spec.radius, nb)[vreg].astype(c)
+    center = sub((0,) * coeffs.ndim)
+    if coeffs.diag is None:  # unit main diagonal (Jacobi-normalized family)
+        u = center
+    else:
+        u = coeffs.diag[reg].astype(c) * center
+    for name, cf in coeffs.ordered_items():   # canonical order — see StencilCoeffs
+        u = u + cf[reg].astype(c) * sub(name_offset(name, coeffs.ndim))
+    return u
 
 
 def apply_ref(coeffs: StencilCoeffs, v: jax.Array, *, policy: Policy = F32) -> jax.Array:
@@ -314,18 +343,13 @@ def apply_ref(coeffs: StencilCoeffs, v: jax.Array, *, policy: Policy = F32) -> j
 
     Terms accumulate in the canonical order of ``coeffs.ordered_items()``
     — the same order every distributed apply path and the Pallas kernel
-    use, which keeps the backends bit-comparable.
+    use, which keeps the backends bit-comparable.  It is
+    :func:`padded_apply` over the zero-padded block.
     """
-    c = policy.compute
+    r = coeffs.spec.radius
     nb = v.ndim - coeffs.ndim          # leading batch axes (0 or 1)
-    if coeffs.diag is None:
-        u = v.astype(c)
-    else:
-        u = coeffs.diag.astype(c) * v.astype(c)
-    for name, cf in coeffs.ordered_items():
-        off = (0,) * nb + name_offset(name, coeffs.ndim)
-        u = u + cf.astype(c) * _shift_nd(v, off).astype(c)
-    return u.astype(policy.storage)
+    vp = jnp.pad(v, [(0, 0)] * nb + [(r, r)] * coeffs.ndim)
+    return padded_apply(coeffs, vp, v.shape, policy=policy).astype(policy.storage)
 
 
 def to_dense(coeffs: StencilCoeffs) -> np.ndarray:

@@ -23,9 +23,8 @@ same shape as an inference stack's kernel autotuner:
   default (``kernels/stencil_nd.ops.default_tile``), so an empty or absent
   cache reproduces the untuned behaviour bit-for-bit.
 * :func:`autotune_cell` / :func:`measure_config` — the hypothesis->measure
-  sweep primitives ``benchmarks/kernel_autotune.py`` drives (extending the
-  ``benchmarks/hillclimb.py`` loop) and ``launch.solve --autotune`` calls
-  inline for its own cell.
+  sweep primitives ``launch.solve --autotune`` calls inline for its own
+  cell.
 
 Kernel imports are deferred inside functions: ``kernels/stencil_nd`` looks
 configs up here, so a module-level import would cycle.
@@ -110,7 +109,7 @@ def cache_key(spec: StencilSpec, dtype, shape: tuple[int, ...],
 
     It names the device the sweep ran on and the *problem cell* (shape
     contract x dtype x local block), never the code revision; re-sweep
-    (``kernel_autotune --force``) after a kernel change.  ``device_kind``
+    (``autotune_cell(..., force=True)``) after a kernel change.  ``device_kind``
     defaults to the first visible device's.
     """
     dims = "x".join(str(int(s)) for s in shape)
@@ -280,7 +279,7 @@ def lookup_config(spec: StencilSpec, dtype, shape: tuple[int, int, int], *,
                 f"tuning-cache entry {key!r} names tile "
                 f"{tuned.tile} which is not valid for the "
                 f"local block {shape} (stale entry?); using the default "
-                f"config — re-sweep with benchmarks/kernel_autotune.py",
+                f"config — re-sweep with tuning.autotune_cell(force=True)",
                 stacklevel=2)
             obs_metrics.counter("tuning.lookup.stale").inc()
             return default_config(spec, dtype, shape), "stale"

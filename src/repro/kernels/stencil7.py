@@ -23,17 +23,15 @@ warnings.warn(
     "This shim re-exports the legacy names and will be removed.",
     DeprecationWarning, stacklevel=2)
 from repro.kernels import stencil_nd
-from repro.kernels.stencil_nd.fused import (  # noqa: F401  (re-exported API)
-    ORDER,
-    stencil7_dot,
-    stencil7_two_dots,
-)
 from repro.kernels.stencil_nd.kernel import stencil_nd_pallas
 from repro.kernels.stencil_nd.ops import (  # noqa: F401  (re-exported API)
     VMEM_BUDGET_BYTES,
     default_tile,
 )
 from repro.kernels.stencil_nd.ref import stencil_nd_ref
+
+# the 7-point kernel's argument order (== STAR7.names: xp, xm, yp, ym, zp, zm)
+ORDER = STAR7.names
 
 
 def stencil7_apply(coeffs: StencilCoeffs, v: jax.Array, *,
@@ -61,9 +59,9 @@ def stencil7_ref(v: jax.Array, coeffs: list[jax.Array],
     return stencil_nd_ref(v, coeffs, STAR7.offsets, accum_dtype=accum_dtype)
 
 
-def pallas_local_apply(coeffs, v, fabric, *, policy, overlap=None,
-                       schedule=None, interpret: bool | None = None):
+def pallas_local_apply(coeffs, v, fabric, *, policy, schedule=None,
+                       interpret: bool | None = None):
     """Drop-in for halo.local_apply: halo exchange + fused Pallas SpMV."""
     return stencil_nd.pallas_local_apply(coeffs, v, fabric, policy=policy,
-                                         overlap=overlap, schedule=schedule,
+                                         schedule=schedule,
                                          interpret=interpret)

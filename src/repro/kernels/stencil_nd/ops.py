@@ -1,6 +1,7 @@
 """jit'd wrappers for the generalized stencil kernel: tuning-cache lookup,
-VMEM budgeting, padding, and the drop-in local-apply (``apply_impl=`` of
-solve_distributed) that pairs the kernel with the depth-r halo exchange.
+VMEM budgeting, padding, and the local apply of ``backend="pallas"``
+(:func:`pallas_local_apply`) that pairs the kernel with the depth-r halo
+exchange.
 
 Every wrapper resolves its tile shapes through the persistent tuning cache
 (``core/tuning``): a swept cell transparently gets its winning
@@ -141,8 +142,8 @@ def stencil_apply(coeffs: StencilCoeffs, v: jax.Array, *,
                       accum_dtype=accum_dtype, interpret=interpret)
 
 
-def pallas_local_apply(coeffs, v, fabric, *, policy, overlap: bool | None = None,
-                       schedule=None, interpret: bool | None = None,
+def pallas_local_apply(coeffs, v, fabric, *, policy, schedule=None,
+                       interpret: bool | None = None,
                        fuse_ring: bool | None = None):
     """Drop-in for halo.local_apply: depth-r halo exchange + fused kernel,
     under either communication schedule (``core.comm.SCHEDULES``).
@@ -204,7 +205,7 @@ def pallas_local_apply(coeffs, v, fabric, *, policy, overlap: bool | None = None
 
     return comm.scheduled_apply(
         cf, vs, fabric, policy=policy,
-        schedule=schedule if schedule is not None else overlap,
+        schedule=schedule,
         full_fn=kernel,
         interior_fn=lambda vv: kernel(
             jnp.pad(vv, [(0, 0)] * nb + [(r, r)] * cf.ndim)),

@@ -326,7 +326,7 @@ def _lower_lm_cell_inner(arch, shape_name, multi_pod, cfg, shape, rec, probes):
     return rec
 
 
-def _compile_stencil(cell, mesh, policy, *, fused, overlap):
+def _compile_stencil(cell, mesh, policy, *, fused, schedule):
     fabric = FabricAxes.from_mesh(mesh)
     X, Y, Z = cell.mesh_shape
     spec = fabric.spec(3)
@@ -337,13 +337,13 @@ def _compile_stencil(cell, mesh, policy, *, fused, overlap):
     from repro.core.stencil import StencilCoeffs, DIAGS_3D
     cf = StencilCoeffs({n: vec for n in DIAGS_3D})
     it = bicgstab.make_iteration_fn(mesh, policy=policy, fused_reductions=fused,
-                                    overlap_halo=overlap)
+                                    schedule=schedule)
     lowered = jax.jit(it, donate_argnums=(1, 2, 3)).lower(cf, vec, vec, vec, vec, scl)
     return lowered.compile()
 
 
 def lower_stencil_cell(cell_name: str, multi_pod: bool, *, fused: bool = True,
-                       overlap: bool = True, policy_name: str | None = None) -> dict:
+                       schedule: str = "overlap", policy_name: str | None = None) -> dict:
     """Stencil BiCGStab iteration roofline.
 
     Two compiles: the requested policy (usually bf16_mixed — proves the
@@ -362,19 +362,19 @@ def lower_stencil_cell(cell_name: str, multi_pod: bool, *, fused: bool = True,
     X, Y, Z = cell.mesh_shape
     rec = {"arch": f"stencil_{cell_name}", "shape": "bicgstab_iter",
            "mesh": "2x16x16" if multi_pod else "16x16", "kind": "solver",
-           "fused_reductions": fused, "overlap_halo": overlap,
+           "fused_reductions": fused, "schedule": schedule,
            "policy": policy.name}
     n_dev = math.prod(mesh.devices.shape)
 
     t0 = time.time()
-    compiled = _compile_stencil(cell, mesh, policy, fused=fused, overlap=overlap)
+    compiled = _compile_stencil(cell, mesh, policy, fused=fused, schedule=schedule)
     rec["lower_compile_s"] = time.time() - t0
     rec["memory_analysis"] = _mem_dict(compiled.memory_analysis())
     rec["policy_cost_raw"] = _cost_vector(compiled, mesh)
 
     if policy.storage != jnp.float32:
         c32 = _compile_stencil(cell, mesh, precision.F32, fused=fused,
-                               overlap=overlap)
+                               schedule=schedule)
         cost32 = _cost_vector(c32, mesh)
         ratio = jnp.dtype(policy.storage).itemsize / 4.0
         cost = {

@@ -213,18 +213,3 @@ def validate_manifest(man: dict) -> list[str]:
 def load_manifest(run_dir: str) -> dict:
     with open(os.path.join(run_dir, "manifest.json")) as f:
         return json.load(f)
-
-
-def write_benchmark_bundle(name: str, record: dict,
-                           root: str = DEFAULT_ROOT) -> str:
-    """One-shot bundle for a benchmark record (the benchmarks/run.py hook):
-    the record lands both as a ``benchmark_record`` event and as
-    ``record.json`` next to the manifest.  Returns the run directory."""
-    ctx = start_run(f"bench-{name}", config={"benchmark": name})
-    metrics.event("benchmark_record", name=name,
-                  schema=record.get("schema"),
-                  generated_by=record.get("generated_by"))
-    with open(os.path.join(ctx.run_dir, "record.json"), "w") as f:
-        json.dump(_jsonable(record), f, indent=2)
-    finish_run(ctx, extra={"benchmark": name})
-    return ctx.run_dir

@@ -285,7 +285,7 @@ def test_stencil7_deprecation_warning_fires_once():
         "hits = [x for x in w if issubclass(x.category, DeprecationWarning)\n"
         "        and 'stencil_nd' in str(x.message)]\n"
         "assert len(hits) == 1, [str(x.message) for x in w]\n"
-        "assert callable(s7.stencil7_apply) and callable(s7.stencil7_dot)\n"
+        "assert callable(s7.stencil7_apply) and callable(s7.stencil7_pallas)\n"
         "print('OK')\n"
     )
     assert "OK" in _run_snippet(code)

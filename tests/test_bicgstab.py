@@ -102,7 +102,7 @@ def test_iterative_refinement_recovers_f32_accuracy():
 
 def test_cg_on_spd_poisson():
     cf, x_true, b = _problem((6, 6, 6), kind="poisson")
-    res = bicgstab.cg_ref(cf, b, tol=1e-8, maxiter=400)
+    res = bicgstab.solve_ref(cf, b, tol=1e-8, maxiter=400, solver="cg")
     assert bool(res.converged)
     np.testing.assert_allclose(np.asarray(res.x), np.asarray(x_true), rtol=2e-4, atol=2e-4)
 

@@ -17,8 +17,8 @@ def test_distributed_apply_matches_ref(subproc):
         cf = stencil.random_nonsymmetric(jax.random.PRNGKey(0), shape)
         v = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
         u_ref = stencil.apply_ref(cf, v)
-        for overlap in (True, False):
-            u = global_apply(mesh, cf, v, overlap=overlap)
+        for schedule in ("overlap", "blocking"):
+            u = global_apply(mesh, cf, v, schedule=schedule)
             np.testing.assert_allclose(np.asarray(u), np.asarray(u_ref), rtol=1e-5, atol=1e-5)
         print('OK')
     """)
@@ -79,8 +79,8 @@ def test_depth_r_halo_apply_matches_ref(subproc):
             cf = stencil.random_nonsymmetric(jax.random.PRNGKey(0), shape, spec=spec)
             v = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
             u_ref = stencil.apply_ref(cf, v)
-            for overlap in (True, False):
-                u = global_apply(mesh, cf, v, overlap=overlap)
+            for schedule in ("overlap", "blocking"):
+                u = global_apply(mesh, cf, v, schedule=schedule)
                 np.testing.assert_allclose(np.asarray(u), np.asarray(u_ref),
                                            rtol=1e-5, atol=1e-5, err_msg=name)
         print('OK')

@@ -150,29 +150,13 @@ def test_pallas_solver_integration():
     np.testing.assert_allclose(np.asarray(q1), np.asarray(q2), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("shape", [(4, 4, 8), (5, 6, 16), (3, 3, 4)])
-def test_stencil7_dot_epilogue(shape):
-    """Fused SpMV + <r0, s> epilogue (§Perf v3 schedule) vs oracles."""
-    from repro.kernels.stencil_nd.fused import stencil7_dot, stencil7_two_dots
-    cf = stencil.random_nonsymmetric(jax.random.PRNGKey(0), shape)
-    p = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)
-    r0 = jax.random.normal(jax.random.PRNGKey(2), shape, jnp.float32)
-    s, r0s = stencil7_dot(cf, p, r0)
-    s_ref = stencil7_ref(p, [cf.diags[n] for n in ORDER])
-    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(float(r0s), float(jnp.vdot(r0, s_ref)), rtol=1e-4, atol=1e-4)
-    y, qy, yy = stencil7_two_dots(cf, p)
-    np.testing.assert_allclose(float(qy), float(jnp.vdot(p, s_ref)), rtol=1e-4, atol=1e-3)
-    np.testing.assert_allclose(float(yy), float(jnp.vdot(s_ref, s_ref)), rtol=1e-4, atol=1e-3)
-
-
 @pytest.mark.slow
 def test_pallas_local_apply_in_distributed_solver(subproc):
-    """solve_distributed with the Pallas kernel as apply_impl == jnp path."""
+    """solve_distributed with the Pallas kernel as the SpMV
+    (``backend="pallas"``, whose apply is ``pallas_local_apply``)."""
     subproc("""
-        import functools, jax, jax.numpy as jnp, numpy as np
+        import jax, jax.numpy as jnp, numpy as np
         from repro.core import stencil, bicgstab, precision
-        from repro.kernels.stencil7 import pallas_local_apply
         from repro.launch.mesh import make_mesh_for_devices
         mesh = make_mesh_for_devices(4)
         shape = (8, 8, 8)
@@ -181,26 +165,12 @@ def test_pallas_local_apply_in_distributed_solver(subproc):
         b = stencil.rhs_for_solution(cf, x_true)
         res = bicgstab.solve_distributed(
             mesh, cf, b, tol=1e-8, maxiter=300, policy=precision.F32,
-            apply_impl=functools.partial(pallas_local_apply, interpret=True))
+            backend="pallas", interpret=True)
         assert bool(res.converged), res
         np.testing.assert_allclose(np.asarray(res.x), np.asarray(x_true),
                                    rtol=2e-4, atol=2e-4)
         print('OK')
     """, n_devices=4)
-
-
-def test_fused_schedule_full_solve():
-    """End-to-end BiCGStab through the v3 fused-kernel schedule converges to
-    the same solution as the reference solver."""
-    from repro.core import bicgstab
-    shape = (6, 6, 8)
-    cf = stencil.random_nonsymmetric(jax.random.PRNGKey(11), shape)
-    x_true = jax.random.normal(jax.random.PRNGKey(12), shape, jnp.float32)
-    b = stencil.rhs_for_solution(cf, x_true)
-    res = bicgstab.solve_ref_fused(cf, b, tol=1e-7, maxiter=100)
-    assert bool(res.converged), float(res.rel_residual)
-    np.testing.assert_allclose(np.asarray(res.x), np.asarray(x_true),
-                               rtol=5e-4, atol=5e-4)
 
 
 def test_fp8_coefficients_with_refinement():

@@ -220,16 +220,6 @@ class TestManifest:
         assert any("run_id" in p for p in problems)
         assert any("bogus" in p for p in problems)
 
-    def test_benchmark_bundle(self, tmp_path):
-        rec = {"schema": "repro.benchmark.v1", "generated_by": "test",
-               "cells": [1, 2]}
-        d = manifest.write_benchmark_bundle("demo", rec, root=str(tmp_path))
-        man = manifest.load_manifest(d)
-        assert manifest.validate_manifest(man) == []
-        assert man["kind"] == "bench-demo" and man["benchmark"] == "demo"
-        with open(os.path.join(d, "record.json")) as f:
-            assert json.load(f) == rec
-
 
 # ------------------------------- emitted counts vs HLO ground truth -----
 

@@ -28,8 +28,9 @@ def test_registry_contents():
 def test_schedule_registry_and_operator_carry():
     assert set(SCHEDULES) == {"blocking", "overlap"}
     assert get_schedule(None).name == "overlap"        # default
-    assert get_schedule(False).name == "blocking"      # legacy bool spelling
-    assert get_schedule(True).name == "overlap"
+    for spelling in (False, True):                     # no boolean spelling
+        with pytest.raises(KeyError, match="unknown comm schedule"):
+            get_schedule(spelling)
     with pytest.raises(KeyError, match="unknown comm schedule"):
         get_schedule("eager")
     cf = stencil.poisson((4, 4, 4))
@@ -206,3 +207,35 @@ def test_fused_backend_allreduce_count_is_3(subproc):
             assert n_pp == 8, (backend, n_pp)
         print('OK')
     """, n_devices=4)
+
+
+@pytest.mark.parametrize("policy", ["f32", "bf16_mixed"])
+@pytest.mark.parametrize("backend,schedule", [
+    ("reference", None), ("spmd", "blocking"), ("spmd", "overlap"),
+    ("pallas", "blocking"), ("pallas", "overlap")])
+def test_iteration_fn_is_the_solvers_step(backend, schedule, policy):
+    """One call of ``make_iteration_fn`` from the solve's start (x = 0,
+    r = p = r0 = b, rho = <b, b>) gives, bit for bit, the x that
+    ``solve_distributed`` returns after one iteration: the unit the dry run
+    lowers is the step the solve runs."""
+    from repro.core import bicgstab
+    from repro.launch.mesh import make_mesh_for_devices
+
+    pol = precision.get_policy(policy)
+    mesh = make_mesh_for_devices(1)
+    cf, v = _problem((8, 8, 8))
+    cf, b = cf.astype(pol.storage), v.astype(pol.storage)
+    res = bicgstab.solve_distributed(
+        mesh, cf, b, tol=0.0, maxiter=1, policy=pol, backend=backend,
+        schedule=schedule)
+    assert int(res.iterations) == 1
+    it = bicgstab.make_iteration_fn(mesh, policy=pol, backend=backend,
+                                    schedule=schedule)
+    # <b, b> as the backend's own dots form it (pallas: its dot kernel)
+    rho = jax.jit(lambda c, u: make_operator(backend, c, policy=pol).dots(
+        [(u, u)], pol)[0])(cf, b)
+    # jitted, as the dry run lowers it (op by op, XLA fuses no multiply-add)
+    x, *_ = jax.jit(it)(cf, jnp.zeros_like(b), b, b, b, rho)
+    assert x.dtype == res.x.dtype
+    np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                  np.asarray(res.x, np.float32))

@@ -50,9 +50,8 @@ def test_chebyshev_approximates_inverse():
 
 
 def test_chebyshev_cuts_poisson_iterations_30pct():
-    """The acceptance lever at test scale (the 48x48x32 headline run lives
-    in benchmarks/solver_matrix.py): right-Chebyshev BiCGStab on Poisson
-    star7 in >=30% fewer iterations, same solution."""
+    """The acceptance lever at test scale: right-Chebyshev BiCGStab on
+    Poisson star7 in >=30% fewer iterations, same solution."""
     shape = (24, 24, 16)
     cf = stencil.poisson(shape)
     x_true = jax.random.normal(jax.random.PRNGKey(1), shape, jnp.float32)

@@ -67,8 +67,7 @@ def test_stencil7_alias_is_generic_kernel():
     from repro.kernels import stencil7, stencil_nd
     assert stencil7.__file__.endswith("stencil7.py")   # module, not package
     for name in ("stencil7_apply", "stencil7_ref", "stencil7_pallas",
-                 "pallas_local_apply", "stencil7_dot", "stencil7_two_dots",
-                 "ORDER", "default_tile", "VMEM_BUDGET_BYTES"):
+                 "pallas_local_apply", "ORDER", "default_tile", "VMEM_BUDGET_BYTES"):
         assert hasattr(stencil7, name), name          # legacy surface intact
     shape = (4, 4, 8)
     cf = stencil.random_nonsymmetric(jax.random.PRNGKey(6), shape)
@@ -93,12 +92,12 @@ def test_pick_zc_budget_scales_with_radius():
 @pytest.mark.parametrize("specname", ["star13", "box27"])
 @pytest.mark.slow
 def test_pallas_local_apply_in_distributed_solver(subproc, specname):
-    """solve_distributed with the generic kernel as apply_impl == jnp path,
-    on a depth-2 (star13) and corner-carrying (box27) halo."""
+    """solve_distributed with the generic kernel as the SpMV
+    (``backend="pallas"``, whose apply is ``pallas_local_apply``), on a
+    depth-2 (star13) and corner-carrying (box27) halo."""
     subproc(f"""
-        import functools, jax, jax.numpy as jnp, numpy as np
+        import jax, jax.numpy as jnp, numpy as np
         from repro.core import stencil, bicgstab, precision
-        from repro.kernels.stencil_nd import pallas_local_apply
         from repro.launch.mesh import make_mesh_for_devices
         mesh = make_mesh_for_devices(4)
         spec = stencil.get_spec({specname!r})
@@ -108,7 +107,7 @@ def test_pallas_local_apply_in_distributed_solver(subproc, specname):
         b = stencil.rhs_for_solution(cf, x_true)
         res = bicgstab.solve_distributed(
             mesh, cf, b, tol=1e-8, maxiter=300, policy=precision.F32,
-            apply_impl=functools.partial(pallas_local_apply, interpret=True))
+            backend="pallas", interpret=True)
         assert bool(res.converged), res
         np.testing.assert_allclose(np.asarray(res.x), np.asarray(x_true),
                                    rtol=2e-4, atol=2e-4)
