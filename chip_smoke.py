@@ -20,8 +20,10 @@ backends at 64x64x256 f32 on the 2x2 mesh against ``solve_ref``.
 HPCG: the 27-point CG solve with the multigrid V-cycle at 384^3 f32
 through the entry point; then the timed path's ``symgs`` sweeps and one
 V-cycle against ``bench/reference_mg.py`` at 384^3, in f32 (within
-``MG_RTOL``) and in bf16_mixed (outside it); the box ``spmv_stream`` and
-its dot partials at 384^3 f32.
+``MG_RTOL``) and in bf16_mixed (outside it); each restriction of the
+384^3 hierarchy by the ``restrict`` kernel against the level's SpMV at
+every point cut to the coarse ones, equal to the bit; the box
+``spmv_stream`` and its dot partials at 384^3 f32.
 
 Informative lines first; the last line is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
@@ -32,6 +34,7 @@ printing it.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -346,6 +349,45 @@ def mg_check(shape: tuple[int, int, int]) -> None:
               f"{MG_RTOL:.0e})")
 
 
+def restrict_check(shape: tuple[int, int, int]) -> None:
+    """Each restriction of the V-cycle's hierarchy over random 27-point
+    fields and random vectors: the ``restrict`` kernel at the coarse points
+    against the parent formula, the level's SpMV at every point cut to
+    them; the same f32 operations, so equal to the bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import multigrid, operator, precision, stencil
+    from repro.core.multigrid import LEVELS, KernelLevel, build_levels
+
+    names = stencil.BOX27.names
+    keys = jax.random.split(jax.random.PRNGKey(3), len(names))
+    cf = jax.jit(lambda: stencil.StencilCoeffs({
+        n: -jax.random.uniform(k, shape, jnp.float32, 0.5, 1.0) / 26
+        for n, k in zip(names, keys)}))()
+
+    @functools.partial(jax.jit, static_argnames="depth")
+    def both(cf, r, x, depth):
+        op = operator.make_operator("spmd", cf, policy=precision.F32,
+                                    schedule="overlap")
+        levels = build_levels(op)
+        fine, coarse = levels[depth], levels[depth + 1]
+        assert isinstance(fine, KernelLevel) and isinstance(coarse, KernelLevel)
+        return (fine.restrict(r, x, coarse),
+                multigrid._sub(r - fine.op.apply(x), multigrid.COARSE))
+
+    for depth in range(LEVELS - 1):
+        n = tuple(m >> depth for m in shape)
+        r, x = (jax.random.normal(jax.random.PRNGKey(10 * depth + k), n, jnp.float32)
+                for k in (1, 2))
+        got, want = both(cf, r, x, depth)
+        diff = float(jnp.max(jnp.abs(got - want)))
+        check(got.shape == want.shape and diff == 0.0,
+              f"{n} restriction at the coarse points against the SpMV at every "
+              f"point: max abs difference {diff!r} (== 0)")
+        del got, want
+
+
 def hpcg() -> None:
     s = solve("--stencil", "box27", "--problem", "poisson", "--solver", "cg",
               "--precond", "mg", "--mesh", "384", "384", "384", "--policy", "f32",
@@ -356,6 +398,7 @@ def hpcg() -> None:
           f"HPCG 384^3: converged in {s['iterations']} iterations, true "
           f"rel-residual {s['true_rel_residual']:.3e}")
     mg_check((384, 384, 384))
+    restrict_check((384, 384, 384))
     stream_check("box27", (384, 384, 384), "f32")
 
 

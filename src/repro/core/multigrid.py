@@ -29,7 +29,11 @@ Two implementations of a level, chosen per level from its operands:
 * on a TPU, for a radius-1 spec with a unit diagonal on an f32 or bf16
   block, the ``symgs`` Pallas kernel (``kernels/stencil_nd/symgs.py``)
   sweeps the full-layout block, and the restriction's residual is the
-  level operator's SpMV;
+  ``restrict`` kernel (``kernels/stencil_nd/restrict.py``): ``r - A x``
+  at the coarse points only, from the next level's fields, which are this
+  level's at those points.  Injection keeps what decides the choice (the
+  spec, the unit diagonal, the dtype), so every level of a hierarchy is
+  one kind or the other;
 * elsewhere a jax.numpy sweep in colour-split form: the iterate held as
   its 8 colour sub-grids, each neighbour term a sub-grid shifted by at
   most one point, so a sweep costs one SpMV's terms and the restriction
@@ -121,10 +125,20 @@ class KernelLevel:
                             interpret=resolve_interpret(None))
         return x
 
-    def restrict(self, r, x):
-        c = self.op.policy.compute
-        res = r.astype(c) - self.op.apply(x).astype(c)
-        return _sub(res, COARSE).astype(self.op.policy.storage)
+    def restrict(self, r, x, coarse):
+        """``(r - A x)`` at the coarse points, by the ``restrict`` kernel
+        from the next level's (``coarse``'s) fields."""
+        from repro.kernels import resolve_interpret
+        from repro.kernels.stencil_nd.restrict import coarse_residual
+
+        obs_metrics.counter("precond.mg.restrict.coarse").inc()
+        pol = self.op.policy
+        items = coarse.op.coeffs.ordered_items()
+        return coarse_residual(
+            r, x, [c for _, c in items],
+            tuple(name_offset(n, 3) for n, _ in items),
+            apply_dtype=pol.storage, compute=pol.compute,
+            out_dtype=pol.storage, interpret=resolve_interpret(None))
 
     def prolong(self, x, xc):
         c = self.op.policy.compute
@@ -205,7 +219,8 @@ class SplitLevel:
             xs = xs.astype(self.policy.storage).astype(xs.dtype)
         return xs
 
-    def restrict(self, rs, xs):
+    def restrict(self, rs, xs, coarse):
+        """``(r - A x)`` on colour (0, 0, 0); ``coarse`` is not read."""
         k = _index(COARSE)
         x0 = jax.lax.slice(xs[k], (1, 1, 1), tuple(n + 1 for n in rs.shape[1:]))
         if self.diag is not None:
@@ -280,7 +295,8 @@ def vcycle(levels, r, depth: int = 0):
     for _ in range(PRE_SWEEPS):
         x = lev.sgs(rs, x)
     if depth + 1 < len(levels):
-        x = lev.prolong(x, vcycle(levels, lev.restrict(rs, x), depth + 1))
+        rc = lev.restrict(rs, x, levels[depth + 1])
+        x = lev.prolong(x, vcycle(levels, rc, depth + 1))
         for _ in range(POST_SWEEPS):
             x = lev.sgs(rs, x)
     return lev.finish(x)

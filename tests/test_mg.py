@@ -18,13 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import bicgstab, multigrid, operator, precision, stencil
+from repro.core import bicgstab, halo, multigrid, operator, precision, stencil
 from repro.core.multigrid import (
-    BACKWARD, FORWARD, MGPrecond, build_levels, colours, vcycle,
+    BACKWARD, COARSE, FORWARD, MGPrecond, build_levels, colours, vcycle,
 )
 from repro.core.precond import IdentityPrecond, PrecondConfig, build_precond
 from repro.core.solvers import cg as solver_cg
 from repro.kernels.stencil_nd import stream
+from repro.kernels.stencil_nd.restrict import coarse_residual
 from repro.kernels.stencil_nd.symgs import plane_order, symgs_sweep
 from repro.obs import metrics
 
@@ -110,14 +111,55 @@ def test_vcycle_in_bf16_fails_the_tolerance():
 
 def test_vcycle_on_the_kernel_path(as_tpu):
     """The chip's path: every level swept by ``symgs``, the restriction's
-    residual by the box ``spmv_stream``."""
+    residual by the ``restrict`` kernel at the coarse points only; no SpMV
+    of the full level."""
     shape = SHAPES[0]
     fields, coeffs, r = _random_box27(shape)
     got = _program_vcycle(coeffs, r)
     assert _rel(got, reference_mg.apply_vcycle(fields, r)) <= VCYCLE_RTOL
     counters = metrics.snapshot()["counters"]
     assert counters["kernels.symgs.traced_calls"] == 3 * 4 + 2
-    assert counters["operator.spmv_interior.stream"] == 3
+    assert counters["precond.mg.restrict.coarse"] == 3
+    assert counters.get("operator.spmv_interior.stream", 0) == 0
+
+
+def _bf16_values(key, shape, scale=1.0):
+    x = scale * jax.random.normal(key, shape, jnp.float32)
+    return x.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("shape", [(8, 72, 40), (16, 16, 16), (8, 16, 264),
+                                   (8, 16, 384)], ids=str)
+@pytest.mark.parametrize("specname", ["box27", "star7"])
+def test_coarse_residual_equals_the_full_residual(specname, shape):
+    """``restrict`` equals the residual at every point cut to the coarse
+    ones, bit for bit: 36 coarse rows (a row tail), 20 and 8 lanes; 132
+    and 192 lanes, a plane of three 128-lane groups (the last partial)
+    split as two pairs, the second half zero lanes.  The
+    operands hold bf16 values, so every product is exact in f32 and the
+    comparison does not depend on whether a compiler fuses a multiply and
+    an add.  Random x puts a nonzero term past every face of the block,
+    which a wrong face would add."""
+    spec = stencil.get_spec(specname)
+    keys = jax.random.split(jax.random.PRNGKey(9), spec.n_offsets + 2)
+    coeffs = stencil.StencilCoeffs({n: _bf16_values(k, shape, 0.2)
+                                    for n, k in zip(spec.names, keys)})
+    x, r = _bf16_values(keys[-2], shape), _bf16_values(keys[-1], shape)
+    want = multigrid._sub(r - halo.interior_apply(coeffs, x, policy=precision.F32),
+                          COARSE)
+    items = multigrid.inject(coeffs).ordered_items()
+    got = coarse_residual(r, x, [c for _, c in items],
+                          tuple(stencil.name_offset(n, 3) for n, _ in items),
+                          interpret=True)
+    assert got.shape == tuple(n // 2 for n in shape)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert metrics.snapshot()["counters"]["kernels.restrict.traced_calls"] == 1
+
+
+def test_coarse_residual_refuses_odd_blocks():
+    v = jnp.zeros((4, 8, 7), jnp.float32)
+    with pytest.raises(ValueError, match="even extents"):
+        coarse_residual(v, v, [], (), interpret=True)
 
 
 @pytest.mark.parametrize("start", ["zero", "given"])
@@ -162,9 +204,9 @@ def test_restricted_residual_is_not_zero():
     shape = SHAPES[0]
     fields, coeffs, r = _random_box27(shape)
     op = operator.make_operator("reference", coeffs, policy=precision.F32)
-    fine = build_levels(op)[0]
+    fine, coarse = build_levels(op)[:2]
     rs = fine.start(r)
-    rc = fine.restrict(rs, fine.sgs(rs, None))
+    rc = fine.restrict(rs, fine.sgs(rs, None), coarse)
     assert float(jnp.linalg.norm(rc)) > 1e-2 * float(jnp.linalg.norm(r[::2, ::2, ::2]))
 
 

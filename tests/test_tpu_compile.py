@@ -233,9 +233,24 @@ def test_symgs_and_box_stream_compile_for_v5e(one_chip, shape):
     assert scopes.pallas_kernels(compiled.as_text()) == ["spmv_stream"]
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", HPCG_LEVELS[:-1], ids=str)
+def test_restrict_compiles_for_v5e(one_chip, shape, dtype):
+    from repro.kernels.stencil_nd.restrict import coarse_residual
+
+    offsets = stencil.BOX27.offsets
+    arr = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    coarse = jax.ShapeDtypeStruct(tuple(n // 2 for n in shape), dtype,
+                                  sharding=one_chip)
+    compiled = _compile(lambda r, x, *c: coarse_residual(r, x, list(c), offsets),
+                        arr, arr, *[coarse] * len(offsets))
+    assert scopes.pallas_kernels(compiled.as_text()) == ["restrict"]
+
+
 def test_hpcg_solve_compiles_for_v5e(one_chip, monkeypatch):
     """The HPCG cell's MG-PCG solve at 384^3 as a TPU process traces it:
-    its kernels are ``symgs`` and ``spmv_stream``, every vector-sized loop
+    its kernels are ``symgs``, ``restrict`` and ``spmv_stream`` (CG's ``A
+    p`` alone: no restriction takes it), every vector-sized loop
     instruction has a layer, and it fits a v5e's 16 GB."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     shape = HPCG_LEVELS[0]
@@ -246,7 +261,14 @@ def test_hpcg_solve_compiles_for_v5e(one_chip, monkeypatch):
         mesh, c, v, tol=1e-6, maxiter=500, policy=precision.F32, solver="cg",
         precond="mg", backend="spmd", schedule="overlap")).lower(cf, arr).compile()
     hlo = compiled.as_text()
-    assert set(scopes.pallas_kernels(hlo)) == {"symgs", "spmv_stream"}
+    kernels = scopes.pallas_kernels(hlo)
+    assert set(kernels) == {"symgs", "restrict", "spmv_stream"}
+    # the set-up's V-cycle and the loop's, 3 restrictions each; one SpMV
+    # in the set-up (b - A x0) and one in the loop (A p)
+    assert kernels.count("restrict") == 6 and kernels.count("spmv_stream") == 2
     assert scopes.unscoped_vectors(hlo, math.prod(shape)) == []
+    layers = scopes.layer_map(hlo)
+    assert {layers[k] for k in layers if k.startswith("restrict")} \
+        == {"precond", "setup"}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
